@@ -30,6 +30,19 @@ _NDSGEN_SRC = _DATAGEN_DIR / "ndsgen.cpp"
 _NDSGEN_BIN = _DATAGEN_DIR / "_build" / "ndsgen"
 _DISTS_JSON = _DATAGEN_DIR / "dists.json"
 _DISTS_HEADER = _DATAGEN_DIR / "_build" / "dists_gen.h"
+_BUILD_STAMP = _DATAGEN_DIR / "_build" / "ndsgen.stamp"
+
+
+def _source_stamp() -> str:
+    """Content hash of everything the generator binary is built from.
+    ``_build/`` is never committed, and staleness is decided by this
+    hash, not by mtimes: a checkout or a copy orders mtimes arbitrarily,
+    and a binary built elsewhere must never generate this run's data."""
+    import hashlib
+    h = hashlib.sha256()
+    for src in (_NDSGEN_SRC, _DISTS_JSON):
+        h.update(src.read_bytes())
+    return h.hexdigest()
 
 
 def render_dists_header() -> Path:
@@ -77,15 +90,24 @@ def check_build(rebuild: bool = False) -> Path:
     check_build returning the tpcds-gen jar + dsdgen paths,
     check.py:47-66)."""
     check_version()
-    if _NDSGEN_BIN.exists() and not rebuild:
-        if _NDSGEN_BIN.stat().st_mtime >= max(
-                _NDSGEN_SRC.stat().st_mtime, _DISTS_JSON.stat().st_mtime):
-            return _NDSGEN_BIN
+    stamp = _source_stamp()
+    if _NDSGEN_BIN.exists() and not rebuild and \
+            _BUILD_STAMP.exists() and \
+            _BUILD_STAMP.read_text().strip() == stamp:
+        return _NDSGEN_BIN
     render_dists_header()
+    # build to a private name, then publish binary and stamp by rename:
+    # concurrent drivers (pod slices on a shared filesystem, a test run
+    # beside a bench) may race into the first build
+    tmp = _NDSGEN_BIN.with_name(f"ndsgen.tmp.{os.getpid()}")
     cmd = ["g++", "-O2", f"-I{_DISTS_HEADER.parent}",
-           "-o", str(_NDSGEN_BIN), str(_NDSGEN_SRC)]
+           "-o", str(tmp), str(_NDSGEN_SRC)]
     print("building native generator:", " ".join(cmd))
     subprocess.run(cmd, check=True)
+    os.replace(tmp, _NDSGEN_BIN)
+    tmp_stamp = _BUILD_STAMP.with_name(f"ndsgen.stamp.tmp.{os.getpid()}")
+    tmp_stamp.write_text(stamp + "\n")
+    os.replace(tmp_stamp, _BUILD_STAMP)
     return _NDSGEN_BIN
 
 

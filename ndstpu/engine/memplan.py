@@ -32,8 +32,8 @@ import os
 from dataclasses import dataclass
 from typing import Iterable, Optional, Tuple
 
-#: fallback per-device budget when the platform reports no memory stats
-#: (CPU meshes in tests/CI) and no NDSTPU_HBM_BYTES override is set
+#: per-device budget on CPU platforms, which report no memory stats
+#: (tests/CI meshes), when no NDSTPU_HBM_BYTES override is set
 DEFAULT_BUDGET_BYTES = 2 << 30
 
 #: fraction of the reported budget the planner is allowed to commit
@@ -108,7 +108,10 @@ def device_budget_bytes(device=None) -> Tuple[int, str]:
 
     ``NDSTPU_HBM_BYTES`` wins (operator pin / tests); then the
     platform's ``memory_stats()`` (``bytes_limit`` less live
-    allocations); then :data:`DEFAULT_BUDGET_BYTES`.
+    allocations); then, on CPU platforms only (they report no stats),
+    :data:`DEFAULT_BUDGET_BYTES`.  An accelerator that reports no
+    ``bytes_limit`` is an error: sizing its chunks and its admission
+    queue from an assumed 2 GiB would be a guess filed as a plan.
     """
     env = os.environ.get("NDSTPU_HBM_BYTES")
     if env:
@@ -128,6 +131,11 @@ def device_budget_bytes(device=None) -> Tuple[int, str]:
                                                          0))
         if free > 0:
             return free, "memory_stats"
+    if getattr(device, "platform", "cpu") != "cpu":
+        raise RuntimeError(
+            f"{device} reports no usable bytes_limit "
+            f"(memory_stats()={stats!r}); set NDSTPU_HBM_BYTES to pin "
+            f"the per-device budget")
     return DEFAULT_BUDGET_BYTES, "default"
 
 

@@ -113,7 +113,15 @@ class Session:
         # RLocks: CTAS/INSERT recurse into _run on the same thread.
         import threading
 
+        from ndstpu.engine import device
         from ndstpu.engine.latch import KeyedLatch
+        # the accelerator engines run on whatever jax.devices()[0] is:
+        # refuse a quiet CPU fallback here, before any query, and keep
+        # the chip's compiled programs in the one resolved cache dir
+        # (a cpu-pinned rehearsal leaves the cache to JAX's own env var)
+        device.require_accelerator(self.backend)
+        if device.wants_chip(self.backend):
+            device.configure_compile_cache()
         if self.spmd_chunk_rows is not None and not (
                 self.spmd_chunk_rows == "auto"
                 or (isinstance(self.spmd_chunk_rows, int)

@@ -312,10 +312,10 @@ def run_full_bench(yaml_params: dict, resume: bool = False) -> None:
             if p.get("output_prefix"):
                 cmd += ["--output_prefix", p["output_prefix"]]
             if p.get("compile_records"):
-                # persisted size-plan records (+ the NDSTPU_XLA_CACHE_DIR
-                # persistent cache): accel engines skip per-query
-                # discovery.  Absolutized so subprocess cwd can't
-                # silently miss it.
+                # persisted size-plan records (+ the persistent XLA
+                # cache engine/device.py resolves): accel engines skip
+                # per-query discovery.  Absolutized so subprocess cwd
+                # can't silently miss it.
                 rec = os.path.abspath(p["compile_records"])
                 p["compile_records"] = rec
                 if not os.path.exists(rec):

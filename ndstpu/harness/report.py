@@ -22,6 +22,7 @@ from typing import Callable
 
 import ndstpu
 from ndstpu import obs
+from ndstpu.engine import device
 from ndstpu.faults import taxonomy
 from ndstpu.io import atomic
 
@@ -68,6 +69,10 @@ class BenchReport:
             if not any(r in k.upper() for r in redacted)}
         self.summary["env"]["engineConf"] = self.engine_conf
         self.summary["env"]["engineVersion"] = ndstpu.__version__
+        # what actually ran it, as JAX reports it — never inferred
+        # from the engine's name
+        self.summary["env"]["device"] = device.describe(
+            self.engine_conf.get("engine"))
         start_time = int(time.time() * 1000)
         counters_before = obs.counters_snapshot()
         # span_attrs tags the query span for trace/ledger consumers —

@@ -46,6 +46,7 @@ from typing import Callable, Dict, List, Optional
 
 from ndstpu import faults, obs
 from ndstpu.check import check_json_summary_folder
+from ndstpu.engine import device
 from ndstpu.harness import admission as adm
 from ndstpu.harness import power, progress
 from ndstpu.io import atomic, loader
@@ -328,7 +329,7 @@ def run_streams_inproc(stream_ids: List[str], cmd_template: List[str],
     # the whole point is ONE engine: refuse stream templates that
     # resolve to different warehouses/engines instead of guessing
     for flag in ("input_prefix", "engine", "input_format", "floats",
-                 "property_file", "compile_records", "xla_cache_dir"):
+                 "property_file", "compile_records"):
         vals = {getattr(ns, flag, None) for ns in streams.values()}
         if len(vals) > 1:
             raise ValueError(
@@ -344,11 +345,7 @@ def run_streams_inproc(stream_ids: List[str], cmd_template: List[str],
     engine_conf.setdefault("engine", engine)
     engine_conf.setdefault("input_format", ns0.input_format)
     engine_conf.setdefault("throughput_mode", "inproc")
-    if getattr(ns0, "xla_cache_dir", None) and accel:
-        engine_conf.setdefault("jax.compilation_cache_dir",
-                               ns0.xla_cache_dir)
-        engine_conf.setdefault(
-            "jax.persistent_cache_min_compile_time_secs", "2.0")
+    device.require_accelerator(engine)   # before the catalog load
     power.apply_engine_properties(engine_conf)
 
     # shared context: ONE catalog load / session / HBM upload for all
@@ -471,8 +468,7 @@ def run_streams_inproc(stream_ids: List[str], cmd_template: List[str],
                 engine=engine, stream_name=stream_name,
                 engine_conf=engine_conf, gate=gate,
                 json_summary_folder=ns.json_summary_folder,
-                summary_prefix=summary_prefix,
-                xla_cache_dir=ns.xla_cache_dir, t0=t0,
+                summary_prefix=summary_prefix, t0=t0,
                 span_attrs={"stream": stream_name, "stream_id": sid,
                             "mode": "inproc"},
                 retry_policy=retry_policy, quarantine=quarantine)
@@ -693,6 +689,7 @@ def _export_inproc_run(streams, results, errors, records, overlap_doc,
             json.dump(obs.run_metrics({
                 "mode": "inproc",
                 "engine": engine,
+                "device": device.describe(engine),
                 "streams": records,
                 "stream_apps": {sid: res["app_id"]
                                 for sid, res in results.items()},

@@ -157,8 +157,11 @@ def _parse_depth(raw) -> Optional[int]:
 
 
 def _run_server(args) -> int:
+    from ndstpu.engine import device
     from ndstpu.serve import lifecycle
     from ndstpu.serve.server import QueryServer, ServeConfig
+    # before binding or loading anything: no chip, no daemon
+    device.require_accelerator(args.engine)
     sd = args.state_dir
     os.makedirs(sd, exist_ok=True)
     cfg = ServeConfig(

@@ -6,10 +6,13 @@ The reference fans out concurrent spark-submit processes with
 
 * ``--mode process`` (default, spec-faithful shape): each stream is one
   OS process running the power CLI with `{}` placeholders substituted
-  the same way.  `--concurrent N` bounds how many streams execute on
-  the shared device at once (the `spark.rapids.sql.concurrentGpuTasks`
-  analog, power_run_gpu.template:21) via a cross-process file-lock
-  semaphore — see ndstpu.harness.admission.
+  the same way.  `--concurrent N` bounds how many streams execute
+  queries at once (the `spark.rapids.sql.concurrentGpuTasks` analog,
+  power_run_gpu.template:21) via a cross-process file-lock semaphore —
+  see ndstpu.harness.admission.  A chip belongs to ONE process, so
+  with an accelerator ``--engine`` (and no ``JAX_PLATFORMS=cpu`` pin)
+  the stream processes run one after another — never N-1 of them on
+  the CPU; use ``inproc`` or ``serve`` for real overlap on a chip.
 * ``--mode inproc`` (fast path): the same N streams run as worker
   threads over ONE shared session/executor so the warehouse loads once
   and each distinct query compiles once — see
@@ -41,6 +44,7 @@ import time
 from typing import Dict, List, Optional
 
 from ndstpu import obs
+from ndstpu.engine import device
 from ndstpu.faults import taxonomy
 from ndstpu.harness import progress
 from ndstpu.io import atomic
@@ -115,10 +119,34 @@ def write_overlap_report(overlap_report: Optional[str],
     return doc
 
 
+def _wants_the_chip(cmd_template: List[str]) -> bool:
+    """True when each process of the wrapped command opens the chip
+    (device.wants_chip of its ``--engine``): libtpu gives a chip to one
+    process at a time, and a second one fails at start-up or — with no
+    platform list — carries on quietly on the CPU."""
+    engine = None
+    for i, arg in enumerate(cmd_template):
+        if arg == "--engine" and i + 1 < len(cmd_template):
+            engine = cmd_template[i + 1]
+        elif arg.startswith("--engine="):
+            engine = arg.split("=", 1)[1]
+    return device.wants_chip(engine)
+
+
 def run_throughput(stream_ids: List[str], cmd_template: List[str],
                    concurrent: Optional[int] = None,
                    budget_s: Optional[float] = None,
                    overlap_report: Optional[str] = None) -> int:
+    # this parent stays off jax: it must never hold the chip its
+    # stream processes need
+    serialized = len(stream_ids) > 1 and _wants_the_chip(cmd_template)
+    max_procs = 1 if serialized else len(stream_ids)
+    if serialized:
+        print(f"NOTE: --mode process with an accelerator engine: one "
+              f"process owns the chip, so the {len(stream_ids)} stream "
+              f"processes run one after another (no overlap).  For "
+              f"concurrent streams on one chip use --mode inproc or "
+              f"--mode serve.")
     env = None
     lock_dir = None
     child_env: Dict[str, str] = {}
@@ -136,12 +164,18 @@ def run_throughput(stream_ids: List[str], cmd_template: List[str],
         t0 = time.time()
         pending = {}
         starts = {}
-        for sid in stream_ids:
-            cmd = [arg.replace("{}", sid) for arg in cmd_template]
-            print("launch:", " ".join(cmd))
-            starts[sid] = time.time()
-            obs.inc("harness.throughput.streams_launched")
-            pending[sid] = subprocess.Popen(cmd, env=env)
+        waiting = list(stream_ids)
+
+        def launch_next() -> None:
+            while waiting and len(pending) < max_procs:
+                sid = waiting.pop(0)
+                cmd = [arg.replace("{}", sid) for arg in cmd_template]
+                print("launch:", " ".join(cmd))
+                starts[sid] = time.time()
+                obs.inc("harness.throughput.streams_launched")
+                pending[sid] = subprocess.Popen(cmd, env=env)
+
+        launch_next()
         rc = 0
         records: List[dict] = []
         # a stream subprocess that dies nonzero is restarted ONCE
@@ -210,14 +244,17 @@ def run_throughput(stream_ids: List[str], cmd_template: List[str],
                 if code:
                     obs.inc("harness.throughput.streams_failed")
                 rc = rc or code
+            launch_next()
             if pending:
                 poll_s = 0.01 if completed else min(poll_s * 2, 0.5)
                 time.sleep(poll_s)
                 if time.time() - last_hb >= 30.0:
                     last_hb = time.time()
                     hb.beat(len(records), "waiting", last_hb - t0)
-        write_overlap_report(overlap_report, records, concurrent,
-                             budget_s, mode="process")
+        write_overlap_report(
+            overlap_report, records, concurrent, budget_s,
+            mode="process",
+            extra={"serialized_for_chip": True} if serialized else None)
         return rc
     finally:
         if lock_dir is not None:

@@ -4,10 +4,9 @@ Every measured query execution lands here as one JSON line keyed by a
 *fingerprint* — ``engine|sf<scale>|seed:<seed>|<warmth>`` — where
 warmth is **measured, not asserted**: the tracer's ``compile_s`` /
 ``execute_s`` split (ndstpu/obs/trace.py) decides cold vs warm with
-the same rule the BenchReport metrics block uses.  Round 5's headline
-regressed from 2.56x to 0.60x because a cold re-baseline silently
-burned the driver's budget; the ledger is the durable memory that
-makes such a run *say so*: it serves two priors per query,
+the same rule the BenchReport metrics block uses.  A cold re-baseline
+once burned a whole run's budget silently; the ledger is the durable
+memory that makes such a run *say so*: it serves two priors per query,
 
 * **best-known-warm** — the fastest warm wall ever recorded.  Cold
   runs contribute their ``execute_s`` (a cold run's post-compile
@@ -22,11 +21,9 @@ Consumers: the harness heartbeat / cheapest-first budget degradation
 
 The file format is one self-describing dict per line (``v: 1``);
 unreadable lines are counted and skipped, never fatal — an interrupted
-append must not poison the history.  ``ingest_file`` understands the
-legacy artifact shapes already in the tree (``BENCH_r0*.json`` driver
-records, ``docs/WARM_R5_SF1.json`` discover/steady walls, and
-``*.metrics.json`` power-run sidecars) so the pre-ledger history
-serves priors from day one.
+append must not poison the history.  ``ingest_file`` reads power-run
+sidecars (``*.metrics.json``) and other ledgers, so runs made without a
+ledger can still serve priors.
 """
 
 from __future__ import annotations
@@ -68,8 +65,8 @@ def make_entry(query: str, wall_s: float, compile_s: float = 0.0,
                extra: Optional[dict] = None) -> dict:
     """One ledger line.  ``warmth`` defaults to the measured
     compile/execute-split classification; pass it explicitly only for
-    legacy artifacts that recorded the phase out of band (e.g. the
-    warm-corpus discover/steady passes).
+    artifacts that recorded the phase out of band (a sidecar's
+    ``mode``).
 
     A warm execution that was served cached spine tables
     (``extra.spine_hits`` > 0, engine/spine.py) is its own warmth
@@ -268,7 +265,7 @@ class Ledger:
     def queries(self) -> set:
         return {e["query"] for e in self.entries}
 
-    # -- legacy-artifact ingest ----------------------------------------------
+    # -- artifact ingest -----------------------------------------------------
 
     def ingest_file(self, path: str, engine: Optional[str] = None,
                     scale_factor=None, seed=None) -> int:
@@ -276,10 +273,6 @@ class Ledger:
 
         * power-run sidecar (``run_metrics`` output): ``queries: [...]``
           with per-query wall/compile/execute + mode;
-        * warm-corpus artifact (docs/WARM_R5_SF1.json): ``discover`` /
-          ``steady`` name->seconds maps (cold / warm passes);
-        * driver record (BENCH_r0*.json): ``cmd``/``rc`` + ``parsed``
-          headline — kept as one run-level ``__bench__`` entry;
         * an existing ledger (JSONL) — merged line by line.
         """
         src = os.path.basename(path)
@@ -301,32 +294,9 @@ class Ledger:
                     engine=eng, scale_factor=scale_factor or "unknown",
                     seed=seed or "unknown",
                     warmth=q.get("mode"), source=src))
-        elif isinstance(obj, dict) and ("discover" in obj or
-                                        "steady" in obj):
-            eng = engine or "tpu"
-            sf = scale_factor or "unknown"
-            sd = seed or "unknown"
-            for q, wall in (obj.get("discover") or {}).items():
-                entries.append(make_entry(
-                    q, wall, compile_s=wall, engine=eng, scale_factor=sf,
-                    seed=sd, warmth="cold", source=src))
-            for q, wall in (obj.get("steady") or {}).items():
-                entries.append(make_entry(
-                    q, wall, execute_s=wall, engine=eng, scale_factor=sf,
-                    seed=sd, warmth="warm", source=src))
-        elif isinstance(obj, dict) and "cmd" in obj and "rc" in obj:
-            parsed = obj.get("parsed") or {}
-            entries.append(make_entry(
-                "__bench__", parsed.get("elapsed_s", 0.0) or 0.0,
-                engine=engine or "unknown",
-                scale_factor=scale_factor or "unknown",
-                seed=seed or "unknown", warmth="unknown", source=src,
-                extra={k: parsed[k] for k in
-                       ("metric", "value", "vs_baseline",
-                        "geomean_speedup", "partial", "phase_reached")
-                       if k in parsed} or None))
-        elif obj is None:
-            # maybe JSONL (another ledger): merge parseable lines
+        else:
+            # JSONL (another ledger, possibly one line long): merge
+            # parseable lines
             for line in text.splitlines():
                 line = line.strip()
                 if not line:
@@ -340,16 +310,9 @@ class Ledger:
         return self.append(entries, dedupe=True)
 
     def ingest_history(self, root: str = ".") -> Dict[str, int]:
-        """Ingest the repo's committed history: BENCH_r0*.json driver
-        records, the warm-corpus walls, and any power-run sidecars at
-        the root / under docs.  Returns {path: entries added}."""
+        """Ingest every power-run sidecar at ``root`` and under its
+        ``docs/``.  Returns {path: entries added}."""
         counts: Dict[str, int] = {}
-        for p in sorted(glob.glob(os.path.join(root, "BENCH_r*.json"))):
-            counts[p] = self.ingest_file(p)
-        warm = os.path.join(root, "docs", "WARM_R5_SF1.json")
-        if os.path.exists(warm):
-            counts[warm] = self.ingest_file(
-                warm, engine="tpu", scale_factor="1", seed="bench")
         for pat in ("*.metrics.json", os.path.join("docs",
                                                    "*.metrics.json")):
             for p in sorted(glob.glob(os.path.join(root, pat))):

@@ -1443,6 +1443,11 @@ class DistributedPlanExecutor:
 
         self._fact_args_fn = fact_args
         dev_args = fact_args(0)
+        # where the fact really lives: devices holding a shard of its
+        # first column, by platform (a 4-chip run must read 4 / tpu)
+        placed = dev_args[0].sharding.device_set
+        obs.annotate(spmd_fact_placement=f"{len(placed)}x"
+                     f"{next(iter(placed)).platform}")
 
         # shuffle-join build partitions ride in as extra sharded args
         # (closure constants would be replicated on every device)

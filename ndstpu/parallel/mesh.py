@@ -14,22 +14,15 @@ from typing import Optional
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-try:  # newer jax: top-level export, replication check spelled check_vma
-    from jax import shard_map as _shard_map
-    _CHECK_KW = "check_vma"
-except ImportError:  # older jax: experimental module, check_rep kwarg
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _CHECK_KW = "check_rep"
-
 SHARD_AXIS = "shards"
 
 
 def shard_map(f, mesh: Mesh, in_specs, out_specs, check_vma: bool = False):
-    """Version-portable ``shard_map`` wrapper: the replication-check
-    kwarg was renamed across jax releases (check_rep -> check_vma), and
-    the symbol moved from jax.experimental to the top level."""
-    return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, **{_CHECK_KW: check_vma})
+    """``jax.shard_map`` with the engine's default: replication check
+    off (outputs declared ``P()`` are replicated by construction, via
+    the all_gather/psum combines in parallel/exchange.py)."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)
 
 
 def make_mesh(n_devices: Optional[int] = None,

@@ -112,10 +112,10 @@ BENCH_RNGSEED = "07291122510"
 def render_power_corpus(rngseed: str = BENCH_RNGSEED,
                         stream: int = 0) -> List[Tuple[str, str]]:
     """The canonical (query_name, sql) power-run corpus: every template,
-    split into executable parts, rendered with ``rngseed``.  Shared by
-    bench.py, warm_corpus, sf10_bench — per-script render loops drifted
-    once (different seed -> same names, different literals -> silently
-    wrong speedups)."""
+    split into executable parts, rendered with ``rngseed``.  One
+    renderer for every script that times the corpus — per-script render
+    loops drifted once (different seed -> same names, different
+    literals -> silently wrong speedups)."""
     queries: List[Tuple[str, str]] = []
     for tpl in list_templates():
         queries.extend(render_template_parts(

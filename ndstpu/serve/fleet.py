@@ -37,6 +37,14 @@ pids, readiness, restart counts, and the serve.fleet.* counters.
 ``NDSTPU_FLEET=0`` is the kill switch: the supervisor degenerates to
 one replica, and the plain single-server ``ndstpu-serve`` path is
 untouched by this module entirely.
+
+**One process per chip.**  A chip belongs to one process at a time,
+so with an accelerator engine (and no ``JAX_PLATFORMS=cpu`` pin) the
+supervisor refuses more ``tpu`` replicas than the host has chips and
+more than one ``tpu-spmd`` replica (each spans the whole mesh), and
+binds ``tpu`` replica *i* to chip *i* through its environment
+(``device.chip_binding_env``).  The supervisor itself never imports
+jax — it would hold a chip its replicas need.
 """
 
 from __future__ import annotations
@@ -54,6 +62,7 @@ import time
 from typing import Callable, Dict, List, Optional
 
 from ndstpu import faults, obs
+from ndstpu.engine import device
 from ndstpu.io import commit as commit_mod
 from ndstpu.serve import protocol, transport
 
@@ -147,6 +156,21 @@ class FleetSupervisor:
             self.config = config
         if config.replicas < 1:
             raise ValueError("fleet needs >= 1 replica")
+        self._chip_bound = False
+        if device.wants_chip(config.engine):
+            chips = device.visible_chips()
+            limit = 1 if config.engine == "tpu-spmd" else chips
+            if config.replicas > limit:
+                raise ValueError(
+                    f"{config.replicas} {config.engine} replicas on a "
+                    f"host with {chips} TPU chip(s): one process owns a "
+                    f"chip (and a tpu-spmd replica owns them all), so "
+                    f"at most {limit} replica(s) fit.  More replicas "
+                    f"than chips would fail to open the device or fall "
+                    f"back to the CPU")
+            # one replica on a multi-chip host still gets one chip:
+            # what `--engine tpu` means does not depend on the count
+            self._chip_bound = config.engine == "tpu" and chips > 1
         self._probe_fn = probe_fn or self._probe_rpc
         self._launcher = launcher or self._launch_proc
         os.makedirs(config.run_dir, exist_ok=True)
@@ -229,11 +253,15 @@ class FleetSupervisor:
             argv += ["--query_timeout_s", str(cfg.query_timeout_s)]
         log = open(os.path.join(cfg.run_dir,
                                 f"{rep.replica_id}.log"), "ab")
+        env = None
+        if self._chip_bound:
+            env = dict(os.environ, **device.chip_binding_env(
+                self.replicas.index(rep)))
         try:
             # own session: replicas outlive a SIGKILL'd supervisor
             # (chaos scenario I) and never see its terminal signals
             return subprocess.Popen(argv, stdout=log, stderr=log,
-                                    start_new_session=True)
+                                    env=env, start_new_session=True)
         finally:
             log.close()
 

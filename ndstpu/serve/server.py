@@ -45,7 +45,7 @@ import time
 from typing import Dict, List, Optional
 
 from ndstpu import faults, obs
-from ndstpu.engine import columnar
+from ndstpu.engine import columnar, device
 from ndstpu.engine.session import Session
 from ndstpu.engine.sql import ast, parse_statement
 from ndstpu.harness import admission as adm
@@ -730,6 +730,7 @@ class QueryServer:
             "draining": self.draining,
             "uptime_s": round(time.time() - self._started_at, 3),
             "engine": self.config.engine,
+            "device": device.describe(self.config.engine),
             "replica_id": self.config.replica_id,
             "endpoints": [ep.spec for ep in self.endpoints],
             "connections": len(self._conns),

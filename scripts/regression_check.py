@@ -9,8 +9,8 @@ is classified ``cold-compile``, never ``regressed``.
     python scripts/regression_check.py /tmp/nds_hw/power_time.csv.metrics.json \\
         --ledger .bench_cache/ledger.jsonl --out REGRESSIONS.json
 
-    # no-hardware CI mode: ingest committed history and verify the
-    # classifier on it + synthetic cases
+    # no-hardware CI mode: verify ingest + classifier on a synthetic
+    # history
     python scripts/regression_check.py --selftest
 """
 from __future__ import annotations
@@ -28,29 +28,39 @@ from ndstpu.obs import sentinel  # noqa: E402
 
 
 def selftest() -> int:
-    """Classifier checks that need no hardware: replay the committed
-    warm-run history through ingest + classify and assert the invariants
-    the sentinel promises (a warm steady-state rerun of the same data is
-    never flagged; cold compiles are never regressions)."""
-    led = ledger_mod.Ledger(path=None, load=False)
-    ingested = led.ingest_history(REPO)
-    print(f"selftest: ingested {sum(ingested.values())} historical "
-          f"entries from {len(ingested)} artifacts "
+    """Classifier checks that need no hardware: write a synthetic run
+    history (a cold and a warm power-run sidecar), replay it through
+    ingest + classify and assert the invariants the sentinel promises
+    (a warm steady-state rerun of the same data is never flagged; cold
+    compiles are never regressions)."""
+    import tempfile
+    steady = {f"query{i}": 0.2 + 0.05 * i for i in range(1, 21)}
+    with tempfile.TemporaryDirectory() as root:
+        for tag, compile_s in (("cold", 30.0), ("warm", 0.0)):
+            with open(os.path.join(root, f"{tag}.csv.metrics.json"),
+                      "w") as f:
+                json.dump({"engine": "tpu", "queries": [
+                    {"query": q, "wall_s": w + compile_s,
+                     "compile_s": compile_s, "execute_s": w,
+                     "mode": tag} for q, w in steady.items()]}, f)
+        led = ledger_mod.Ledger(path=None, load=False)
+        ingested = {
+            p: led.ingest_file(p, scale_factor="1") for p in sorted(
+                os.path.join(root, n) for n in os.listdir(root))}
+    print(f"selftest: ingested {sum(ingested.values())} synthetic "
+          f"entries from {len(ingested)} sidecars "
           f"({len(led.queries())} distinct queries)")
-    warm_doc = os.path.join(REPO, "docs", "WARM_R5_SF1.json")
-    if os.path.exists(warm_doc):
-        with open(warm_doc) as f:
-            steady = json.load(f).get("steady", {})
-        qsums = [{"query": q, "wall_s": w, "compile_s": 0.0,
-                  "execute_s": w} for q, w in steady.items()]
-        res = sentinel.classify_run(qsums, led, engine="tpu",
-                                    scale_factor="1")
-        counts = res["counts"]
-        print(f"selftest: steady-state replay counts: {counts}")
-        assert not res["regressions"], (
-            f"replaying the committed steady-state against its own "
-            f"ledger flagged regressions: {res['regressions']}")
-        assert counts.get("cold-compile", 0) == 0, counts
+    assert sum(ingested.values()) == 2 * len(steady), ingested
+    qsums = [{"query": q, "wall_s": w, "compile_s": 0.0,
+              "execute_s": w} for q, w in steady.items()]
+    res = sentinel.classify_run(qsums, led, engine="tpu",
+                                scale_factor="1")
+    counts = res["counts"]
+    print(f"selftest: steady-state replay counts: {counts}")
+    assert not res["regressions"], (
+        f"replaying the steady state against its own ledger flagged "
+        f"regressions: {res['regressions']}")
+    assert counts.get("cold-compile", 0) == 0, counts
     # synthetic verdict table
     v = sentinel.classify_query("q", 60.0, 55.0, 5.0, 1.0)
     assert v["verdict"] == "cold-compile", v
@@ -74,9 +84,9 @@ def main(argv=None) -> int:
                     help="ledger JSONL (default $NDSTPU_LEDGER or "
                          ".bench_cache/ledger.jsonl)")
     ap.add_argument("--ingest-history", action="store_true",
-                    help="also ingest committed history artifacts "
-                         "(BENCH_r*.json, docs/WARM_R5_SF1.json, "
-                         "*.metrics.json) as baselines")
+                    help="also ingest the power-run sidecars "
+                         "(*.metrics.json) at the repo root and under "
+                         "docs/ as baselines")
     ap.add_argument("--engine", default=None,
                     help="baseline scope override (default: from each "
                          "sidecar)")

@@ -15,8 +15,6 @@ cache keys.
 """
 
 import math
-import os
-import subprocess
 
 import pytest
 
@@ -185,17 +183,8 @@ def test_canonical_key_session_helper(ssess):
 
 
 @pytest.fixture(scope="module")
-def warehouse(tmp_path_factory):
-    data = tmp_path_factory.mktemp("rawc")
-    wh = tmp_path_factory.mktemp("whc")
-    env = dict(os.environ, PYTHONPATH=os.getcwd())
-    subprocess.run(["python", "-m", "ndstpu.datagen.driver", "local",
-                    "0.002", "2", str(data)], check=True, env=env)
-    subprocess.run(["python", "-m", "ndstpu.io.transcode",
-                    "--input_prefix", str(data), "--output_prefix",
-                    str(wh), "--report_file", str(wh / "load.txt")],
-                   check=True, env=env, stdout=subprocess.DEVNULL)
-    return wh
+def warehouse(sf002_warehouse):
+    return sf002_warehouse
 
 
 @pytest.fixture(scope="module")

@@ -92,46 +92,56 @@ def test_expected_cold_is_median():
                              scale_factor="1") == 20.0
 
 
-def test_ingest_legacy_shapes(tmp_path):
-    warm = tmp_path / "WARM.json"
-    warm.write_text(json.dumps({
-        "discover": {"query1": 12.0}, "steady": {"query1": 0.4},
-        "failed": [], "note": "x"}))
-    bench = tmp_path / "BENCH_r99.json"
-    bench.write_text(json.dumps({
-        "n": 99, "cmd": "python x", "rc": 0,
-        "parsed": {"metric": "m", "value": 1.0, "elapsed_s": 100.0}}))
-    sidecar = tmp_path / "t.csv.metrics.json"
-    sidecar.write_text(json.dumps({
-        "engine": "cpu",
-        "queries": [{"query": "query2", "wall_s": 1.0,
-                     "compile_s": 0.0, "execute_s": 0.98,
-                     "mode": "warm"}],
+def _sidecar(path, engine, queries):
+    path.write_text(json.dumps({
+        "engine": engine,
+        "queries": [{"query": q, "wall_s": wall, "compile_s": comp,
+                     "execute_s": wall - comp, "mode": mode}
+                    for q, wall, comp, mode in queries],
         "totals": {}}))
+
+
+def test_ingest_shapes(tmp_path):
+    """Both shapes ingest_file reads: a power-run sidecar and another
+    ledger (JSONL), with measured warmth carried through and re-ingest
+    deduped."""
+    sidecar = tmp_path / "t.csv.metrics.json"
+    _sidecar(sidecar, "tpu", [("query1", 12.0, 11.6, "cold"),
+                              ("query2", 1.0, 0.0, "warm")])
+    other = ledger_mod.Ledger(str(tmp_path / "other.jsonl"))
+    other.record_query("query1", 0.4, 0.0, 0.4, engine="tpu",
+                       scale_factor="1")
     led = ledger_mod.Ledger(path=None)
-    assert led.ingest_file(str(warm), engine="tpu",
-                           scale_factor="1") == 2
-    assert led.ingest_file(str(bench)) == 1
-    assert led.ingest_file(str(sidecar), scale_factor="1") == 1
-    # warmth came through: discover=cold, steady=warm
-    assert led.best_warm("query1", engine="tpu",
-                         scale_factor="1") == 0.4
+    assert led.ingest_file(str(sidecar), scale_factor="1") == 2
+    assert led.ingest_file(other.path) == 1
+    # warmth came through: cold wall is the expected-cold prior, the
+    # warm entry from the merged ledger is the best-known-warm
     assert led.expected_cold("query1", engine="tpu",
                              scale_factor="1") == 12.0
-    assert led.best_warm("query2", engine="cpu",
+    assert led.best_warm("query1", engine="tpu",
+                         scale_factor="1") == 0.4
+    assert led.best_warm("query2", engine="tpu",
                          scale_factor="1") == 1.0
     # re-ingest is a no-op (dedupe)
-    assert led.ingest_file(str(warm), engine="tpu",
-                           scale_factor="1") == 0
+    assert led.ingest_file(str(sidecar), scale_factor="1") == 0
+    assert led.ingest_file(other.path) == 0
 
 
-def test_ingest_committed_history():
+def test_ingest_history_sweeps_sidecars(tmp_path):
+    """ingest_history over a synthetic tree: every sidecar at the root
+    and under docs/ is swept, nothing else is."""
+    (tmp_path / "docs").mkdir()
+    _sidecar(tmp_path / "power_time.csv.metrics.json", "tpu",
+             [(f"query{i}", 1.0 + i, 0.0, "warm") for i in range(1, 61)])
+    _sidecar(tmp_path / "docs" / "hw.csv.metrics.json", "tpu",
+             [(f"query{i}", 0.5 + i, 0.0, "warm") for i in range(1, 61)])
+    (tmp_path / "notes.json").write_text(json.dumps({"queries": "no"}))
     led = ledger_mod.Ledger(path=None)
-    counts = led.ingest_history(REPO)
-    # the committed warm-corpus artifact alone carries >100 queries
-    assert sum(counts.values()) > 100
+    counts = led.ingest_history(str(tmp_path))
+    assert len(counts) == 2
+    assert sum(counts.values()) == 120
     assert led.best_warm("query1", engine="tpu",
-                         scale_factor="1") is not None
+                         scale_factor="unknown") == 1.5
 
 
 # -------------------------------------------------------------- sentinel

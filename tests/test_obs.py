@@ -420,17 +420,19 @@ def test_power_run_emits_traces_and_sidecar(tmp_path, monkeypatch,
         for i, sql in enumerate(FIVE_QUERIES)))
     monkeypatch.setattr(loader, "load_catalog",
                         lambda prefix, use_decimal=True: tiny_catalog())
+    # the cache-file gauge reads the ONE resolved cache directory
+    # (ndstpu/engine/device.py): here, where the variable points
     xla_dir = tmp_path / "xla"
     xla_dir.mkdir()
     (xla_dir / "seeded_entry").write_text("x")
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(xla_dir))
     args = argparse.Namespace(
         query_stream_file=str(stream), input_prefix=str(tmp_path),
         time_log=str(tmp_path / "power_time.csv"),
         input_format="parquet", engine="tpu", output_prefix=None,
         output_format="parquet", property_file=None,
         json_summary_folder=str(tmp_path / "json"), sub_queries=None,
-        extra_time_log=None, xla_cache_dir=str(xla_dir),
-        compile_records=None, floats=True)
+        extra_time_log=None, compile_records=None, floats=True)
     power.run_query_stream(args)
 
     sidecar = json.load(open(str(tmp_path / "power_time.csv.metrics.json")))
@@ -442,6 +444,8 @@ def test_power_run_emits_traces_and_sidecar(tmp_path, monkeypatch,
     c = sidecar["counters"]
     assert c["engine.cache.compiled.miss"] == len(FIVE_QUERIES)
     assert sidecar["gauges"]["xla.persistent_cache.files"] == 1
+    # every report names what ran it: the tpu engine, pinned to the cpu
+    assert sidecar["device"]["platform"] == "cpu"
 
     jsonl = (tmp_path / "power_time.csv.trace.jsonl").read_text()
     spans = [json.loads(ln) for ln in jsonl.splitlines()
@@ -471,10 +475,6 @@ def test_exchange_collective_counters(fresh_tracer):
     documented per-compiled-program semantics)."""
     import jax
     import jax.numpy as jnp
-    try:
-        from jax import shard_map
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from ndstpu.parallel import exchange
@@ -488,12 +488,8 @@ def test_exchange_collective_counters(fresh_tracer):
     def body(x):
         return exchange.broadcast_gather(x)
 
-    try:  # replication-check kwarg was renamed across jax versions
-        fn = shard_map(body, mesh=mesh, in_specs=(P(SHARD_AXIS),),
+    fn = jax.shard_map(body, mesh=mesh, in_specs=(P(SHARD_AXIS),),
                        out_specs=P(), check_vma=False)
-    except TypeError:
-        fn = shard_map(body, mesh=mesh, in_specs=(P(SHARD_AXIS),),
-                       out_specs=P(), check_rep=False)
     x = jnp.arange(n_dev * 4, dtype=jnp.int32)
     before = obs.counters_snapshot()
     jax.jit(fn)(x)

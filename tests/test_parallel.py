@@ -92,22 +92,9 @@ def test_broadcast_gather(mesh8):
 
 
 @pytest.fixture(scope="module")
-def dist_catalog(tmp_path_factory):
-    import os
-    import subprocess
-
+def dist_catalog(sf002_warehouse):
     from ndstpu.io import loader
-    data = tmp_path_factory.mktemp("draw")
-    wh = tmp_path_factory.mktemp("dwh")
-    env = dict(os.environ, PYTHONPATH=os.getcwd())
-    subprocess.run(["python", "-m", "ndstpu.datagen.driver", "local",
-                    "0.002", "2", str(data)], check=True, env=env)
-    subprocess.run(["python", "-m", "ndstpu.io.transcode",
-                    "--input_prefix", str(data),
-                    "--output_prefix", str(wh),
-                    "--report_file", str(wh / "load.txt")],
-                   check=True, env=env, stdout=subprocess.DEVNULL)
-    return loader.load_catalog(str(wh))
+    return loader.load_catalog(str(sf002_warehouse))
 
 
 def _dist_vs_cpu(catalog, mesh, sql, threshold=1000, broadcast_limit=None,

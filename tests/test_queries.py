@@ -5,7 +5,6 @@ stream generator must honor the marker/permutation/rngseed contracts
 (reference: nds_gen_query_stream.py, spark.tpl dialect markers)."""
 
 import os
-import subprocess
 
 import pytest
 
@@ -15,18 +14,8 @@ from ndstpu.queries import streamgen
 
 
 @pytest.fixture(scope="module")
-def warehouse(tmp_path_factory):
-    data = tmp_path_factory.mktemp("raw")
-    wh = tmp_path_factory.mktemp("wh")
-    env = dict(os.environ, PYTHONPATH=os.getcwd())
-    subprocess.run(["python", "-m", "ndstpu.datagen.driver", "local", "0.002",
-                    "2", str(data)], check=True, env=env)
-    subprocess.run(["python", "-m", "ndstpu.io.transcode",
-                    "--input_prefix", str(data),
-                    "--output_prefix", str(wh),
-                    "--report_file", str(wh / "load.txt")],
-                   check=True, env=env, stdout=subprocess.DEVNULL)
-    return wh
+def warehouse(sf002_warehouse):
+    return sf002_warehouse
 
 
 @pytest.fixture(scope="module")

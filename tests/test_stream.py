@@ -27,17 +27,11 @@ def env():
 
 
 @pytest.fixture(scope="module")
-def stream_root(tmp_path_factory, env):
+def stream_root(tmp_path_factory, env, sf002_warehouse):
     """Tiny plain-parquet warehouse (ParquetChunkSource cannot stream
     ndslake ACID layouts) + one query stream for the power CLI."""
     root = tmp_path_factory.mktemp("stream")
-    subprocess.run(["python", "-m", "ndstpu.datagen.driver", "local",
-                    "0.002", "2", str(root / "raw")], check=True, env=env)
-    subprocess.run(["python", "-m", "ndstpu.io.transcode",
-                    "--input_prefix", str(root / "raw"),
-                    "--output_prefix", str(root / "wh"),
-                    "--report_file", str(root / "load.txt")],
-                   check=True, env=env, stdout=subprocess.DEVNULL)
+    os.symlink(sf002_warehouse, root / "wh", target_is_directory=True)
     subprocess.run(["python", "-m", "ndstpu.queries.streamgen",
                     "--output_dir", str(root / "streams"),
                     "--rngseed", "07291122510", "--streams", "1"],
