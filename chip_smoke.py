@@ -32,11 +32,18 @@ alone.  The default mode DEMANDS a TPU: if the children's default
 backend is not ``tpu`` the run fails at stage ``device``, before any
 query, whatever the environment says, and prints no result.
 
-The last line of stdout is one JSON object.  ``"ok": true`` appears
-only for a complete run on a TPU.  ``--rehearse-cpu`` (tiny ``--sf``,
-platform pinned to cpu, Pallas interpreted) and ``--stages`` runs
-report under ``"rehearsal"`` / ``"partial"`` with ``"ok": false``: they
-debug this script, they are not a pass.
+The last line of stdout is one JSON object with exactly these keys,
+the device as JAX reports it:
+
+    {"ok": true, "device": {"platform": "tpu", "kind": "...", "count": 1}}
+
+The line before it is the full report (also ``<out>/result.json``): each
+stage's outcome and wall time, compile seconds as set-up time.
+``"ok": true`` appears only for a complete run on a TPU.
+``--rehearse-cpu`` (tiny ``--sf``, platform pinned to cpu, Pallas
+interpreted) and ``--stages`` runs say ``"ok": false`` and report under
+``"rehearsal"`` / ``"partial"``: they debug this script, they are not a
+pass.
 
 Large data (raw files, warehouse, query outputs) lives in a work
 directory outside the checkout and is removed at the end; only small
@@ -608,6 +615,11 @@ class Smoke:
         with open(os.path.join(self.out, "result.json"), "w") as f:
             json.dump(result, f, indent=1)
         print(json.dumps(result), flush=True)
+        # the last line: these keys and no others
+        print(json.dumps({"ok": result["ok"], "device": {
+            "platform": self.device["platform"],
+            "kind": self.device["device_kind"],
+            "count": self.device["count"]}}), flush=True)
         return 0 if all_ok else 1
 
 

@@ -103,7 +103,11 @@ def test_chip_smoke_cpu_rehearsal(tmp_path):
         capture_output=True, text=True, env=env, cwd=str(tmp_path),
         timeout=1200)
     assert r.returncode == 0, r.stdout[-3000:] + r.stderr[-3000:]
-    result = json.loads(r.stdout.strip().splitlines()[-1])
+    report_line, last_line = r.stdout.strip().splitlines()[-2:]
+    # the last line carries exactly these keys; the report precedes it
+    assert json.loads(last_line) == {"ok": False, "device": {
+        "platform": "cpu", "kind": "cpu", "count": 4}}
+    result = json.loads(report_line)
     assert result["ok"] is False
     assert result["rehearsal"]["ok"] is True
     assert result["device"]["platform"] == "cpu"
