@@ -275,8 +275,13 @@ class Table:
         return {n: c.to_pylist() for n, c in self.columns.items()}
 
     def to_rows(self) -> List[tuple]:
-        cols = [c.to_pylist() for c in self.columns.values()]
-        return list(zip(*cols)) if cols else []
+        from ndstpu import obs
+        # the collect: host-side decode of the result, part of running
+        # the query (so execute_s where a query span collects it)
+        with obs.span("to_rows", cat="plan-node", bucket="execute_s",
+                      rows=self.num_rows):
+            cols = [c.to_pylist() for c in self.columns.values()]
+            return list(zip(*cols)) if cols else []
 
     @staticmethod
     def concat(tables: Sequence["Table"]) -> "Table":

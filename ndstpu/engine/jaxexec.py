@@ -1756,6 +1756,8 @@ class JaxExecutor:
         from ndstpu.engine.latch import KeyedLatch
         self._exec_lock = threading.RLock()
         self._key_latch = KeyedLatch()
+        # plan node -> pre-order id, while a replay program is traced
+        self._scope_ids: Dict[int, int] = {}
         # eager bounds diagnostic: plain (non-compiling) executors keep
         # it always on — they have no discovery phase to front-load the
         # check into; CompilingExecutor narrows it to discovery
@@ -1844,9 +1846,20 @@ class JaxExecutor:
         if m is None:
             return self._fallback(p)
         try:
+            if self.mode == "replay":
+                # tracing the replay program: name the operator's HLO
+                # after its kind and pre-order plan-node id (metadata
+                # only)
+                with jax.named_scope(self._scope_name(p)):
+                    return m(p)
             return m(p)
         except Unsupported as u:
             return self._fallback(p, code=u.code)
+
+    def _scope_name(self, p: lp.Plan) -> str:
+        i = self._scope_ids.get(id(p))
+        kind = type(p).__name__
+        return kind if i is None else f"{kind}_{i}"
 
     # -- fallback ------------------------------------------------------------
 
@@ -3546,6 +3559,54 @@ def _cut_segments(p: lp.Plan):
     return parent, segs
 
 
+# JAX's own compile-path events -> (counter, span attribute).  A
+# backend_compile event wraps the persistent-cache lookup too: one that
+# follows a cache retrieval on its thread was a load, not a compile.
+_COMPILE_EVENTS = {
+    "/jax/core/compile/jaxpr_trace_duration":
+        ("engine.compile.trace_s", "compile_trace_s"),
+    "/jax/core/compile/jaxpr_to_mlir_module_duration":
+        ("engine.compile.lower_s", "compile_lower_s"),
+    "/jax/core/compile/backend_compile_duration":
+        ("engine.compile.xla_s", "compile_xla_s"),
+    "/jax/compilation_cache/cache_retrieval_time_sec":
+        ("engine.compile.cache_load_s", "compile_cache_load_s"),
+}
+_compile_listener_state = threading.local()
+_compile_listener_installed = False
+
+
+def _on_compile_event(event: str, duration: float, **_kw) -> None:
+    names = _COMPILE_EVENTS.get(event)
+    if names is None or not obs.enabled():
+        return
+    st = _compile_listener_state
+    if event.endswith("cache_retrieval_time_sec"):
+        st.loaded = True
+        obs.inc("engine.xla.cache_hits")
+    elif event.endswith("backend_compile_duration"):
+        if getattr(st, "loaded", False):
+            st.loaded = False
+            return      # the retrieval's seconds are already filed
+        obs.inc("engine.xla.compiles")
+    counter, attr = names
+    obs.inc(counter, duration)
+    obs.accumulate(**{attr: duration})
+
+
+def _install_compile_listener() -> None:
+    """Once per process: split ``compile_s`` into jit trace / lowering /
+    XLA compile or cache load, as JAX itself times them, and count the
+    real backend compiles (``engine.xla.compiles``: which step
+    recompiled).  The seconds also land on the span they happened in
+    (``discover_query``, or the warm-up ``replay``)."""
+    global _compile_listener_installed
+    if _compile_listener_installed:
+        return
+    _compile_listener_installed = True
+    jax.monitoring.register_event_duration_secs_listener(_on_compile_event)
+
+
 class CompilingExecutor(JaxExecutor):
     """JaxExecutor + whole-query compile cache keyed by SQL text.
 
@@ -3566,17 +3627,7 @@ class CompilingExecutor(JaxExecutor):
         # inside discovery (every query's first execution), not on
         # steady-state demoted eager aggregates
         self._in_discovery = False
-        # opt-in per-query attribution (NDSTPU_ATTRIB=1): splits a
-        # replay into host-arg-build / device-compute / result-fetch
-        # spans and records fetched bytes + XLA cost-analysis flops so
-        # a query can be classified dispatch-, transfer-, or
-        # compute-bound (the wall clock alone cannot say which —
-        # SURVEY §5: the reference has only wall-clock).  Off by
-        # default: the extra block_until_ready serializes the device
-        # pipeline.
-        self.attrib_enabled = os.environ.get(
-            "NDSTPU_ATTRIB", "0") not in ("", "0")
-        self.last_attribution: Optional[dict] = None
+        _install_compile_listener()
 
     def execute_cached(self, p: lp.Plan, key: str,
                        params: Optional[ex.ParamBinding] = None,
@@ -3714,10 +3765,10 @@ class CompilingExecutor(JaxExecutor):
         The whole replay runs under a tracer span attributed to
         ``bucket`` — ``execute_s`` normally, ``compile_s`` for the
         discovery-time warm-up call that pays the XLA compile — so the
-        harness's per-query cost split is self-labeling.  The finer
-        host-prep/device/fetch sub-split (NDSTPU_ATTRIB=1) keeps its
-        opt-in: it needs a block_until_ready that serializes the
-        device pipeline."""
+        harness's per-query cost split is self-labeling.  The span
+        carries where its host time went (``host_prep_s``,
+        ``dispatch_s``, ``device_wait_s``, ``assemble_s``); a profiler
+        trace has the same four as live ``ndstpu:replay.*`` marks."""
         with obs.span("replay", cat="plan-node", bucket=bucket,
                       n_programs=1 + len(cp.seg_fps or ())) as sp:
             result = self._replay_query_timed(cp, sp, binding)
@@ -3726,104 +3777,69 @@ class CompilingExecutor(JaxExecutor):
     def _replay_query_timed(self, cp: _CompiledPlan, sp,
                             binding: Optional[ex.ParamBinding] = None,
                             ) -> Optional[Table]:
-        attrib = self.attrib_enabled
         t_start = time.perf_counter()
         seg_args = {}
         seg_oks = []
-        seg_flop_args: list = []
-        for fp in (cp.seg_fps or ()):
-            scp = self._seg_compiled.get(fp)
-            if scp is None or scp.versions != cp.versions:
-                obs.inc("engine.cache.seg_compiled.miss")
-                return None
-            obs.inc("engine.cache.seg_compiled.hit")
-            if scp.compilable:
-                if scp.fn is None:
-                    scp.fn = self._build_jit(scp)
-                args = {t: self._accel_args(t, c)
-                        for t, c in scp.table_cols.items()}
-                args["\x00params"] = _param_args_np(scp.param_spec,
-                                                    binding)
-                if attrib:
-                    seg_flop_args.append((scp, args))
-                (out, alive), ok = scp.fn(args)
-                seg_args[_seg_argname(fp)] = (out, alive)
-                seg_oks.append(ok)
-            else:
-                # fallback-isolated segment: host numpy result, shipped
-                # to the device at the recorded output capacity (the
-                # ambient concrete _ParamCtx supplies bound values)
-                host = self.execute_to_host(scp.plan)
-                seg_args[_seg_argname(fp)] = self._seg_host_args(
-                    scp, host)
-        args = {t: self._accel_args(t, cols)
-                for t, cols in cp.table_cols.items()}
-        args["\x00params"] = _param_args_np(cp.param_spec, binding)
-        args.update(seg_args)
+        with obs.annotation("replay.prep"):
+            for fp in (cp.seg_fps or ()):
+                scp = self._seg_compiled.get(fp)
+                if scp is None or scp.versions != cp.versions:
+                    obs.inc("engine.cache.seg_compiled.miss")
+                    return None
+                obs.inc("engine.cache.seg_compiled.hit")
+                if scp.compilable:
+                    if scp.fn is None:
+                        scp.fn = self._build_jit(scp)
+                    args = {t: self._accel_args(t, c)
+                            for t, c in scp.table_cols.items()}
+                    args["\x00params"] = _param_args_np(scp.param_spec,
+                                                        binding)
+                    (out, alive), ok = scp.fn(args)
+                    seg_args[_seg_argname(fp)] = (out, alive)
+                    seg_oks.append(ok)
+                else:
+                    # fallback-isolated segment: host numpy result,
+                    # shipped to the device at the recorded output
+                    # capacity (the ambient concrete _ParamCtx supplies
+                    # bound values)
+                    host = self.execute_to_host(scp.plan)
+                    seg_args[_seg_argname(fp)] = self._seg_host_args(
+                        scp, host)
+            args = {t: self._accel_args(t, cols)
+                    for t, cols in cp.table_cols.items()}
+            args["\x00params"] = _param_args_np(cp.param_spec, binding)
+            args.update(seg_args)
         t_dispatch = time.perf_counter()
-        (out, alive), ok = cp.fn(args)
-        if attrib:
-            # serialize: device span ends when every output is ready,
-            # fetch span is then the pure device->host transfer
-            jax.block_until_ready(((out, alive), ok))
-            t_ready = time.perf_counter()
-        (out, alive_np), okv, seg_okv = jax.device_get(
-            ((out, alive), ok, seg_oks))
+        with obs.annotation("replay.dispatch"):
+            (out, alive), ok = cp.fn(args)
+        t_called = time.perf_counter()
+        # the device runs from here (one replay in flight: the host
+        # waits in device_get for the programs and the D2H copy)
+        with obs.annotation("replay.device_wait"):
+            (out, alive_np), okv, seg_okv = jax.device_get(
+                ((out, alive), ok, seg_oks))
         t_fetched = time.perf_counter()
         fetched = int(alive_np.nbytes) + sum(
             d.nbytes + v.nbytes for d, v in out.values())
         obs.inc("engine.fetched_bytes", fetched)
-        sp.set(host_prep_s=round(t_dispatch - t_start, 5),
-               fetched_bytes=fetched)
-        if attrib:
-            attribution = {
-                "host_prep_s": round(t_dispatch - t_start, 5),
-                "device_s": round(t_ready - t_dispatch, 5),
-                "fetch_s": round(t_fetched - t_ready, 5),
-                "fetched_bytes": fetched,
-                "n_programs": 1 + len(cp.seg_fps or ()),
-                "flops": self._cost_flops(cp, args, seg_flop_args),
-            }
-            self.last_attribution = attribution
-            sp.set(device_s=attribution["device_s"],
-                   fetch_s=attribution["fetch_s"],
-                   flops=attribution["flops"])
-        if not (bool(okv) and all(bool(o) for o in seg_okv)):
-            return None
-        for fp in (cp.seg_fps or ()):
-            scp = self._seg_compiled.get(fp)
-            if scp is not None:
-                scp.preloaded = False
-                scp.fn_validated = True
-        return self._assemble_host(cp, out, alive_np)
-
-    def _cost_flops(self, cp: _CompiledPlan, args,
-                    seg_flop_args) -> Optional[float]:
-        """XLA cost-analysis flops of the parent + compiled segment
-        programs (drives MFU = flops / device_s / peak_flops).  Each
-        program is re-lowered ONCE to reach cost_analysis (tracing can
-        take seconds on CTE-heavy queries), then cached on its
-        _CompiledPlan.  None when the backend offers no analysis."""
-
-        def one(plan_cp, plan_args) -> float:
-            cached = getattr(plan_cp, "cost_flops", None)
-            if cached is not None:
-                return cached
-            an = plan_cp.fn.lower(plan_args).compile().cost_analysis()
-            if isinstance(an, (list, tuple)):
-                flops = sum(float(d.get("flops", 0.0)) for d in an if d)
-            else:
-                flops = float(an.get("flops", 0.0))
-            plan_cp.cost_flops = flops
-            return flops
-
-        try:
-            total = one(cp, args)
-            for scp, sargs in seg_flop_args:
-                total += one(scp, sargs)
-            return total
-        except Exception:
-            return None
+        result = None
+        if bool(okv) and all(bool(o) for o in seg_okv):
+            for fp in (cp.seg_fps or ()):
+                scp = self._seg_compiled.get(fp)
+                if scp is not None:
+                    scp.preloaded = False
+                    scp.fn_validated = True
+            with obs.annotation("replay.assemble"):
+                result = self._assemble_host(cp, out, alive_np)
+        t_end = time.perf_counter()
+        obs.inc("engine.replay.device_wait_s", t_fetched - t_called)
+        if sp is not obs.NULL_SPAN:
+            sp.set(host_prep_s=round(t_dispatch - t_start, 5),
+                   dispatch_s=round(t_called - t_dispatch, 6),
+                   device_wait_s=round(t_fetched - t_called, 6),
+                   assemble_s=round(t_end - t_fetched, 6),
+                   fetched_bytes=fetched)
+        return result
 
     @staticmethod
     def _assemble_host(cp: _CompiledPlan, out, alive_np) -> Table:
@@ -4289,6 +4305,8 @@ class CompilingExecutor(JaxExecutor):
             self._pos = 0
             self._oks = []
             self._rec = cp.record
+            self._scope_ids = {id(n): i
+                               for i, n in enumerate(cp.plan.walk())}
             self._trace_tables = {}
             for name, entry in tables.items():
                 if name == "\x00params":
@@ -4330,6 +4348,7 @@ class CompilingExecutor(JaxExecutor):
             finally:
                 self.mode = "eager"
                 self._trace_tables = None
+                self._scope_ids = {}
             out = {name: (c.data, c.valid) for name, c in dt.columns.items()}
             return (out, dt.alive), ok
 

@@ -9,6 +9,7 @@ nds_maintenance.py:107-116).
 
 from __future__ import annotations
 
+import time
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional
 
@@ -147,9 +148,14 @@ class Session:
         DDL.  With ``pin`` (from :meth:`pin_snapshot`), a query runs
         against that frozen catalog epoch regardless of concurrent
         ingest commits — DML/DDL under a pin is an error."""
+        from ndstpu import obs
         from ndstpu.engine.sql import normalize_sql_key
-        stmt = parse_statement(text)
-        return self._run(stmt, key=normalize_sql_key(text), pin=pin)
+        # before the statement span: a short query's parse is a tenth
+        # of its wall, and unbucketed it went unattributed
+        with obs.span("parse", cat="plan-node", bucket="execute_s"):
+            stmt = parse_statement(text)
+            key = normalize_sql_key(text)
+        return self._run(stmt, key=key, pin=pin)
 
     def sql_script(self, text: str) -> List[Optional[columnar.Table]]:
         return [self._run(s) for s in parse_statements(text)]
@@ -208,13 +214,13 @@ class Session:
         # per-query compile/execute split needs no bookkeeping here
         from ndstpu import obs
         with obs.span("statement", cat="plan-node", bucket="execute_s",
-                      kind=type(stmt).__name__, backend=self.backend):
-            return self._run_traced(stmt, key, pin)
+                      kind=type(stmt).__name__,
+                      backend=self.backend) as sp:
+            return self._run_traced(stmt, key, pin, sp)
 
     def _run_traced(self, stmt: ast.Node,
-                    key: Optional[str] = None,
-                    pin: Optional[SnapshotPin] = None
-                    ) -> Optional[columnar.Table]:
+                    key: Optional[str], pin: Optional[SnapshotPin],
+                    sp) -> Optional[columnar.Table]:
         if isinstance(stmt, ast.Query):
             plan, disp, canon = self._plan_cached(stmt, key, pin)
             if canon is not None:
@@ -229,7 +235,12 @@ class Session:
             # execution serialized (see __post_init__): the executor's
             # per-query mutable state is not safe under concurrent
             # statements, and one device runs programs serially anyway
+            t_lock = time.perf_counter()
             with self._exec_lock:
+                # 0 behind a one-slot gate; streams over one Session
+                # (inproc throughput) wait here for one another
+                sp.set(exec_lock_wait_s=round(
+                    time.perf_counter() - t_lock, 6))
                 if getattr(self, "spine_cache", None) is not None:
                     plan, canon = self._splice_spines(plan, canon, key,
                                                       pin)

@@ -88,9 +88,11 @@ class InprocAdmission:
     """In-process ``slots`` semantics of :class:`DeviceAdmission` for
     the inproc throughput scheduler (ndstpu/harness/scheduler.py): the
     stream workers are threads in ONE process, so a plain semaphore
-    replaces the lock files.  Tracks the observed concurrency peak and
-    per-acquisition device intervals — the committed evidence that at
-    most ``slots`` queries held the device at once."""
+    replaces the lock files.  Tracks the observed concurrency peak —
+    the committed evidence that at most ``slots`` queries held the
+    device at once — and running sums of the holds (a daemon holds one
+    of these for its lifetime, so nothing here grows; each hold's start
+    and length is the ``gate_hold`` span's to keep)."""
 
     def __init__(self, slots: int):
         if slots < 1:
@@ -103,7 +105,8 @@ class InprocAdmission:
         self._active = 0
         self.max_active = 0
         self.wait_s_total = 0.0
-        self.intervals = []  # (t_acquired, t_released) epoch pairs
+        self.busy_s_total = 0.0
+        self.gated_total = 0
 
     def acquire(self) -> int:
         t0 = time.time()
@@ -122,7 +125,8 @@ class InprocAdmission:
         with self._mu:
             self._active -= 1
             if t0 is not None:
-                self.intervals.append((t0, time.time()))
+                self.gated_total += 1
+                self.busy_s_total += time.time() - t0
         self._sem.release()
 
     @contextlib.contextmanager
@@ -136,14 +140,13 @@ class InprocAdmission:
     def device_timeline(self) -> dict:
         """Admission-level overlap evidence for the overlap report."""
         with self._mu:
-            ivs = list(self.intervals)
-        return {
-            "slots": self.slots,
-            "max_concurrent": self.max_active,
-            "gated_queries": len(ivs),
-            "busy_s_total": round(sum(b - a for a, b in ivs), 3),
-            "wait_s_total": round(self.wait_s_total, 3),
-        }
+            return {
+                "slots": self.slots,
+                "max_concurrent": self.max_active,
+                "gated_queries": self.gated_total,
+                "busy_s_total": round(self.busy_s_total, 3),
+                "wait_s_total": round(self.wait_s_total, 3),
+            }
 
 
 def from_env() -> Optional[DeviceAdmission]:

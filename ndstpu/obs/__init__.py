@@ -10,8 +10,10 @@ package's module-level facade over one process-global tracer:
     obs.inc("engine.cache.compiled.hit")
 
 Default ON; ``NDSTPU_TRACE=0`` disables everything (spans become a
-shared no-op, instruments early-return).  See docs/OBSERVABILITY.md for
-the span model, instrument catalog, and export formats.
+shared no-op, instruments early-return).  Where jax is loaded, open
+spans are also ``ndstpu:<name>`` annotations in any profiler trace.
+See docs/OBSERVABILITY.md for the span model, instrument catalog, and
+export formats.
 """
 
 from __future__ import annotations
@@ -23,8 +25,10 @@ from ndstpu.obs.trace import NULL_SPAN, Span, Tracer, env_enabled
 
 __all__ = [
     "Tracer", "Span", "NULL_SPAN", "env_enabled", "tracer", "enabled",
-    "span", "record", "add_time", "annotate", "inc", "set_gauge",
+    "span", "record", "annotation", "add_time", "annotate", "accumulate",
+    "inc", "set_gauge",
     "counters_snapshot", "gauges_snapshot", "counter_delta",
+    "finished", "events_since",
     "export_jsonl", "export_chrome", "export_run", "run_metrics",
     "reset",
 ]
@@ -59,12 +63,28 @@ def record(name: str, cat: str, t0_epoch: float, wall_s: float,
     _TRACER.record(name, cat, t0_epoch, wall_s, **attrs)
 
 
+def annotation(name: str, **stats):
+    return _TRACER.annotation(name, **stats)
+
+
 def add_time(bucket: str, seconds: float) -> None:
     _TRACER.add_time(bucket, seconds)
 
 
 def annotate(**attrs) -> None:
     _TRACER.annotate(**attrs)
+
+
+def accumulate(**amounts: float) -> None:
+    _TRACER.accumulate(**amounts)
+
+
+def finished() -> int:
+    return _TRACER.finished()
+
+
+def events_since(position: int) -> list:
+    return _TRACER.events_since(position)
 
 
 def inc(name: str, value: float = 1) -> None:

@@ -31,14 +31,30 @@ so worker-thread engine spans still land in the open query span.
 Clocks: durations are ``time.perf_counter`` deltas (monotonic); every
 span also records an epoch-anchored start timestamp so traces from
 concurrent processes (throughput streams) can be laid side by side.
+
+One clock with the device trace: where ``jax`` is already loaded in the
+process (this module never imports it), every open span also holds a
+``jax.profiler.TraceAnnotation("ndstpu:<name>")``, so any profiler
+trace taken of the process carries the program's spans on the trace's
+own clock.  Outside a profiler session a TraceMe is a flag test.
+
+Bounded: a long-lived process (the serve daemon) keeps the newest
+``max_events`` finished spans; older ones are dropped from the front in
+blocks and counted in ``obs.spans.dropped``.  A reader of a window
+takes ``finished()`` before it and ``events_since()`` after: an index
+into ``events`` shifts with every dropped block.
 """
 
 from __future__ import annotations
 
 import os
+import sys
 import threading
 import time
 from typing import Dict, List, Optional
+
+ANNOTATION_PREFIX = "ndstpu:"
+DEFAULT_MAX_EVENTS = 100_000
 
 
 def env_enabled() -> bool:
@@ -67,6 +83,20 @@ class _NullSpan:
 
 NULL_SPAN = _NullSpan()
 
+_TRACEME = None
+
+
+def _traceme():
+    """``jax.profiler.TraceAnnotation`` if jax is loaded, else None.
+    Looked up through ``sys.modules`` so that a process that must not
+    touch JAX (the benchmark's client) never does through this module."""
+    global _TRACEME
+    if _TRACEME is None:
+        jax = sys.modules.get("jax")
+        prof = getattr(jax, "profiler", None)
+        _TRACEME = getattr(prof, "TraceAnnotation", None)
+    return _TRACEME
+
 
 class Span:
     """One timed region.  Context manager; not reusable."""
@@ -74,7 +104,7 @@ class Span:
     __slots__ = ("tracer", "name", "cat", "bucket", "collect", "attrs",
                  "parent", "collector", "parent_collector", "buckets",
                  "child_bucketed_s", "t0", "t0_epoch", "wall_s", "tid",
-                 "depth", "seq")
+                 "depth", "seq", "mark")
 
     def __init__(self, tracer: "Tracer", name: str, cat: str,
                  bucket: Optional[str], collect: bool, attrs: dict):
@@ -106,12 +136,15 @@ class Span:
         stack.append(self)
         self.tid = threading.get_ident()
         self.seq = t._next_seq()
+        self.mark = t.annotation(self.name)
+        self.mark.__enter__()
         self.t0_epoch = time.time()
         self.t0 = time.perf_counter()
         return self
 
     def __exit__(self, exc_type, exc, tb):
         t1 = time.perf_counter()
+        self.mark.__exit__(None, None, None)
         t = self.tracer
         stack = t._stack()
         while stack and stack.pop() is not self:
@@ -152,13 +185,16 @@ class Span:
 class Tracer:
     """Process-global span recorder + counter/gauge registry."""
 
-    def __init__(self, enabled: Optional[bool] = None):
+    def __init__(self, enabled: Optional[bool] = None,
+                 max_events: int = DEFAULT_MAX_EVENTS):
         self.enabled = env_enabled() if enabled is None else enabled
         self._lock = threading.Lock()
         self._local = threading.local()
         self._fallback_collector: Optional[Span] = None
         self._seq = 0
-        self.events: List[dict] = []      # finished spans, end order
+        # finished spans, end order: the newest max_events of them
+        self.events: List[dict] = []
+        self.max_events = max_events
         self.counters: Dict[str, float] = {}
         self.gauges: Dict[str, float] = {}
         self.pid = os.getpid()
@@ -174,13 +210,28 @@ class Tracer:
             return NULL_SPAN
         return Span(self, name, cat, bucket, collect, attrs)
 
+    def annotation(self, name: str, **stats):
+        """A live profiler annotation ``ndstpu:<name>`` (a context
+        manager), or the no-op span where the tracer is off or the
+        process has not loaded jax."""
+        if not self.enabled:
+            return NULL_SPAN
+        traceme = _traceme()
+        if traceme is None:
+            return NULL_SPAN
+        return traceme(ANNOTATION_PREFIX + name, **stats)
+
     def record(self, name: str, cat: str, t0_epoch: float,
                wall_s: float, **attrs) -> None:
         """Log an already-measured region (explicit timestamps) — for
         overlapping regions a context manager cannot express, e.g. the
-        throughput wrapper's concurrent stream processes."""
+        throughput wrapper's concurrent stream processes.  A profiler
+        trace gets the region as an instant ``ndstpu:<name>`` mark that
+        carries ``wall_us`` (a TraceMe cannot be opened in the past)."""
         if not self.enabled:
             return
+        with self.annotation(name, wall_us=int(wall_s * 1e6)):
+            pass
         self._append_event({
             "name": name, "cat": cat, "ph": "X",
             "ts_epoch_s": round(t0_epoch, 6),
@@ -212,6 +263,19 @@ class Tracer:
         coll = stack[-1].collector if stack else self._fallback_collector
         if coll is not None:
             coll.attrs.update(attrs)
+
+    def accumulate(self, **amounts: float) -> None:
+        """Add amounts to numeric attributes of the innermost span open
+        on this thread (nothing where none is) — e.g. the compile
+        listener filing JAX's trace / lower / compile seconds under the
+        ``discover_query`` or warm-up ``replay`` span they happened in."""
+        if not self.enabled:
+            return
+        stack = self._stack()
+        if stack:
+            attrs = stack[-1].attrs
+            for k, v in amounts.items():
+                attrs[k] = round(attrs.get(k, 0.0) + v, 6)
 
     # -- instruments ----------------------------------------------------------
 
@@ -268,6 +332,28 @@ class Tracer:
     def _append_event(self, ev: dict) -> None:
         with self._lock:
             self.events.append(ev)
+            # trimmed in blocks (a tenth of the cap over it), so the
+            # list shifts once per block and not once per span
+            over = len(self.events) - self.max_events
+            if over > 0 and over >= self.max_events // 10:
+                del self.events[:over]
+                self.counters["obs.spans.dropped"] = \
+                    self.counters.get("obs.spans.dropped", 0) + over
+
+    def finished(self) -> int:
+        """How many spans have finished, the dropped ones included: a
+        position in the stream of events that trimming does not move
+        (an index into ``events`` shifts with every trimmed block)."""
+        with self._lock:
+            return int(self.counters.get("obs.spans.dropped", 0)) \
+                + len(self.events)
+
+    def events_since(self, position: int) -> List[dict]:
+        """The spans finished after ``position`` (a ``finished()``
+        reading) that are still kept."""
+        with self._lock:
+            dropped = int(self.counters.get("obs.spans.dropped", 0))
+            return self.events[max(0, position - dropped):]
 
     # -- aggregation ----------------------------------------------------------
 

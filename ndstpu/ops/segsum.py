@@ -134,6 +134,7 @@ def segment_sum_f32(vals: jnp.ndarray, gid: jnp.ndarray,
             out_specs=pl.BlockSpec((1, block_segs), lambda j, i: (0, j)),
             out_shape=jax.ShapeDtypeStruct((1, s_pad), jnp.float32),
             interpret=interpret,
+            name="segsum_f32",
         )(v2, g2)
     return out[0, :num_segments]
 
@@ -217,6 +218,7 @@ def segment_sum_decimal(vals: jnp.ndarray, gid: jnp.ndarray,
             out_shape=jax.ShapeDtypeStruct((_N_LIMBS + 1, s_pad),
                                            jnp.int32),
             interpret=interpret,
+            name="segsum_limb",
         )(lv, g2)
     out = out[:, :num_segments].astype(jnp.int64)
     counts = out[_N_LIMBS]

@@ -69,11 +69,13 @@ def _recv_exact(sock: socket.socket, n: int) -> Optional[bytes]:
     return bytes(buf)
 
 
-def send_msg(sock: socket.socket, obj: dict) -> None:
+def send_msg(sock: socket.socket, obj: dict) -> int:
+    """Send one frame; returns the bytes written (header + payload)."""
     payload = json.dumps(obj, default=str).encode("utf-8")
     if len(payload) > MAX_FRAME_BYTES:
         raise ProtocolError(f"frame too large: {len(payload)} bytes")
     sock.sendall(_LEN.pack(len(payload)) + payload)
+    return _LEN.size + len(payload)
 
 
 def recv_msg(sock: socket.socket) -> Optional[dict]:

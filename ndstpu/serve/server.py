@@ -108,9 +108,9 @@ class _Conn:
         self.reader: Optional[threading.Thread] = None
         self.executor: Optional[threading.Thread] = None
 
-    def send(self, obj: dict) -> None:
+    def send(self, obj: dict) -> int:
         with self.wlock:
-            protocol.send_msg(self.sock, obj)
+            return protocol.send_msg(self.sock, obj)
 
 
 class QueryServer:
@@ -305,7 +305,11 @@ class QueryServer:
         timeout = self.config.resolved_timeout_s() + 30.0
         for conn in conns:
             th = conn.executor
-            if th is not None and th is not threading.current_thread():
+            # a connection accepted as the drain began may not have
+            # started its executor yet: its stream is closed, it will
+            # find nothing to run
+            if th is not None and th.ident is not None \
+                    and th is not threading.current_thread():
                 th.join(timeout)
         inflight_done = obs.counters_snapshot().get("serve.ok", 0)
         self._flush(reason)
@@ -522,9 +526,14 @@ class QueryServer:
         """Run one admitted request end to end; returns failed?"""
         rid, tenant, canon = req["id"], req["tenant"], req["canon"]
         name = req.get("name") or rid
+        t0 = time.time()
+        # admission's reply to this executor picking the request up:
+        # the scheduler's hand-over, or the connection's executor busy
+        # with the request before
+        obs.record("admit_wait", "serve", req["admitted_at"],
+                   t0 - req["admitted_at"], id=rid, tenant=tenant)
         qspan = obs.span(name, cat="query", collect=True,
                          tenant=tenant, serve=1)
-        t0 = time.time()
         try:
             # chaos-only: an injected replica crash takes the WHOLE
             # process down mid-flight (fleet_smoke scenario 2 without
@@ -568,55 +577,79 @@ class QueryServer:
         finally:
             self.queue.release()
         wall = qspan.wall_s or (time.time() - t0)
-        obs.inc("serve.ok")
-        self.breaker.note_success(canon)
-        self.queue.observe(wall)  # EWMA behind retry_after_s hints
-        self.slo.record(tenant, wall, "ok")
-        self.journal.mark_query(name, req["sql"], canon_key=canon)
-        self._persist_compiled()
-        self._ledger_append(name, tenant, qspan)
-        resp = {"status": "ok", "id": rid,
-                "wall_s": round(wall, 6), "attempts": attempts}
-        resp.update(result)
-        try:
-            conn.send(resp)
-        except OSError:
-            pass  # client gone; work is journaled regardless
+        # from the query span's end to the reply sent: journal mark,
+        # compile-record persist, ledger append, serialise + send
+        with obs.span("reply_tail", cat="serve", id=rid) as tail:
+            obs.inc("serve.ok")
+            self.breaker.note_success(canon)
+            self.queue.observe(wall)  # EWMA behind retry_after_s hints
+            self.slo.record(tenant, wall, "ok")
+            self.journal.mark_query(name, req["sql"], canon_key=canon)
+            self._persist_compiled()
+            self._ledger_append(name, tenant, qspan)
+            resp = {"status": "ok", "id": rid,
+                    "wall_s": round(wall, 6), "attempts": attempts}
+            resp.update(result)
+            try:
+                tail.set(reply_bytes=conn.send(resp))
+            except OSError:
+                pass  # client gone; work is journaled regardless
         return False
 
     def _run_guarded(self, req: dict) -> dict:
         """One attempt, under the device gate + watchdog."""
         timeout = self.config.resolved_timeout_s()
-        with self.gate.slot():
-            if timeout <= 0:
-                return self._run_query(self.session, req)
-            slot: dict = {}
-            with self._session_lock:
-                sess = self.session
+        # canon's head (the fingerprint) groups a span dump by template
+        ids = {"id": req["id"], "canon": req["canon"][:24]}
+        with obs.span("gate_wait", cat="serve", **ids):
+            self.gate.acquire()     # the pure wait for the one device
+        try:
+            # the queue's service time: pin + statement + row conversion
+            with obs.span("gate_hold", cat="serve", **ids) as hold:
+                return self._run_held(req, timeout, hold)
+        finally:
+            self.gate.release()
 
-            def work():
-                try:
-                    slot["result"] = self._run_query(sess, req)
-                except Exception as e:  # noqa: BLE001
-                    slot["err"] = e
+    def _run_held(self, req: dict, timeout: float, hold) -> dict:
+        """The body of one attempt, under the device slot."""
+        if timeout <= 0:
+            return self._run_query(self.session, req)
+        slot: dict = {}
+        with self._session_lock:
+            sess = self.session
 
-            th = threading.Thread(target=work, daemon=True,
-                                  name=f"serve-q-{req['id']}")
-            th.start()
-            th.join(timeout)
-            if th.is_alive():
-                # power watchdog idiom: abandon the wedged thread and
-                # swap every future request onto a fresh session — the
-                # drain path depends on this never blocking forever
-                self._zombies.append({"th": th, "name": req["id"]})
-                obs.inc("serve.watchdog.abandoned")
-                self._swap_session(sess)
-                raise TimeoutError(
-                    f"{req['id']} hung > {timeout:.0f}s; abandoned "
-                    f"(server continues on a fresh session)")
-            if "err" in slot:
-                raise slot["err"]
-            return slot["result"]
+        def work():
+            t_body = time.perf_counter()
+            try:
+                slot["result"] = self._run_query(sess, req)
+            except Exception as e:  # noqa: BLE001
+                slot["err"] = e
+            finally:
+                slot["body_s"] = time.perf_counter() - t_body
+
+        th = threading.Thread(target=work, daemon=True,
+                              name=f"serve-q-{req['id']}")
+        t_handed = time.perf_counter()
+        th.start()
+        th.join(timeout)
+        if "body_s" in slot:
+            # what the watchdog costs while the slot is held: starting
+            # its thread and waking from the join
+            hold.set(handover_s=round(
+                time.perf_counter() - t_handed - slot["body_s"], 6))
+        if th.is_alive():
+            # power watchdog idiom: abandon the wedged thread and
+            # swap every future request onto a fresh session — the
+            # drain path depends on this never blocking forever
+            self._zombies.append({"th": th, "name": req["id"]})
+            obs.inc("serve.watchdog.abandoned")
+            self._swap_session(sess)
+            raise TimeoutError(
+                f"{req['id']} hung > {timeout:.0f}s; abandoned "
+                f"(server continues on a fresh session)")
+        if "err" in slot:
+            raise slot["err"]
+        return slot["result"]
 
     def _swap_session(self, old: Session) -> None:
         with self._session_lock:
@@ -640,11 +673,12 @@ class QueryServer:
         """Execute snapshot-pinned; write or collect the result."""
         sql = req["sql"]
         pin = None
-        try:
-            if isinstance(parse_statement(sql), ast.Query):
-                pin = session.pin_snapshot()
-        except Exception:  # noqa: BLE001 — let sql() raise properly
-            pass
+        with obs.span("pin", cat="serve"):
+            try:
+                if isinstance(parse_statement(sql), ast.Query):
+                    pin = session.pin_snapshot()
+            except Exception:  # noqa: BLE001 — let sql() raise properly
+                pass
         result = session.sql(sql, pin=pin)
         if result is None:
             return {"rows": 0, "ddl": True}
@@ -667,7 +701,7 @@ class QueryServer:
                 raise ValueError(f"unsupported output format "
                                  f"{self.config.output_format}")
             return {"rows": result.num_rows, "output": safe}
-        rows = result.to_rows()
+        rows = result.to_rows()     # its own span (cat plan-node)
         cap = int(req.get("max_rows") or 100)
         return {"rows": len(rows),
                 "columns": list(result.columns),
@@ -743,6 +777,9 @@ class QueryServer:
             if self.session is not None else 0,
             "zombies": sum(1 for z in self._zombies
                            if z["th"].is_alive()),
+            # bounded (obs/trace.py); the gate keeps sums alone
+            "trace_events": len(obs.tracer().events),
+            "gated_queries": self.gate.gated_total,
             "requests": c.get("serve.requests", 0),
             "ok": c.get("serve.ok", 0),
             "errors": c.get("serve.errors", 0),

@@ -402,12 +402,21 @@ def test_hw_metrics_default_path(tmp_path, fresh_tracer):
     assert json.load(open(path))["power"] is None
 
 
+@pytest.mark.parametrize("process", ["as-found", "jit-caches-warm"])
 def test_power_run_emits_traces_and_sidecar(tmp_path, monkeypatch,
-                                            fresh_tracer):
+                                            fresh_tracer, process):
     """Acceptance shape: a power run over 5 queries produces the JSONL
     trace, the Chrome trace, and the metrics sidecar whose per-query
     compile_s + execute_s accounts for >=90% of wall, with cache
-    counters distinguishing the cold run."""
+    counters distinguishing the cold run.
+
+    The second case runs in a process whose in-memory jit caches
+    already hold these programs (the first case, or under xdist any
+    earlier file of the worker, put them there): a query then takes
+    10-40 ms instead of seconds, and whatever the query span holds
+    outside a bucketed span -- once the parse before ``statement`` and
+    the ``to_rows`` collect, a millisecond each -- is a tenth of it.
+    Both are bucketed spans now; the bar holds for short queries too."""
     import argparse
 
     from ndstpu.harness import power
@@ -500,3 +509,200 @@ def test_exchange_collective_counters(fresh_tracer):
     local = x.size // n_dev
     assert delta.get("exchange.shuffle_bytes") == \
         local * 4 * n_dev * (n_dev - 1)
+
+
+# -- a replay accounts for its own time ---------------------------------------
+
+
+def _replay_session(sql=FIVE_QUERIES[3]):      # join + aggregate + sort
+    sess = Session(tiny_catalog(), backend="tpu")
+    first = sess.sql(sql).to_rows()
+    return sess, sql, first
+
+
+def test_replay_phases_sum_to_its_wall(fresh_tracer):
+    sess, sql, first = _replay_session()
+    n0 = obs.finished()
+    before = obs.counters_snapshot()
+    assert sess.sql(sql).to_rows() == first
+    evs = obs.events_since(n0)
+    (replay,) = [e for e in evs if e["name"] == "replay"]
+    a = replay["args"]
+    phases = [a["host_prep_s"], a["dispatch_s"], a["device_wait_s"],
+              a["assemble_s"]]
+    assert all(p >= 0 for p in phases)
+    # four readings of one clock inside the span: they miss only the
+    # span's own enter and exit
+    assert sum(phases) <= replay["wall_s"] + 1e-4
+    assert replay["wall_s"] - sum(phases) < 0.005
+    # the attributes are the record; a profiler trace has the phases as
+    # live marks, and the span list nothing more
+    assert not [e for e in evs if e["name"].startswith("replay.")]
+    moved = obs.counter_delta(before)
+    assert moved["engine.replay.device_wait_s"] == pytest.approx(
+        a["device_wait_s"], abs=2e-6)
+    # the statement says how long it waited for the execution lock
+    (stmt,) = [e for e in evs if e["name"] == "statement"]
+    assert 0 <= stmt["args"]["exec_lock_wait_s"] < 0.05
+    # and the collect is a span of its own
+    (rows,) = [e for e in evs if e["name"] == "to_rows"]
+    assert rows["cat"] == "plan-node" and rows["args"]["rows"] == len(first)
+
+
+def test_trace_off_replay_leaves_nothing_behind():
+    """NDSTPU_TRACE=0: no span, no counter, no annotation, and the same
+    answers from discovery and from replay."""
+    obs.reset(enabled=True)
+    _sess, _sql, want = _replay_session()
+    tr = obs.reset(enabled=False)
+    try:
+        sess, sql, first = _replay_session()
+        second = sess.sql(sql).to_rows()
+        assert first == want and second == want
+        assert tr.events == [] and tr.counters == {} and tr.gauges == {}
+        assert obs.annotation("x") is obs.NULL_SPAN
+    finally:
+        obs.reset()
+
+
+def test_compile_listener_counts_cold_compiles_only(fresh_tracer):
+    before = obs.counters_snapshot()
+    # a shape no other test of this process compiles: JAX keeps
+    # executables of programs it has seen, whatever Session asks
+    sess, sql, _first = _replay_session(
+        "select s_qty, min(s_price) as p, count(*) as n from sales, item "
+        "where s_item_sk = i_item_sk and i_brand_id < 2 "
+        "group by s_qty order by s_qty")
+    cold = obs.counter_delta(before)
+    assert cold["engine.xla.compiles"] >= 1
+    assert cold["engine.compile.xla_s"] > 0
+    assert cold["engine.compile.trace_s"] > 0
+    assert cold["engine.compile.lower_s"] > 0
+    # the seconds are filed under the span they happened in
+    (disc,) = [e for e in fresh_tracer.events
+               if e["name"] == "discover_query"]
+    assert disc["args"]["compile_xla_s"] > 0
+    spent = sum(disc["args"].get(k, 0.0) for k in (
+        "compile_trace_s", "compile_lower_s", "compile_xla_s",
+        "compile_cache_load_s"))
+    assert spent <= disc["wall_s"] + 1e-3
+    # the tests run without the warm-up replay (tests/conftest.py), so
+    # the first replay is the step that compiles the whole-query
+    # program -- and says so
+    mid = obs.counters_snapshot()
+    sess.sql(sql).to_rows()
+    first_replay = obs.counter_delta(mid)
+    assert first_replay["engine.xla.compiles"] >= 1
+    (replay,) = [e for e in fresh_tracer.events if e["name"] == "replay"]
+    assert replay["args"]["compile_xla_s"] > 0
+    assert replay["args"]["compile_trace_s"] > 0
+    assert replay["args"]["dispatch_s"] >= replay["args"]["compile_xla_s"]
+    # a steady replay compiles nothing
+    mid = obs.counters_snapshot()
+    sess.sql(sql).to_rows()
+    warm = obs.counter_delta(mid)
+    assert not any(k.startswith(("engine.xla.", "engine.compile."))
+                   for k in warm), warm
+
+
+def test_replay_program_names_its_operators(fresh_tracer):
+    from ndstpu.engine import jaxexec
+    sess, sql, _first = _replay_session()
+    cp = sess.compiled_plan(sql)
+    exe = sess._jax_exec_cache
+    args = {t: exe._accel_args(t, c) for t, c in cp.table_cols.items()}
+    args["\x00params"] = jaxexec._param_args_np(cp.param_spec, None)
+    text = cp.fn.lower(args).as_text(debug_info=True)
+    kinds = {type(n).__name__: i for i, n in enumerate(cp.plan.walk())}
+    for kind in ("Join", "Aggregate", "Sort"):
+        assert f"{kind}_{kinds[kind]}/" in text, kind
+    # metadata only: without debug info the program text has no scope
+    assert "Join_" not in cp.fn.lower(args).as_text()
+
+
+@pytest.mark.parametrize("kernel", ["segsum_f32", "segsum_limb"])
+def test_pallas_kernels_carry_their_names(kernel):
+    import jax
+    import jax.numpy as jnp
+
+    from ndstpu.ops import segsum
+    fn = {"segsum_f32": segsum.segment_sum_f32,
+          "segsum_limb": segsum.segment_sum_decimal}[kernel]
+    dtype = jnp.float32 if kernel == "segsum_f32" else jnp.int32
+    lowered = jax.jit(lambda v, g: fn(v, g, g >= 0, 8, interpret=True)
+                      ).lower(jnp.ones(2048, dtype),
+                              jnp.zeros(2048, jnp.int32))
+    assert f'"{kernel}/' in lowered.as_text(debug_info=True)
+
+
+# -- one clock with the device trace ------------------------------------------
+
+
+def test_spans_are_profiler_annotations_where_jax_is_loaded(fresh_tracer):
+    import jax
+    with obs.span("outer") as sp:
+        assert isinstance(sp.mark, jax.profiler.TraceAnnotation)
+    assert isinstance(obs.annotation("replay.device_wait"),
+                      jax.profiler.TraceAnnotation)
+    obs.record("admit_wait", "serve", 1.0, 0.002, id="r1")   # no raise
+    assert fresh_tracer.events[-1]["name"] == "admit_wait"
+
+
+def test_obs_never_imports_jax():
+    """The benchmark's client process uses ndstpu.serve.client and must
+    not touch JAX: spans there are plain spans."""
+    import subprocess
+    import sys
+    code = ("import sys\n"
+            "from ndstpu import obs\n"
+            "from ndstpu.serve import client\n"
+            "with obs.span('x') as sp:\n"
+            "    assert sp.mark is obs.NULL_SPAN\n"
+            "obs.record('y', 'serve', 1.0, 0.1)\n"
+            "assert len(obs.tracer().events) == 2\n"
+            "assert 'jax' not in sys.modules\n")
+    subprocess.run([sys.executable, "-c", code], check=True, timeout=120)
+
+
+# -- bounded in a long-lived process ------------------------------------------
+
+
+def test_tracer_keeps_the_newest_spans():
+    tr = obs.trace.Tracer(enabled=True, max_events=100)
+    for i in range(250):
+        tr.record(f"s{i}", "op", float(i), 0.0)
+    # trimmed in blocks of a tenth of the cap: never over cap + block
+    assert 100 <= len(tr.events) < 110 + 1
+    assert tr.events[-1]["name"] == "s249"
+    assert tr.events[0]["name"] == f"s{250 - len(tr.events)}"
+    assert tr.counters["obs.spans.dropped"] == 250 - len(tr.events)
+    assert obs.trace.Tracer().max_events == 100_000
+
+
+@pytest.mark.parametrize("before", [0, 95, 130, 250])
+def test_a_window_of_spans_survives_the_trim(before):
+    """A position taken with finished() still cuts the same window
+    after blocks were dropped from the front; an index would not."""
+    tr = obs.trace.Tracer(enabled=True, max_events=100)
+    for i in range(before):
+        tr.record(f"s{i}", "op", float(i), 0.0)
+    pos = tr.finished()
+    assert pos == before
+    for i in range(before, before + 60):
+        tr.record(f"s{i}", "op", float(i), 0.0)
+    assert [e["name"] for e in tr.events_since(pos)] == \
+        [f"s{i}" for i in range(before, before + 60)]
+    assert tr.finished() == before + 60
+
+
+def test_inproc_admission_keeps_sums_and_no_list():
+    from ndstpu.harness.admission import InprocAdmission
+    gate = InprocAdmission(1)
+    for _ in range(55):
+        with gate.slot():
+            pass
+    tl = gate.device_timeline()
+    assert tl["gated_queries"] == gate.gated_total == 55
+    assert tl["max_concurrent"] == 1 and tl["busy_s_total"] >= 0
+    assert not [v for v in vars(gate).values()
+                if isinstance(v, (list, dict))]

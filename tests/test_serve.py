@@ -778,3 +778,132 @@ def test_fleet_default_endpoints_stable_and_short(tmp_path):
     assert len(set(a)) == 3
     assert all(len(p) < 100 for p in a), "AF_UNIX ~108-byte path cap"
     assert default_endpoints(str(tmp_path / "other"), 3) != a
+
+
+# -- a request times its own phases (docs/OBSERVABILITY.md, serve span tree) --
+
+def _serve_spans(since: int):
+    """Spans finished since an ``obs.finished()`` reading, by name."""
+    by = {}
+    for e in obs.events_since(since):
+        by.setdefault(e["name"], []).append(e)
+    return by
+
+
+def _of(spans, name, rid, cat="serve"):
+    return [e for e in spans.get(name, [])
+            if e["cat"] == cat and e["args"].get("id") == rid]
+
+
+@pytest.mark.parametrize("watchdog_s", [30.0, 0.0],
+                         ids=["watchdog-thread", "inline"])
+def test_answered_request_has_every_phase_once(serve_env, watchdog_s):
+    """One admit_wait and one reply_tail per answered request, one
+    gate_wait + gate_hold per attempt, each with the request's id; the
+    hold is its pin + statement + row conversion and little else."""
+    _, cli = serve_env(query_timeout_s=watchdog_s)
+    n0 = obs.finished()
+    rids = [cli.sql(f"SELECT count(*) AS c FROM t WHERE a > {k}")["id"]
+            for k in range(3)]
+    # the reply is written inside reply_tail: let the last span close
+    deadline = time.time() + 5.0
+    while len(_serve_spans(n0).get("reply_tail", [])) < 3 \
+            and time.time() < deadline:
+        time.sleep(0.01)
+    spans = _serve_spans(n0)
+    for rid in rids:
+        for name in ("admit_wait", "reply_tail", "gate_wait", "gate_hold"):
+            assert len(_of(spans, name, rid)) == 1, (name, rid)
+        assert _of(spans, "reply_tail", rid)[0]["args"]["reply_bytes"] > 0
+        assert _of(spans, "gate_wait", rid)[0]["args"]["canon"]
+    assert len(spans["pin"]) == len(spans["statement"]) \
+        == len(spans["to_rows"]) == 3
+    # requests ran one after another on one connection: pair by order
+    holds = sorted(spans["gate_hold"], key=lambda e: e["ts_epoch_s"])
+    for hold, pin, stmt, rows in zip(
+            holds, *(sorted(evs, key=lambda e: e["ts_epoch_s"])
+                     for evs in (spans["pin"], spans["statement"],
+                                 spans["to_rows"]))):
+        inside = pin["wall_s"] + stmt["wall_s"] + rows["wall_s"]
+        assert hold["wall_s"] >= inside - 1e-5
+        # the rest is the parse before the statement span, the reply's
+        # slice of the rows and, with the watchdog, starting and joining
+        # its thread
+        assert hold["wall_s"] - inside < 0.05, (hold, inside)
+    # none of the new spans takes a name or category the benchmark's
+    # readers key on
+    for name in ("admit_wait", "reply_tail", "gate_wait", "gate_hold",
+                 "pin"):
+        assert all(e["cat"] == "serve" for e in spans[name])
+
+
+def test_each_attempt_waits_and_holds_once(serve_env):
+    """run_with_retry makes a second attempt after a transient execute
+    fault: two gate_wait + two gate_hold under one id, still one
+    admit_wait and one reply_tail."""
+    _, cli = serve_env()
+    faults.install("execute:transient:1:times=1")
+    try:
+        n0 = obs.finished()
+        r = cli.sql("SELECT max(b) AS m FROM t")
+        assert r["status"] == "ok" and r["attempts"] == 2
+        time.sleep(0.05)
+        spans = _serve_spans(n0)
+        rid = r["id"]
+        assert len(_of(spans, "gate_wait", rid)) == 2
+        holds = _of(spans, "gate_hold", rid)
+        assert len(holds) == 2
+        assert sum(1 for h in holds if "error" in h["args"]) == 1
+        assert len(_of(spans, "admit_wait", rid)) == 1
+        assert len(_of(spans, "reply_tail", rid)) == 1
+    finally:
+        faults.uninstall()
+
+
+def test_second_request_waits_out_the_firsts_hold(serve_env):
+    """Two connections, one device slot: a request sent while another
+    holds the gate shows a gate_wait that lasts to the end of that
+    hold."""
+    srv, cli = serve_env()
+    cli2 = ServeClient(srv.config.socket_path, retries=0,
+                       connect_timeout_s=10.0)
+    real_sql = srv.session.sql
+
+    def slow_sql(text, pin=None):
+        if "slow_marker" in text:
+            time.sleep(0.4)
+        return real_sql(text, pin=pin)
+
+    srv.session.sql = slow_sql
+    try:
+        n0 = obs.finished()
+        got = {}
+        th = threading.Thread(
+            target=lambda: got.update(first=cli.sql(
+                "SELECT count(*) AS slow_marker FROM t")), daemon=True)
+        th.start()
+        time.sleep(0.15)        # the first is inside its hold by now
+        second = cli2.sql("SELECT min(a) AS m FROM t")
+        th.join(10.0)
+        time.sleep(0.05)
+        spans = _serve_spans(n0)
+        hold1 = _of(spans, "gate_hold", got["first"]["id"])[0]
+        wait2 = _of(spans, "gate_wait", second["id"])[0]
+        hold1_end = hold1["ts_epoch_s"] + hold1["wall_s"]
+        remaining = hold1_end - wait2["ts_epoch_s"]
+        assert 0.1 < remaining < 0.4
+        assert wait2["wall_s"] >= remaining - 0.005
+        wait1 = _of(spans, "gate_wait", got["first"]["id"])[0]
+        assert wait1["wall_s"] < 0.05       # nobody held it before
+    finally:
+        srv.session.sql = real_sql
+        cli2.close()
+
+
+def test_health_reports_the_bounded_buffers(serve_env):
+    srv, cli = serve_env()
+    cli.sql("SELECT count(*) AS c FROM t")
+    h = cli.health()
+    # (the reply goes out inside reply_tail, which closes after it)
+    assert 0 < h["trace_events"] <= len(obs.tracer().events)
+    assert h["gated_queries"] == srv.gate.gated_total == 1
