@@ -87,6 +87,16 @@ _DEAD32 = np.int32(2 ** 30)
 _ORD_DEAD32 = np.int32(2 ** 30 + 1)   # order keys: dead strictly last
 _NARROW_LIM = 2 ** 30                 # |value| bound for int32 keys
 _MIN_CAPACITY = 256
+# survivor compaction (_survivor_positions): one element gathered by the
+# binary search costs this many updates of the scatter.  TPU v5 lite,
+# 4 Mi rows (PR 26's chip run): the int32 scatter takes 21.1 ms whatever
+# survives (10.8 at 2 Mi, 5.8 at 1 Mi rows), the search 1.5 + 7.2 ns x
+# cap x 22 steps: 6.7 ms for 32 Ki survivors, 11.9 for 64 Ki, 22.4 for
+# 128 Ki, 43.4 for 256 Ki, 333 for 2 Mi; at 1 Mi rows 1.5 ms for 4 Ki,
+# 38.9 for 256 Ki.  (jnp.nonzero(size=) counts in int64 under x64,
+# emulated on this chip: 257-291 ms at 4 Mi rows, 65-73 at 1 Mi.)
+_SEARCH_COMPACT_COST = 1.5
+_JOIN_PATHS = ("lookup", "expand", "sort")
 
 # Engine default for NDSTPU_GROUPBY.  Module-level and literal on
 # purpose: obs/artifact_lint.py parses it from source (no jax import)
@@ -1758,6 +1768,9 @@ class JaxExecutor:
         self._key_latch = KeyedLatch()
         # plan node -> pre-order id, while a replay program is traced
         self._scope_ids: Dict[int, int] = {}
+        # equi-join operators by the path each took, since the replay
+        # program being traced began (-> _CompiledPlan.join_paths)
+        self._join_paths: Dict[str, int] = dict.fromkeys(_JOIN_PATHS, 0)
         # eager bounds diagnostic: plain (non-compiling) executors keep
         # it always on — they have no discovery phase to front-load the
         # check into; CompilingExecutor narrows it to discovery
@@ -2067,9 +2080,11 @@ class JaxExecutor:
     def compact(self, dt: DTable) -> DTable:
         """Scatter alive rows to the front (order-preserving); one
         sync point for the new capacity."""
-        cap, n_alive = self._capacity_for(jnp.sum(dt.alive))
-        idx_src = jnp.nonzero(dt.alive, size=cap,
-                              fill_value=0)[0].astype(jnp.int32)
+        return self._compact_to(dt, *self._capacity_for(jnp.sum(dt.alive)))
+
+    def _compact_to(self, dt: DTable, cap: int, n_alive) -> DTable:
+        """The ``n_alive`` alive rows of ``dt`` at the front of ``cap``."""
+        idx_src = self._survivor_positions(dt.alive, cap)
         alive = jax.lax.iota(jnp.int32, cap) < \
             jnp.asarray(n_alive).astype(jnp.int32)
         return DTable(_gather_cols(dt.columns, idx_src, alive), alive)
@@ -3169,8 +3184,28 @@ class JaxExecutor:
             rvalid = rvalid & rc.valid
         return lkey, rkey, lvalid, rvalid, bound
 
+    def _lut_span(self, bound, m: int, n: int) -> Optional[int]:
+        """Slots of the direct-addressed tables for a join of ``m`` build
+        and ``n`` probe rows, or None for the sort path."""
+        # LUT only when the domain is within both the absolute cap and a
+        # small multiple of the table sizes: its cumsum/memset run over
+        # `bound` slots, so a near-cap domain against tiny tables would
+        # cost far more than the sort path over m+n rows
+        if bound is not None and 0 < bound <= min(
+                self.join_lut_cap, max(8 * (m + n), 1 << 20)):
+            return int(bound)
+        return None
+
+    @staticmethod
+    def _build_counts(bkey: jnp.ndarray, span: int):
+        """(bidx, cnt_t): each build row's slot (dead and NULL-key rows
+        go to the trash slot ``span``) and the rows per slot."""
+        bidx = jnp.where(bkey >= 0, bkey, span).astype(jnp.int32)
+        return bidx, jnp.zeros(span + 1, jnp.int32).at[bidx].add(1)
+
     def _probe_counts(self, pkey: jnp.ndarray, bkey: jnp.ndarray,
-                      bound: int, need_order: bool = True):
+                      bound: int, need_order: bool = True,
+                      cnt_t: Optional[jnp.ndarray] = None):
         """Per-probe-row (lo, counts) against the build side, plus the
         build-side stable key order: ``order[lo[i] .. lo[i]+counts[i]-1]``
         are the build rows matching probe row ``i``.
@@ -3195,15 +3230,10 @@ class JaxExecutor:
         m = int(bkey.shape[0])
         n = int(pkey.shape[0])
         iota_m = jax.lax.iota(jnp.int32, m)
-        # LUT only when the domain is within both the absolute cap and a
-        # small multiple of the table sizes: its cumsum/memset run over
-        # `bound` slots, so a near-cap domain against tiny tables would
-        # cost far more than the sort path over m+n rows
-        if bound is not None and 0 < bound <= min(
-                self.join_lut_cap, max(8 * (m + n), 1 << 20)):
-            span = int(bound)
-            bidx = jnp.where(bkey >= 0, bkey, span).astype(jnp.int32)
-            cnt_t = jnp.zeros(span + 1, jnp.int32).at[bidx].add(1)
+        span = self._lut_span(bound, m, n)
+        if span is not None:
+            if cnt_t is None:   # else the caller's _build_counts table
+                cnt_t = self._build_counts(bkey, span)[1]
             cnt = cnt_t[:span]
             ccnt = jnp.cumsum(cnt)
             # valid build keys sort AFTER the (<0) sentinel rows in the
@@ -3357,9 +3387,24 @@ class JaxExecutor:
         lkey = jnp.where(lvalid & lt.alive, lkey, -1)
         rkey = jnp.where(rvalid & rt.alive, rkey, -2)
 
+        span = self._lut_span(bound, rt.capacity, lt.capacity)
+        cnt_t = None
+        if span is not None and kind in ("inner", "left"):
+            # a build side whose alive keys are unique (a dimension on
+            # its surrogate key) gives each probe row 0 or 1 match: one
+            # lookup, no expansion.  Observed, recorded and guarded like
+            # every other data-dependent choice of the size plan.
+            bidx, cnt_t = self._build_counts(rkey, span)
+            if self._branch_bool(jnp.max(cnt_t[:span]) <= 1):
+                self._join_paths["lookup"] += 1
+                return self._lookup_join(lt, rt, lkey, bidx, span, kind,
+                                         extra)
+        self._join_paths["sort" if span is None else "expand"] += 1
+
         need_order = kind in ("inner", "left") or extra is not None
         lo, counts, order = self._probe_counts(lkey, rkey, bound,
-                                               need_order=need_order)
+                                               need_order=need_order,
+                                               cnt_t=cnt_t)
         counts = jnp.where(lt.alive, counts, 0)
         matched = counts > 0
 
@@ -3396,6 +3441,68 @@ class JaxExecutor:
         if kind == "left":
             return self._left_join(lt, rt, order, lo, counts, extra)
         raise Unsupported(f"join kind {kind}", code="NDS210")
+
+    def _lookup_join(self, lt: DTable, rt: DTable, lkey, bidx, span: int,
+                     kind: str, extra) -> DTable:
+        """Inner / left join against a build side with unique alive keys:
+        one table of build-row ids over the key domain, one probe-sized
+        gather, and (inner) a compaction sized by the survivors.  Output
+        rows keep the probe's order."""
+        row_lut = jnp.full(span + 1, -1, jnp.int32).at[bidx].set(
+            jax.lax.iota(jnp.int32, rt.capacity))
+        pk = jnp.clip(lkey, 0, span - 1).astype(jnp.int32)
+        ri = row_lut[pk]
+        matched = (ri >= 0) & (lkey >= 0) & lt.alive
+        ri = jnp.maximum(ri, 0)
+        rcols = _gather_cols(rt.columns, ri, matched)
+        if kind == "left":
+            if extra is not None:
+                # at most one candidate a probe row: the residual
+                # predicate is a mask over the joined row
+                joined = DTable({**lt.columns, **rcols}, matched)
+                matched = matched & JEval(joined).predicate(extra)
+                rcols = _gather_cols(rt.columns, ri, matched)
+            return DTable({**lt.columns, **rcols}, lt.alive)
+        out = DTable({**lt.columns, **rcols}, matched)
+        cap, n_out = self._capacity_for(jnp.sum(matched))
+        if cap != lt.capacity:
+            # (else the survivors fill the probe's size class: nothing
+            # moves)
+            out = self._compact_to(out, cap, n_out)
+        if extra is not None:
+            # on the compacted rows, as the expansion path does: the
+            # predicate's right columns are gathered at ``cap``
+            out = DTable(out.columns,
+                         out.alive & JEval(out).predicate(extra))
+        return out
+
+    @staticmethod
+    def _survivor_positions(mask: jnp.ndarray, cap: int) -> jnp.ndarray:
+        """Positions of the first ``cap`` set rows of ``mask``, ascending
+        (slots past the last set row hold a valid, dead position): by
+        binary search over the running count where few survive (cap x
+        log2(n) element gathers), else by one scatter of n updates."""
+        n = int(mask.shape[0])
+        steps = max(n - 1, 1).bit_length()
+        csum = jnp.cumsum(mask.astype(jnp.int32))
+        if cap * steps * _SEARCH_COMPACT_COST > n:
+            # each set row to its rank; the others to a trash slot
+            dest = jnp.where(mask, csum - 1, cap)
+            return jnp.zeros(cap + 1, jnp.int32).at[dest].set(
+                jax.lax.iota(jnp.int32, n))[:cap]
+        # src[j] = first i with csum[i] > j
+        want = jax.lax.iota(jnp.int32, cap) + 1
+
+        def halve(_, lo_hi):
+            lo, hi = lo_hi
+            mid = (lo + hi) // 2
+            below = csum[mid] < want
+            return jnp.where(below, mid + 1, lo), jnp.where(below, hi, mid)
+
+        lo, _ = jax.lax.fori_loop(
+            0, steps, halve,
+            (jnp.zeros(cap, jnp.int32), jnp.full(cap, n - 1, jnp.int32)))
+        return jnp.minimum(lo, n - 1)
 
     def _expand(self, lt: DTable, rt: DTable, order, lo, counts,
                 total, out_cap: int) -> DTable:
@@ -3434,8 +3541,7 @@ class JaxExecutor:
         pos = jax.lax.iota(jnp.int32, out_cap)
         is_m = pos < n_matched
         mi = jnp.clip(pos, 0, inner_c.capacity - 1)
-        um_idx = jnp.nonzero(unmatched_mask, size=out_cap,
-                             fill_value=0)[0].astype(jnp.int32)
+        um_idx = self._survivor_positions(unmatched_mask, out_cap)
         um_rows = um_idx[jnp.clip(pos - n_matched, 0, out_cap - 1)]
         out_alive = pos < (n_matched + n_unmatched)
         cols = _select_cols(
@@ -3480,6 +3586,9 @@ class _CompiledPlan:
     # representative SQL text for persisted records: canonical cache
     # keys are not re-plannable, so save/load round-trips through SQL
     source_sql: Optional[str] = None
+    # equi-join operators of the traced program by path (_JOIN_PATHS
+    # order), set when fn is traced; None before
+    join_paths: Optional[Tuple[int, int, int]] = None
 
 
 def _scan_columns(p: lp.Plan) -> Dict[str, Optional[List[str]]]:
@@ -3780,6 +3889,7 @@ class CompilingExecutor(JaxExecutor):
         t_start = time.perf_counter()
         seg_args = {}
         seg_oks = []
+        ran = [cp]      # the programs this replay runs on the device
         with obs.annotation("replay.prep"):
             for fp in (cp.seg_fps or ()):
                 scp = self._seg_compiled.get(fp)
@@ -3797,6 +3907,7 @@ class CompilingExecutor(JaxExecutor):
                     (out, alive), ok = scp.fn(args)
                     seg_args[_seg_argname(fp)] = (out, alive)
                     seg_oks.append(ok)
+                    ran.append(scp)
                 else:
                     # fallback-isolated segment: host numpy result,
                     # shipped to the device at the recorded output
@@ -3833,12 +3944,18 @@ class CompilingExecutor(JaxExecutor):
                 result = self._assemble_host(cp, out, alive_np)
         t_end = time.perf_counter()
         obs.inc("engine.replay.device_wait_s", t_fetched - t_called)
+        # join operators of the programs just run, by the path each took
+        # when its program was traced
+        joins = {"join_" + k: sum(p.join_paths[i] for p in ran)
+                 for i, k in enumerate(_JOIN_PATHS)}
+        for k, v in joins.items():
+            obs.inc("engine.replay." + k, v)
         if sp is not obs.NULL_SPAN:
             sp.set(host_prep_s=round(t_dispatch - t_start, 5),
                    dispatch_s=round(t_called - t_dispatch, 6),
                    device_wait_s=round(t_fetched - t_called, 6),
                    assemble_s=round(t_end - t_fetched, 6),
-                   fetched_bytes=fetched)
+                   fetched_bytes=fetched, **joins)
         return result
 
     @staticmethod
@@ -4081,10 +4198,12 @@ class CompilingExecutor(JaxExecutor):
                            .sum()) & (2 ** 61 - 1)
         return (name, t.num_rows, chk)
 
-    _REC_FORMAT = 4   # bump when the pickle schema changes
+    _REC_FORMAT = 5   # bump when the pickle schema changes
                       # (4: + per-program param_spec; keys round-trip
                       # through representative SQL so canonical cache
-                      # keys can be rebuilt by re-canonicalizing)
+                      # keys can be rebuilt by re-canonicalizing;
+                      # 5: + one ("bool", unique build keys) entry per
+                      # inner/left LUT join in the size plans)
 
     def save_compile_records(self, path: str) -> int:
         """Persist discovery size-plan records (NOT compiled code — XLA
@@ -4308,6 +4427,7 @@ class CompilingExecutor(JaxExecutor):
             self._scope_ids = {id(n): i
                                for i, n in enumerate(cp.plan.walk())}
             self._trace_tables = {}
+            self._join_paths = dict.fromkeys(_JOIN_PATHS, 0)
             for name, entry in tables.items():
                 if name == "\x00params":
                     continue   # parameter subtree, not a table
@@ -4345,6 +4465,8 @@ class CompilingExecutor(JaxExecutor):
                 ok = jnp.asarray(True)
                 for o in self._oks:
                     ok = ok & o
+                cp.join_paths = tuple(self._join_paths[k]
+                                      for k in _JOIN_PATHS)
             finally:
                 self.mode = "eager"
                 self._trace_tables = None

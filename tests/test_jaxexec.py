@@ -763,3 +763,263 @@ def test_sibling_scalar_agg_fusion_rejects_overlap(catalog, cpu_sess):
     p, _cols = cpu_sess.plan(sql)
     scans = [n for n in p.walk() if isinstance(n, lp.Scan)]
     assert len(scans) == 2, "overlapping intervals must not fuse"
+
+
+# -- lookup join: a build side with unique alive keys -----------------------
+#
+# fact probes dim on its key.  Every case runs on Session(backend="cpu")
+# and twice on a fresh Session(backend="tpu") (discovery, then the jitted
+# replay with the recorded branch and its guard).
+
+_LJ_ROWS = 1000     # probe rows (capacity 1024: survivors of a filtered
+#                     dimension fit a smaller size class and compact)
+
+
+def _i32(vals):
+    from ndstpu.engine.columnar import INT32, Column
+    valid = np.array([v is not None for v in vals])
+    data = np.array([0 if v is None else v for v in vals], dtype=np.int32)
+    return Column(data, INT32, None if valid.all() else valid)
+
+
+def _lookup_catalog(nulls: str):
+    """fact(f_id, f_k, f_v, f_hit) and dim(d_k, d_grp, d_val, d_name).
+    ``nulls``: which side has NULL join keys (probe / build / both), or
+    ``dup`` (no NULLs, every third build key twice)."""
+    from ndstpu.engine.columnar import Column, Table
+    from ndstpu.io.loader import Catalog
+    rng = np.random.default_rng(26)
+    n_dim = 60
+    d_k = list(range(100, 100 + n_dim))
+    if nulls in ("build", "both"):
+        d_k[5] = d_k[17] = None
+    if nulls == "dup":
+        d_k = d_k + d_k[::3]
+    f_k = [int(k) for k in rng.integers(90, 100 + n_dim + 10, _LJ_ROWS)]
+    if nulls in ("probe", "both"):
+        for i in range(0, _LJ_ROWS, 7):
+            f_k[i] = None
+    alive_dim = {k for k in d_k if k is not None}
+    cat = Catalog()
+    cat.register("fact", Table({
+        "f_id": _i32(list(range(_LJ_ROWS))),
+        "f_k": _i32(f_k),
+        "f_v": _i32([int(v) for v in rng.integers(0, 50, _LJ_ROWS)]),
+        "f_hit": _i32([int(k in alive_dim) for k in f_k]),
+    }))
+    cat.register("dim", Table({
+        "d_k": _i32(d_k),
+        "d_grp": _i32([i % 4 for i in range(len(d_k))]),
+        "d_val": _i32([(7 * i) % 50 for i in range(len(d_k))]),
+        "d_name": Column.from_strings([f"name{i % 9}"
+                                       for i in range(len(d_k))]),
+    }))
+    return cat
+
+
+_LJ_CATALOGS = {}
+
+
+def _lj_catalog(nulls):
+    if nulls not in _LJ_CATALOGS:
+        _LJ_CATALOGS[nulls] = _lookup_catalog(nulls)
+    return _LJ_CATALOGS[nulls]
+
+
+_LJ_SHAPES = {
+    # (probe side, build side)
+    "whole": ("fact", "dim"),
+    "filtered": ("fact", "(select * from dim where d_grp = 1) d"),
+    "none": ("fact", "(select * from dim where d_grp = 99) d"),
+    "all": ("(select * from fact where f_hit = 1) f", "dim"),
+}
+
+
+def _lj_sql(kind, shape, extra):
+    probe, build = _LJ_SHAPES[shape]
+    on = "f_k = d_k" + (" and f_v < d_val" if extra else "")
+    return (f"select f_id, f_k, f_v, d_k, d_val, d_name from {probe} "
+            f"{kind} join {build} on {on}")
+
+
+_LJ_CASES = [(k, s, n, e) for k in ("inner", "left")
+             for s in _LJ_SHAPES for n in ("probe", "build", "both")
+             for e in (False, True)] + \
+            [(k, "whole", "dup", e) for k in ("inner", "left")
+             for e in (False, True)]
+
+
+@pytest.mark.parametrize("kind,shape,nulls,extra", _LJ_CASES)
+def test_lookup_join(kind, shape, nulls, extra):
+    catalog = _lj_catalog(nulls)
+    sql = _lj_sql(kind, shape, extra)
+    want = Session(catalog, backend="cpu").sql(sql)
+    sess = Session(catalog, backend="tpu")
+    for _run in ("discovery", "replay"):
+        got = sess.sql(sql)
+        assert got.column_names == want.column_names
+        assert_tables_match(want, got)
+        if kind == "inner":
+            # output rows keep the probe's order
+            ids = [r[0] for r in got.to_rows()]
+            assert ids == sorted(ids)
+    cp = sess.compiled_plan(sql)
+    assert cp is not None and cp.compilable
+    # a duplicated build key expands; unique alive keys look up
+    assert cp.join_paths == ((0, 1, 0) if nulls == "dup" else (1, 0, 0))
+    # bounds and dictionaries of both sides' columns survive the join
+    meta = {name: (d, b) for name, _ct, d, b in cp.out_meta}
+    base = sess._jax_executor()._table_device
+    for name, table in (("f_k", "fact"), ("f_v", "fact"), ("d_val", "dim")):
+        bounds = base(table).column(name).bounds
+        assert bounds is not None and meta[name][1] == bounds
+    assert list(meta["d_name"][0]) == \
+        list(base("dim").column("d_name").dictionary)
+
+
+@pytest.mark.parametrize("method", ["search", "scatter"])
+@pytest.mark.parametrize("n,frac", [(256, 0.0), (1024, 0.01), (1024, 0.3),
+                                    (4096, 0.2), (4096, 1.0)])
+def test_survivor_positions(monkeypatch, method, n, frac):
+    """Both compaction methods give the set rows' positions, ascending,
+    and valid positions past them."""
+    import jax.numpy as jnp
+    from ndstpu.engine import jaxexec
+    monkeypatch.setattr(jaxexec, "_SEARCH_COMPACT_COST",
+                        0 if method == "search" else 10 ** 9)
+    mask = np.random.default_rng(n).random(n) < frac
+    k = int(mask.sum())
+    cap = jaxexec.size_class(max(k, 1))
+    src = np.asarray(jaxexec.JaxExecutor._survivor_positions(
+        jnp.asarray(mask), cap))
+    assert src.shape == (cap,)
+    assert (src[:k] == np.nonzero(mask)[0]).all()
+    assert ((src >= 0) & (src < n)).all()
+
+
+@pytest.mark.parametrize("method", ["search", "scatter"])
+def test_lookup_join_compaction_methods(monkeypatch, method):
+    from ndstpu.engine import jaxexec
+    monkeypatch.setattr(jaxexec, "_SEARCH_COMPACT_COST",
+                        0 if method == "search" else 10 ** 9)
+    catalog = _lj_catalog("both")
+    sql = _lj_sql("inner", "filtered", True)
+    want = Session(catalog, backend="cpu").sql(sql)
+    sess = Session(catalog, backend="tpu")
+    for _run in ("discovery", "replay"):
+        assert_tables_match(want, sess.sql(sql), ordered=True)
+
+
+_GUARD_SQL = ("select f_id, d_k, d_val from fact join "
+              "(select * from dim where d_grp = {grp}) d on f_v = d_val")
+
+
+def _guard_catalog():
+    """dim.d_val is no key: unique among the rows of d_grp 1, duplicated
+    among those of d_grp 2."""
+    from ndstpu.engine.columnar import Table
+    from ndstpu.io.loader import Catalog
+    src = _lookup_catalog("probe")
+    dim = src.get("dim")
+    d_val = [(i if g == 1 else i % 5) for i, g in
+             enumerate(np.asarray(dim.column("d_grp").data))]
+    cat = Catalog()
+    cat.register("fact", src.get("fact"))
+    cat.register("dim", Table({**dim.columns, "d_val": _i32(d_val)}))
+    return cat
+
+
+def test_lookup_join_guard_rediscovers():
+    """The uniqueness the lookup rests on is a replay guard: a parameter
+    draw (same compiled key, same catalog version) or a swapped-in table
+    whose alive build keys repeat rediscovers onto the expand path and
+    answers as the reference does."""
+    import warnings
+    from ndstpu import obs
+    catalog = _guard_catalog()
+    cpu = Session(catalog, backend="cpu")
+    sess = Session(catalog, backend="tpu")
+    uniq, dup = _GUARD_SQL.format(grp=1), _GUARD_SQL.format(grp=2)
+    assert sess.canonical_key(uniq) == sess.canonical_key(dup)
+    for _run in ("discovery", "replay"):
+        assert_tables_match(cpu.sql(uniq), sess.sql(uniq))
+    assert sess.compiled_plan(uniq).join_paths == (1, 0, 0)
+    before = obs.counters_snapshot().get("engine.discoveries", 0)
+    with warnings.catch_warnings(record=True) as caught:
+        warnings.simplefilter("always")
+        assert_tables_match(cpu.sql(dup), sess.sql(dup))
+    assert any("rediscover" in str(w.message) for w in caught)
+    assert obs.counters_snapshot()["engine.discoveries"] == before + 1
+    assert_tables_match(cpu.sql(dup), sess.sql(dup))     # replay
+    assert sess.compiled_plan(dup).join_paths == (0, 1, 0)
+    # and back: unique again under the first draw
+    assert_tables_match(cpu.sql(uniq), sess.sql(uniq))
+    assert_tables_match(cpu.sql(uniq), sess.sql(uniq))
+    assert sess.compiled_plan(uniq).join_paths == (1, 0, 0)
+    # a table swapped in under a new catalog version
+    from ndstpu.engine.columnar import Table
+    dim = catalog.get("dim")
+    catalog.register("dim", Table({
+        **dim.columns, "d_val": _i32([i % 3 for i in range(dim.num_rows)])}))
+    for _run in ("discovery", "replay"):
+        assert_tables_match(cpu.sql(uniq), sess.sql(uniq))
+    assert sess.compiled_plan(uniq).join_paths == (0, 1, 0)
+
+
+def test_compile_records_older_format_ignored(tmp_path):
+    """A record file written before the size plans gained the lookup
+    branch (format 4) loads nothing and is rewritten, not merged."""
+    import pickle
+    from ndstpu.engine.jaxexec import CompilingExecutor
+    assert CompilingExecutor._REC_FORMAT == 5
+    catalog = _lj_catalog("probe")
+    sql = _lj_sql("inner", "filtered", False)
+    s1 = Session(catalog, backend="tpu")
+    want = s1.sql(sql).to_rows()
+    path = str(tmp_path / "plans.pkl")
+    assert s1.save_compiled(path) == 1
+    with open(path, "rb") as f:
+        data = pickle.load(f)
+    data["\x00fmt"] = 4
+    data["select 'written by format 4'"] = data[sql]
+    with open(path, "wb") as f:
+        pickle.dump(data, f)
+    s2 = Session(catalog, backend="tpu")
+    assert s2.preload_compiled(path) == 0
+    assert s2.sql(sql).to_rows() == want      # discovery runs
+    assert s2._jax_executor().n_discoveries == 1
+    assert s2.save_compiled(path) == 1
+    with open(path, "rb") as f:
+        data = pickle.load(f)
+    assert data["\x00fmt"] == 5 and sql in data
+    assert "select 'written by format 4'" not in data
+    assert Session(catalog, backend="tpu").preload_compiled(path) == 1
+
+
+def test_join_path_counters_and_span(catalog):
+    """Each replay adds its programs' join operators, by the path each
+    took at trace time, to three counters and to the replay span."""
+    from ndstpu import obs
+    sql = next(iter(streamgen.render_template_parts(
+        str(streamgen.TEMPLATE_DIR / "query3.tpl"), "07291122510", 0)))[1]
+    obs.reset(enabled=True)
+    try:
+        sess = Session(catalog, backend="tpu")
+        sess.sql(sql)                               # discovery: no replay
+        names = ["engine.replay.join_" + k
+                 for k in ("lookup", "expand", "sort")]
+        assert not any(k in obs.counters_snapshot() for k in names)
+        for n_replays in (1, 2):
+            sess.sql(sql)
+            snap = obs.counters_snapshot()
+            assert [snap[k] for k in names] == [2 * n_replays, 0, 0]
+        cp = sess.compiled_plan(sql)
+        programs = [cp] + [sess._jax_executor()._seg_compiled[fp]
+                           for fp in (cp.seg_fps or ())]
+        assert [sum(p.join_paths[i] for p in programs)
+                for i in range(3)] == [2, 0, 0]
+        span = [e for e in obs.tracer().events if e["name"] == "replay"][-1]
+        assert [span["args"]["join_" + k]
+                for k in ("lookup", "expand", "sort")] == [2, 0, 0]
+    finally:
+        obs.reset()
