@@ -96,7 +96,26 @@ _MIN_CAPACITY = 256
 # 38.9 for 256 Ki.  (jnp.nonzero(size=) counts in int64 under x64,
 # emulated on this chip: 257-291 ms at 4 Mi rows, 65-73 at 1 Mi.)
 _SEARCH_COMPACT_COST = 1.5
-_JOIN_PATHS = ("lookup", "expand", "sort")
+# lookup join by comparing each probe key with every alive build key
+# (_compare_cheaper): one (probe key, build key) pair costs this many
+# elements of the probe-sized gather it replaces.  TPU v5 lite (PR 29's
+# chip run): the keycmp kernel takes 0.59 / 1.11 / 2.13 / 4.21 / 8.30 /
+# 16.5 ms for 256 / 512 / 1024 / 2048 / 4096 / 8192 keys at 4 Mi rows
+# and 0.28 / 0.31 / 0.58 / 1.09 / 2.12 / 4.18 ms at 1 Mi in blocks of 512
+# rows (0.52 and 3.72 ms at 256 and 2048 keys in the blocks it has now;
+# 0.45 ms at 256 keys inside a replay): 0.48 ps a pair.  The same
+# compare as one XLA reduce fusion: 0.94-1.2 ps up to 1024 keys, 2.3 ps
+# from 2048 on.  A gather of 4 Mi elements: 36.1 ms from 18 001, 73 050
+# or 1 920 801 slots (8.6 ns an element; 9.1 ms at 1 Mi rows), 39-41 ms
+# from 128-301 slots.  (From 64 slots or fewer XLA selects instead:
+# 0.27 ms, under the kernel's 0.45-0.52 at 256 keys.  Not modelled:
+# sending query96's and query25's 13-slot `store` lookups back to it
+# is worth 0.4 ms each, 0.2 % of a pass.)
+_COMPARE_PAIR_COST = 5.6e-5
+# the kernel keeps keys and rows in scalar memory (1 MB a core)
+_COMPARE_MAX_KEYS = 32768
+# "compare" joins are counted under "lookup" too (they are lookups)
+_JOIN_PATHS = ("lookup", "expand", "sort", "compare")
 
 # Engine default for NDSTPU_GROUPBY.  Module-level and literal on
 # purpose: obs/artifact_lint.py parses it from source (no jax import)
@@ -3394,11 +3413,29 @@ class JaxExecutor:
             # its surrogate key) gives each probe row 0 or 1 match: one
             # lookup, no expansion.  Observed, recorded and guarded like
             # every other data-dependent choice of the size plan.
-            bidx, cnt_t = self._build_counts(rkey, span)
-            if self._branch_bool(jnp.max(cnt_t[:span]) <= 1):
+            k_cap, n_b = self._capacity_for(
+                jnp.sum(rkey >= 0, dtype=jnp.int32))
+            if self._compare_cheaper(lt.capacity, rt.capacity, k_cap):
+                # few alive build keys (a filtered dimension): compare
+                # each probe key with all of them -- no table over the
+                # key domain, no probe-sized gather
+                bkeys, brows = self._alive_build_keys(rkey, k_cap, n_b)
+                skeys = jnp.sort(bkeys)
+                unique = self._branch_bool(jnp.all(
+                    (skeys[1:] != skeys[:-1]) | (skeys[1:] < 0)))
+                if unique:
+                    self._join_paths["compare"] += 1
+                    ri = self._compare_rows(lkey, bkeys, brows, span)
+            else:
+                bidx, cnt_t = self._build_counts(rkey, span)
+                unique = self._branch_bool(jnp.max(cnt_t[:span]) <= 1)
+                if unique:
+                    ri = self._table_rows(
+                        lkey, bidx, jax.lax.iota(jnp.int32, rt.capacity),
+                        span)
+            if unique:
                 self._join_paths["lookup"] += 1
-                return self._lookup_join(lt, rt, lkey, bidx, span, kind,
-                                         extra)
+                return self._lookup_join(lt, rt, lkey, ri, kind, extra)
         self._join_paths["sort" if span is None else "expand"] += 1
 
         need_order = kind in ("inner", "left") or extra is not None
@@ -3442,16 +3479,12 @@ class JaxExecutor:
             return self._left_join(lt, rt, order, lo, counts, extra)
         raise Unsupported(f"join kind {kind}", code="NDS210")
 
-    def _lookup_join(self, lt: DTable, rt: DTable, lkey, bidx, span: int,
-                     kind: str, extra) -> DTable:
-        """Inner / left join against a build side with unique alive keys:
-        one table of build-row ids over the key domain, one probe-sized
-        gather, and (inner) a compaction sized by the survivors.  Output
+    def _lookup_join(self, lt: DTable, rt: DTable, lkey, ri, kind: str,
+                     extra) -> DTable:
+        """Inner / left join against a build side with unique alive keys,
+        given each probe row's build row ``ri`` (-1: none): lazy build
+        columns and (inner) a compaction sized by the survivors.  Output
         rows keep the probe's order."""
-        row_lut = jnp.full(span + 1, -1, jnp.int32).at[bidx].set(
-            jax.lax.iota(jnp.int32, rt.capacity))
-        pk = jnp.clip(lkey, 0, span - 1).astype(jnp.int32)
-        ri = row_lut[pk]
         matched = (ri >= 0) & (lkey >= 0) & lt.alive
         ri = jnp.maximum(ri, 0)
         rcols = _gather_cols(rt.columns, ri, matched)
@@ -3475,6 +3508,52 @@ class JaxExecutor:
             out = DTable(out.columns,
                          out.alive & JEval(out).predicate(extra))
         return out
+
+    @staticmethod
+    def _compare_cheaper(n: int, m: int, k_cap: int) -> bool:
+        """Is comparing ``n`` probe keys with ``k_cap`` alive build keys
+        cheaper than the direct-addressed lookup (a gather of ``n``
+        elements behind two scatters of the ``m`` build rows)?  In
+        gathered elements; _COMPARE_PAIR_COST has the readings."""
+        if k_cap > _COMPARE_MAX_KEYS:
+            return False
+        steps = max(m - 1, 1).bit_length()
+        compare = n * k_cap * _COMPARE_PAIR_COST + \
+            min(k_cap * steps * _SEARCH_COMPACT_COST, m)
+        return compare < n + 2 * m
+
+    def _alive_build_keys(self, rkey: jnp.ndarray, k_cap: int, n_b):
+        """(keys, rows): the alive build keys (``rkey >= 0``) and their
+        row ids in the first ``n_b`` of ``k_cap`` slots, ascending by
+        row; the other slots keyed -2, which no probe key equals."""
+        pos = self._survivor_positions(rkey >= 0, k_cap)
+        used = jax.lax.iota(jnp.int32, k_cap) < \
+            jnp.asarray(n_b).astype(jnp.int32)
+        # keys on the LUT path lie under the span: int32 holds them
+        return jnp.where(used, rkey[pos].astype(jnp.int32), -2), pos
+
+    @staticmethod
+    def _table_rows(lkey: jnp.ndarray, slots: jnp.ndarray,
+                    rows: jnp.ndarray, span: int) -> jnp.ndarray:
+        """Each probe row's build row (-1: none; anything where ``lkey``
+        is negative) from a table of ``rows`` over the key domain (slot
+        ``span`` is the trash): one probe-sized gather."""
+        row_lut = jnp.full(span + 1, -1, jnp.int32).at[slots].set(rows)
+        return row_lut[jnp.clip(lkey, 0, span - 1).astype(jnp.int32)]
+
+    def _compare_rows(self, lkey: jnp.ndarray, bkeys: jnp.ndarray,
+                      brows: jnp.ndarray, span: int) -> jnp.ndarray:
+        """The same rows with no table and no gather: ``lkey`` against
+        every one of the unique ``bkeys``, in the keycmp kernel.  Eager
+        execution (discovery, op by op on the host, where a [K, n]
+        compare would be materialised) scatters the K keys into the
+        table instead: the choice adds no entry to the size plan."""
+        if self.mode != "replay":
+            return self._table_rows(
+                lkey, jnp.where(bkeys >= 0, bkeys, span), brows, span)
+        from ndstpu.ops import keycmp
+        return keycmp.match_rows(lkey, bkeys, brows,
+                                 interpret=self._pallas_interpret())
 
     @staticmethod
     def _survivor_positions(mask: jnp.ndarray, cap: int) -> jnp.ndarray:
@@ -3588,7 +3667,7 @@ class _CompiledPlan:
     source_sql: Optional[str] = None
     # equi-join operators of the traced program by path (_JOIN_PATHS
     # order), set when fn is traced; None before
-    join_paths: Optional[Tuple[int, int, int]] = None
+    join_paths: Optional[Tuple[int, int, int, int]] = None
 
 
 def _scan_columns(p: lp.Plan) -> Dict[str, Optional[List[str]]]:
@@ -4198,12 +4277,15 @@ class CompilingExecutor(JaxExecutor):
                            .sum()) & (2 ** 61 - 1)
         return (name, t.num_rows, chk)
 
-    _REC_FORMAT = 5   # bump when the pickle schema changes
+    _REC_FORMAT = 6   # bump when the pickle schema changes
                       # (4: + per-program param_spec; keys round-trip
                       # through representative SQL so canonical cache
                       # keys can be rebuilt by re-canonicalizing;
                       # 5: + one ("bool", unique build keys) entry per
-                      # inner/left LUT join in the size plans)
+                      # inner/left LUT join in the size plans;
+                      # 6: + the ("cap", alive build keys) entry before
+                      # it; where comparing with those keys is the
+                      # cheaper lookup the bool is their uniqueness)
 
     def save_compile_records(self, path: str) -> int:
         """Persist discovery size-plan records (NOT compiled code — XLA

@@ -784,27 +784,37 @@ def _i32(vals):
 
 def _lookup_catalog(nulls: str):
     """fact(f_id, f_k, f_v, f_hit) and dim(d_k, d_grp, d_val, d_name).
-    ``nulls``: which side has NULL join keys (probe / build / both), or
-    ``dup`` (no NULLs, every third build key twice)."""
+    ``nulls``: which side has NULL join keys (probe / build / both),
+    ``dup`` (no NULLs, every third build key twice), or ``edge`` (no
+    NULLs, 300 build keys: filters keep 256 or 257 of them, the last of
+    one capacity class and the first of the next), or ``wide`` (NULLs on
+    both sides, 8000 probe rows: capacity 8192)."""
     from ndstpu.engine.columnar import Column, Table
     from ndstpu.io.loader import Catalog
     rng = np.random.default_rng(26)
-    n_dim = 60
-    d_k = list(range(100, 100 + n_dim))
-    if nulls in ("build", "both"):
+    n_rows = 8000 if nulls == "wide" else _LJ_ROWS
+    n_dim = 300 if nulls == "edge" else 60
+    # every fifth value of the key domain: wider than any capacity of
+    # alive keys here, as a dimension's domain is
+    d_k = list(range(100, 100 + 5 * n_dim, 5))
+    if nulls in ("build", "both", "wide"):
         d_k[5] = d_k[17] = None
     if nulls == "dup":
         d_k = d_k + d_k[::3]
-    f_k = [int(k) for k in rng.integers(90, 100 + n_dim + 10, _LJ_ROWS)]
-    if nulls in ("probe", "both"):
-        for i in range(0, _LJ_ROWS, 7):
+    # four probe keys in five are on the build side's grid (those of
+    # its NULLed and filtered rows too)
+    f_k = [100 + 5 * int(i) + int(off) for i, off in zip(
+        rng.integers(-2, n_dim + 2, n_rows),
+        rng.random(n_rows) >= 0.8)]
+    if nulls in ("probe", "both", "wide"):
+        for i in range(0, n_rows, 7):
             f_k[i] = None
     alive_dim = {k for k in d_k if k is not None}
     cat = Catalog()
     cat.register("fact", Table({
-        "f_id": _i32(list(range(_LJ_ROWS))),
+        "f_id": _i32(list(range(n_rows))),
         "f_k": _i32(f_k),
-        "f_v": _i32([int(v) for v in rng.integers(0, 50, _LJ_ROWS)]),
+        "f_v": _i32([int(v) for v in rng.integers(0, 50, n_rows)]),
         "f_hit": _i32([int(k in alive_dim) for k in f_k]),
     }))
     cat.register("dim", Table({
@@ -832,6 +842,16 @@ _LJ_SHAPES = {
     "filtered": ("fact", "(select * from dim where d_grp = 1) d"),
     "none": ("fact", "(select * from dim where d_grp = 99) d"),
     "all": ("(select * from fact where f_hit = 1) f", "dim"),
+    # alive build keys at a capacity class's edge (catalog "edge")
+    "k256": ("fact", "(select * from dim where d_k < 1380) d"),
+    "k257": ("fact", "(select * from dim where d_k < 1385) d"),
+    # a probe capacity that is no power of two (catalog "wide"): UNION
+    # ALL keeps both branches' capacities, 256 survivors of a join (ids
+    # moved under the fact's: the probe's order stays ascending) + 8192
+    "union": ("(select f_id - 10000 f_id, f_k, f_v from "
+              "(select * from fact where f_v < 5) f1 join "
+              "(select * from dim where d_grp = 1) d1 on f_k = d_k "
+              "union all select f_id, f_k, f_v from fact) f", "dim"),
 }
 
 
@@ -843,14 +863,25 @@ def _lj_sql(kind, shape, extra):
 
 
 _LJ_CASES = [(k, s, n, e) for k in ("inner", "left")
-             for s in _LJ_SHAPES for n in ("probe", "build", "both")
+             for s in ("whole", "filtered", "none", "all")
+             for n in ("probe", "build", "both")
              for e in (False, True)] + \
             [(k, "whole", "dup", e) for k in ("inner", "left")
-             for e in (False, True)]
+             for e in (False, True)] + \
+            [(k, s, "edge", False) for k in ("inner", "left")
+             for s in ("k256", "k257")] + \
+            [(k, "union", "wide", False) for k in ("inner", "left")]
+# how a unique-key lookup finds each probe row's build row: by comparing
+# with the alive build keys, or by a gather from a table over the key
+# domain; forced through the constant the choice rests on
+_LJ_METHODS = {"compare": 0.0, "gather": 1e9}
 
 
+@pytest.mark.parametrize("method", list(_LJ_METHODS))
 @pytest.mark.parametrize("kind,shape,nulls,extra", _LJ_CASES)
-def test_lookup_join(kind, shape, nulls, extra):
+def test_lookup_join(monkeypatch, kind, shape, nulls, extra, method):
+    from ndstpu.engine import jaxexec
+    monkeypatch.setattr(jaxexec, "_COMPARE_PAIR_COST", _LJ_METHODS[method])
     catalog = _lj_catalog(nulls)
     sql = _lj_sql(kind, shape, extra)
     want = Session(catalog, backend="cpu").sql(sql)
@@ -866,7 +897,17 @@ def test_lookup_join(kind, shape, nulls, extra):
     cp = sess.compiled_plan(sql)
     assert cp is not None and cp.compilable
     # a duplicated build key expands; unique alive keys look up
-    assert cp.join_paths == ((0, 1, 0) if nulls == "dup" else (1, 0, 0))
+    # (lookup, expand, sort, compare: a compare is a lookup too)
+    n_joins = 2 if shape == "union" else 1
+    assert cp.join_paths == (
+        (0, 1, 0, 0) if nulls == "dup" else
+        (n_joins, 0, 0, n_joins if method == "compare" else 0))
+    if shape == "union":
+        assert ("cap", 256) in cp.record and want.num_rows > 5000
+    if nulls == "edge" and method == "compare":
+        # the alive build keys' capacity: the size plan's first entry
+        # of the join
+        assert ("cap", 256 if shape == "k256" else 512) in cp.record
     # bounds and dictionaries of both sides' columns survive the join
     meta = {name: (d, b) for name, _ct, d, b in cp.out_meta}
     base = sess._jax_executor()._table_device
@@ -875,6 +916,51 @@ def test_lookup_join(kind, shape, nulls, extra):
         assert bounds is not None and meta[name][1] == bounds
     assert list(meta["d_name"][0]) == \
         list(base("dim").column("d_name").dictionary)
+
+
+@pytest.mark.parametrize("method", list(_LJ_METHODS))
+def test_lookup_join_int64_composite_key(monkeypatch, method):
+    """Three key pairs whose radixes pass 2^62 re-densify: the composite
+    key is int64 though its domain fits the tables, and both lookup
+    methods take it (the compare narrows it: the domain fits int32)."""
+    import jax.numpy as jnp
+    from ndstpu.engine import jaxexec
+    from ndstpu.engine.columnar import Table
+    from ndstpu.io.loader import Catalog
+    monkeypatch.setattr(jaxexec, "_COMPARE_PAIR_COST", _LJ_METHODS[method])
+    seen = []
+    join_keys = jaxexec.JaxExecutor._join_keys
+    monkeypatch.setattr(
+        jaxexec.JaxExecutor, "_join_keys",
+        lambda self, *a: seen.append(join_keys(self, *a)) or seen[-1])
+    rng = np.random.default_rng(29)
+    n_dim = 40
+    d_a = [0, 2 ** 31 - 1] + [int(v) for v in
+                              rng.integers(1, 2 ** 31 - 1, n_dim - 2)]
+    d_b = [0, 2 ** 30 - 1] + [int(v) for v in
+                              rng.integers(1, 2 ** 30 - 1, n_dim - 2)]
+    d_g = [i % 4 for i in range(n_dim)]
+    pick = rng.integers(0, n_dim, _LJ_ROWS)
+    miss = rng.random(_LJ_ROWS) < 0.3
+    cat = Catalog()
+    cat.register("dim", Table({
+        "d_a": _i32(d_a), "d_b": _i32(d_b), "d_g": _i32(d_g),
+        "d_val": _i32(list(range(n_dim)))}))
+    cat.register("fact", Table({
+        "f_id": _i32(list(range(_LJ_ROWS))),
+        "f_a": _i32([d_a[i] for i in pick]),
+        "f_b": _i32([d_b[i] for i in pick]),
+        "f_g": _i32([(d_g[i] + int(x)) % 4 for i, x in zip(pick, miss)])}))
+    sql = ("select f_id, d_val from fact join dim "
+           "on f_a = d_a and f_b = d_b and f_g = d_g")
+    want = Session(cat, backend="cpu").sql(sql)
+    assert 0 < want.num_rows < _LJ_ROWS
+    sess = Session(cat, backend="tpu")
+    for _run in ("discovery", "replay"):
+        assert_tables_match(want, sess.sql(sql), ordered=True)
+    assert all(k[0].dtype == jnp.int64 and k[4] < 2 ** 20 for k in seen)
+    assert sess.compiled_plan(sql).join_paths == \
+        ((1, 0, 0, 1) if method == "compare" else (1, 0, 0, 0))
 
 
 @pytest.mark.parametrize("method", ["search", "scatter"])
@@ -923,19 +1009,26 @@ def _guard_catalog():
     dim = src.get("dim")
     d_val = [(i if g == 1 else i % 5) for i, g in
              enumerate(np.asarray(dim.column("d_grp").data))]
+    d_val[3] = 1000   # (d_grp 3) a key domain wider than the alive keys
     cat = Catalog()
     cat.register("fact", src.get("fact"))
     cat.register("dim", Table({**dim.columns, "d_val": _i32(d_val)}))
     return cat
 
 
-def test_lookup_join_guard_rediscovers():
-    """The uniqueness the lookup rests on is a replay guard: a parameter
-    draw (same compiled key, same catalog version) or a swapped-in table
-    whose alive build keys repeat rediscovers onto the expand path and
-    answers as the reference does."""
+@pytest.mark.parametrize("method", list(_LJ_METHODS))
+def test_lookup_join_guard_rediscovers(monkeypatch, method):
+    """The uniqueness the lookup rests on is a replay guard, under either
+    lookup method (compare: the sorted alive keys differ; gather: no
+    count over the key domain passes 1): a parameter draw (same compiled
+    key, same catalog version) or a swapped-in table whose alive build
+    keys repeat rediscovers onto the expand path and answers as the
+    reference does."""
     import warnings
     from ndstpu import obs
+    from ndstpu.engine import jaxexec
+    monkeypatch.setattr(jaxexec, "_COMPARE_PAIR_COST", _LJ_METHODS[method])
+    looked_up = (1, 0, 0, 1 if method == "compare" else 0)
     catalog = _guard_catalog()
     cpu = Session(catalog, backend="cpu")
     sess = Session(catalog, backend="tpu")
@@ -943,7 +1036,7 @@ def test_lookup_join_guard_rediscovers():
     assert sess.canonical_key(uniq) == sess.canonical_key(dup)
     for _run in ("discovery", "replay"):
         assert_tables_match(cpu.sql(uniq), sess.sql(uniq))
-    assert sess.compiled_plan(uniq).join_paths == (1, 0, 0)
+    assert sess.compiled_plan(uniq).join_paths == looked_up
     before = obs.counters_snapshot().get("engine.discoveries", 0)
     with warnings.catch_warnings(record=True) as caught:
         warnings.simplefilter("always")
@@ -951,11 +1044,11 @@ def test_lookup_join_guard_rediscovers():
     assert any("rediscover" in str(w.message) for w in caught)
     assert obs.counters_snapshot()["engine.discoveries"] == before + 1
     assert_tables_match(cpu.sql(dup), sess.sql(dup))     # replay
-    assert sess.compiled_plan(dup).join_paths == (0, 1, 0)
+    assert sess.compiled_plan(dup).join_paths == (0, 1, 0, 0)
     # and back: unique again under the first draw
     assert_tables_match(cpu.sql(uniq), sess.sql(uniq))
     assert_tables_match(cpu.sql(uniq), sess.sql(uniq))
-    assert sess.compiled_plan(uniq).join_paths == (1, 0, 0)
+    assert sess.compiled_plan(uniq).join_paths == looked_up
     # a table swapped in under a new catalog version
     from ndstpu.engine.columnar import Table
     dim = catalog.get("dim")
@@ -963,15 +1056,51 @@ def test_lookup_join_guard_rediscovers():
         **dim.columns, "d_val": _i32([i % 3 for i in range(dim.num_rows)])}))
     for _run in ("discovery", "replay"):
         assert_tables_match(cpu.sql(uniq), sess.sql(uniq))
-    assert sess.compiled_plan(uniq).join_paths == (0, 1, 0)
+    assert sess.compiled_plan(uniq).join_paths == (0, 1, 0, 0)
+
+
+_EDGE_SQL = ("select f_id, d_k, d_val from fact join "
+             "(select * from dim where d_k < {hi}) d on f_k = d_k")
+
+
+def test_compare_join_capacity_guard_rediscovers():
+    """The compare path is traced for a capacity of alive build keys,
+    a replay guard like its uniqueness branch (exercised above): a
+    later binding of the same compiled key that keeps
+    one build key more than the class holds rediscovers once, answers
+    as the reference does, and compares over the next class."""
+    import warnings
+    from ndstpu import obs
+    catalog = _lj_catalog("edge")
+    cpu = Session(catalog, backend="cpu")
+    sess = Session(catalog, backend="tpu")
+    fits, outgrows = _EDGE_SQL.format(hi=1380), _EDGE_SQL.format(hi=1385)
+    assert sess.canonical_key(fits) == sess.canonical_key(outgrows)
+    for _run in ("discovery", "replay"):
+        assert_tables_match(cpu.sql(fits), sess.sql(fits))
+    cp = sess.compiled_plan(fits)
+    assert cp.join_paths == (1, 0, 0, 1) and ("cap", 256) in cp.record
+    before = obs.counters_snapshot().get("engine.discoveries", 0)
+    with warnings.catch_warnings(record=True) as caught:
+        warnings.simplefilter("always")
+        assert_tables_match(cpu.sql(outgrows), sess.sql(outgrows))
+    assert any("rediscover" in str(w.message) for w in caught)
+    assert_tables_match(cpu.sql(outgrows), sess.sql(outgrows))   # replay
+    assert obs.counters_snapshot()["engine.discoveries"] == before + 1
+    cp = sess.compiled_plan(outgrows)
+    assert cp.join_paths == (1, 0, 0, 1) and ("cap", 512) in cp.record
+    # the smaller draw fits the larger class: no rediscovery back
+    assert_tables_match(cpu.sql(fits), sess.sql(fits))
+    assert obs.counters_snapshot()["engine.discoveries"] == before + 1
 
 
 def test_compile_records_older_format_ignored(tmp_path):
-    """A record file written before the size plans gained the lookup
-    branch (format 4) loads nothing and is rewritten, not merged."""
+    """A record file written before the size plans gained the alive
+    build keys' capacity (format 5) loads nothing and is rewritten, not
+    merged."""
     import pickle
     from ndstpu.engine.jaxexec import CompilingExecutor
-    assert CompilingExecutor._REC_FORMAT == 5
+    assert CompilingExecutor._REC_FORMAT == 6
     catalog = _lj_catalog("probe")
     sql = _lj_sql("inner", "filtered", False)
     s1 = Session(catalog, backend="tpu")
@@ -980,8 +1109,8 @@ def test_compile_records_older_format_ignored(tmp_path):
     assert s1.save_compiled(path) == 1
     with open(path, "rb") as f:
         data = pickle.load(f)
-    data["\x00fmt"] = 4
-    data["select 'written by format 4'"] = data[sql]
+    data["\x00fmt"] = 5
+    data["select 'written by format 5'"] = data[sql]
     with open(path, "wb") as f:
         pickle.dump(data, f)
     s2 = Session(catalog, backend="tpu")
@@ -991,35 +1120,41 @@ def test_compile_records_older_format_ignored(tmp_path):
     assert s2.save_compiled(path) == 1
     with open(path, "rb") as f:
         data = pickle.load(f)
-    assert data["\x00fmt"] == 5 and sql in data
-    assert "select 'written by format 4'" not in data
+    assert data["\x00fmt"] == 6 and sql in data
+    assert "select 'written by format 5'" not in data
     assert Session(catalog, backend="tpu").preload_compiled(path) == 1
 
 
-def test_join_path_counters_and_span(catalog):
+@pytest.mark.parametrize("method", list(_LJ_METHODS))
+def test_join_path_counters_and_span(monkeypatch, catalog, method):
     """Each replay adds its programs' join operators, by the path each
-    took at trace time, to three counters and to the replay span."""
+    took at trace time, to four counters and to the replay span."""
     from ndstpu import obs
+    from ndstpu.engine import jaxexec
+    monkeypatch.setattr(jaxexec, "_COMPARE_PAIR_COST", _LJ_METHODS[method])
+    n_compare = 2 if method == "compare" else 0
     sql = next(iter(streamgen.render_template_parts(
         str(streamgen.TEMPLATE_DIR / "query3.tpl"), "07291122510", 0)))[1]
     obs.reset(enabled=True)
     try:
         sess = Session(catalog, backend="tpu")
         sess.sql(sql)                               # discovery: no replay
-        names = ["engine.replay.join_" + k
-                 for k in ("lookup", "expand", "sort")]
+        paths = ("lookup", "expand", "sort", "compare")
+        names = ["engine.replay.join_" + k for k in paths]
         assert not any(k in obs.counters_snapshot() for k in names)
+        # both of query3's joins are lookups; those that compare are
+        # counted under lookup too
         for n_replays in (1, 2):
             sess.sql(sql)
             snap = obs.counters_snapshot()
-            assert [snap[k] for k in names] == [2 * n_replays, 0, 0]
+            assert [snap[k] for k in names] == \
+                [2 * n_replays, 0, 0, n_compare * n_replays]
         cp = sess.compiled_plan(sql)
         programs = [cp] + [sess._jax_executor()._seg_compiled[fp]
                            for fp in (cp.seg_fps or ())]
         assert [sum(p.join_paths[i] for p in programs)
-                for i in range(3)] == [2, 0, 0]
+                for i in range(4)] == [2, 0, 0, n_compare]
         span = [e for e in obs.tracer().events if e["name"] == "replay"][-1]
-        assert [span["args"]["join_" + k]
-                for k in ("lookup", "expand", "sort")] == [2, 0, 0]
+        assert [span["args"]["join_" + k] for k in paths] == [2, 0, 0, n_compare]
     finally:
         obs.reset()

@@ -52,3 +52,75 @@ def test_segment_sum_decimal_empty_mask():
         block_rows=256, block_segs=128, interpret=True)
     assert np.asarray(sums).tolist() == [0] * s
     assert np.asarray(counts).tolist() == [0] * s
+
+
+# -- keycmp: which of K unique keys is each row's ---------------------------
+
+
+@pytest.mark.parametrize("n,k,alive", [(256, 256, 0), (256, 256, 256),
+                                       (1024, 512, 257), (8192, 256, 40),
+                                       (131072, 272, 200),
+                                       # capacities no power of two (a
+                                       # UNION ALL's): padded to blocks
+                                       (256 + 8192, 256, 100),
+                                       ((1 << 18) + (1 << 15), 256, 60),
+                                       (200, 256, 3)])
+def test_keycmp_match_rows(n, k, alive):
+    """The kernel (interpreted) against a table over the key domain:
+    unused key slots (-2) and NULL / dead probe keys (-1) match nothing."""
+    from ndstpu.ops import keycmp
+    rng = np.random.RandomState(n + alive)
+    domain = 5000
+    keys = np.full(k, -2, np.int32)
+    keys[:alive] = rng.choice(domain, alive, replace=False)
+    rows = rng.randint(0, 10 ** 6, k).astype(np.int32)
+    x = rng.randint(-1, domain, n).astype(np.int32)
+    x[: min(alive, n)] = keys[: min(alive, n)]      # every key asked for
+    got = np.asarray(keycmp.match_rows(
+        jnp.asarray(x), jnp.asarray(keys), jnp.asarray(rows),
+        interpret=True))
+    lut = np.full(domain, -1, np.int32)
+    lut[keys[:alive]] = rows[:alive]
+    want = np.where(x >= 0, lut[np.clip(x, 0, None)], -1)
+    np.testing.assert_array_equal(got, want)
+
+
+def test_keycmp_rejects_untiled_keys():
+    from ndstpu.ops import keycmp
+    ok = jnp.zeros(256, jnp.int32)
+    with pytest.raises(ValueError):
+        keycmp.match_rows(ok, ok[:5], ok[:5], interpret=True)
+
+
+# The chip's own compiler, for a chip that is described and not attached:
+# what the interpreter cannot refuse (tiling, scalar memory).  Nothing runs.
+
+@pytest.fixture(scope="module")
+def one_chip():
+    import os
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:   # noqa: BLE001 -- no TPU compiler here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.mark.parametrize("n,k", [(256, 256), (1 << 20, 8192),
+                                 (1 << 22, 512), (1 << 22, 32768),
+                                 (256 + 8192, 256),
+                                 ((1 << 20) + (1 << 17), 512)])
+def test_keycmp_compiles_for_v5e(one_chip, n, k):
+    import jax
+    from ndstpu.engine import jaxexec
+    from ndstpu.ops import keycmp
+    assert k <= jaxexec._COMPARE_MAX_KEYS
+    x = jax.ShapeDtypeStruct((n,), jnp.int32, sharding=one_chip)
+    kk = jax.ShapeDtypeStruct((k,), jnp.int32, sharding=one_chip)
+    compiled = keycmp.match_rows.lower(x, kk, kk).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    # nothing of [K, n] is materialised
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 * n
