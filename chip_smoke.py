@@ -117,13 +117,12 @@ class Smoke:
         if self.rehearsal:
             # the explicit pin is what lets the accelerator engines run
             # on the CPU at all (ndstpu/engine/device.py); four virtual
-            # devices rehearse the spmd stage; the kernel runs in the
-            # Pallas interpreter inside whole-query programs too
+            # devices rehearse the spmd stage; the kernels run in the
+            # Pallas interpreter inside whole-query programs
             self.env["JAX_PLATFORMS"] = "cpu"
             self.env["XLA_FLAGS"] = (
                 self.env.get("XLA_FLAGS", "") +
                 " --xla_force_host_platform_device_count=4").strip()
-            self.env["NDSTPU_GROUPBY"] = "pallas"
             # a cpu-pinned process sets no cache directory in code;
             # give the rehearsal one so the warm checks have a cache
             self.env.setdefault("JAX_COMPILATION_CACHE_DIR",

@@ -83,8 +83,8 @@ CODES: Dict[str, Tuple[str, str]] = {
                           "single-chip whole-fact path (the fact must fit "
                           "HBM resident; spmd_chunk_rows is ignored there)"),
     "NDS312": ("info", "string join key shards on frozen global-dictionary "
-                       "codes (no build-dictionary translation; "
-                       "NDSTPU_GLOBAL_DICTS=0 restores the translate path)"),
+                       "codes (no build-dictionary translation; a "
+                       "warehouse without the sidecar translates)"),
     # -- NDS4xx canonicalization / parameter lifting ----------------------
     "NDS401": ("info", "shape-affecting literal: value feeds static shape "
                        "or capacity planning (LIMIT, interval width, "

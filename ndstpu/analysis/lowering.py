@@ -588,15 +588,13 @@ class LoweringAuditor:
                                "shardable on the spine",
                                f"{npath}/keys[{i}]")
                 elif t.known and t.kind == "string":
-                    from ndstpu.io import gdict
-                    if gdict.enabled():
-                        # static mirror of dplan._probe_keys' identity
-                        # path: with warehouse-wide frozen dictionaries
-                        # both sides share one code space and the key
-                        # shards on raw codes
-                        self._emit("NDS312", "string join key shards "
-                                   "on frozen global-dictionary codes",
-                                   f"{npath}/keys[{i}]")
+                    # static mirror of dplan._probe_keys' identity
+                    # path: with warehouse-wide frozen dictionaries
+                    # both sides share one code space and the key
+                    # shards on raw codes
+                    self._emit("NDS312", "string join key shards "
+                               "on frozen global-dictionary codes",
+                               f"{npath}/keys[{i}]")
             est = model.estimate(build)
             reducible = (
                 node.kind in SPMD_REDUCIBLE_BUILD_JOIN_KINDS

@@ -1,7 +1,7 @@
 """Which device a process runs on, and where its compiled code is kept.
 
 Every entry point that builds an accelerator session (power run,
-in-process scheduler, serve daemon, root bench.py, chip_smoke.py) goes
+in-process scheduler, serve daemon, chip_smoke.py) goes
 through this module for three decisions:
 
 * **May an accelerator engine run here?**  :func:`require_accelerator`

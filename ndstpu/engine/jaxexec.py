@@ -116,11 +116,23 @@ _COMPARE_PAIR_COST = 5.6e-5
 _COMPARE_MAX_KEYS = 32768
 # "compare" joins are counted under "lookup" too (they are lookups)
 _JOIN_PATHS = ("lookup", "expand", "sort", "compare")
-
-# Engine default for NDSTPU_GROUPBY.  Module-level and literal on
-# purpose: obs/artifact_lint.py parses it from source (no jax import)
-# to cross-check docs/*.json artifacts that pin `engine_defaults`.
-GROUPBY_DEFAULT = "pallas"
+# group-by by linearized key (_direct_group_ids): the most slots of a
+# composite key domain; a larger one takes the sort path.  1 << 16 left
+# q2's pivoted (d_week_seq x d_day_name) composite key (~83k slots) --
+# and q59's (week x store x day, ~1.17M) -- on the SORT path: a full
+# multi-key sort of the 2-3M-row fact spine that costs more than the
+# masked scatters the pivot removed.  Slot buffers are domain-sized
+# (1 << 21 x 8 B = 16 MB per reduction, freed per aggregate), trivial
+# next to the row data; sparse scatter output stays cheaper than
+# sorting millions of rows.  (No chip reading on either side of it yet:
+# no cell groups by a domain near the cap.)
+_GROUPBY_DOMAIN_CAP = 1 << 21
+# direct-addressed join (_lut_span): the most slots of the key domain;
+# a larger one takes the combined sort.  The count and start tables of
+# that many slots live in HBM (2 x 4 B x slots; 1 << 25 -> 256 MB peak,
+# freed per join).  query25's store_sales x store_returns on three keys
+# is over it: 78 ms a pass on the sort path (PR 29's chip run).
+_JOIN_LUT_CAP = 1 << 25
 
 
 def size_class(n: int) -> int:
@@ -1363,10 +1375,9 @@ class JEval:
                 if oc.ctype.kind != "string" or oc.dictionary is None:
                     raise Unsupported("string parameter vs non-dictionary"
                                       " operand", code="NDS206")
-                from ndstpu.io import gdict
-                if op in ("=", "<>") and gdict.enabled():
+                if op in ("=", "<>"):
                     # scalar dict-code param: the bound value resolves
-                    # to one frozen-dictionary code on the host (miss ->
+                    # to one dictionary code on the host (miss ->
                     # len(dict) sentinel), so equality runs on raw codes
                     # and every binding replays one traced scalar
                     code = ctx.str_code(par.slot, oc.dictionary)
@@ -1734,42 +1745,17 @@ class JaxExecutor:
         # currently being discovered / eager-executed
         self._seg_compiled: Dict[str, "_CompiledPlan"] = {}
         self._seg_tables: Dict[str, DTable] = {}
-        # group-by strategy: "sort" = lexsort dense-rank only; "auto" =
-        # linearized gid when the key domain is small (skips the sort);
-        # "pallas" = auto + one-hot MXU segment sums for exact
-        # decimal/int aggregates (ndstpu.ops.segsum).  Read once per
-        # executor: the choice is baked into traced programs.
-        # Default is pallas: v5e has no native int64 ALU, so XLA's
-        # int64 scatter-add is emulated on the VPU while the limb
-        # kernel runs on the MXU (kernel-vs-scatter times: not measured
-        # on today's code; chip_smoke.py proves it compiles and is
-        # exact).
-        # The kernel only engages where it would COMPILE (TPU replay);
-        # interpret-mode execution (CPU platforms, eager/discovery
-        # passes) keeps the scatter path unless NDSTPU_GROUPBY=pallas
-        # is set explicitly (tests use that for interpreter coverage).
-        import os as _os
-        self.groupby_mode = _os.environ.get("NDSTPU_GROUPBY",
-                                            GROUPBY_DEFAULT)
-        self._groupby_explicit = "NDSTPU_GROUPBY" in _os.environ
-        self.groupby_domain_cap = int(
-            _os.environ.get("NDSTPU_GROUPBY_DOMAIN", str(1 << 21)))
-        # 1<<16 left q2's pivoted (d_week_seq x d_day_name) composite
-        # key (~83k slots) — and q59's (week x store x day, ~1.17M) —
-        # on the SORT path: a full multi-key sort of the 2-3M-row fact
-        # spine that costs more than the masked scatters the pivot
-        # removed.  Slot buffers are ngseg-sized (1<<21 x 8B = 16 MB
-        # per reduction, freed per aggregate), trivial next to the row
-        # data; sparse scatter output stays cheaper than sorting
-        # millions of rows.
-        # LUT-join domain cap: counts/starts tables of `bound` slots live
-        # in HBM (2 x 4B x bound; 1<<25 -> 256 MB peak, freed per join)
-        self.join_lut_cap = int(
-            _os.environ.get("NDSTPU_JOIN_LUT_CAP", str(1 << 25)))
+        # ONE configuration, the one the benchmark's cells run: no
+        # environment variable selects a path of this executor.  The
+        # thresholds between paths are module constants beside their
+        # chip readings (_GROUPBY_DOMAIN_CAP, _JOIN_LUT_CAP,
+        # _SEARCH_COMPACT_COST, _COMPARE_PAIR_COST); a Pallas kernel
+        # engages by one rule (_pallas_kernel).
         # compile+run the jitted replay at the end of discovery so
         # steady-state executions never pay a trace/compile (opt out
-        # with NDSTPU_WARM_REPLAY=0)
-        self.warm_replay = _os.environ.get(
+        # with NDSTPU_WARM_REPLAY=0: WHEN the replay program compiles,
+        # not which program)
+        self.warm_replay = os.environ.get(
             "NDSTPU_WARM_REPLAY", "1") != "0"
         # introspection counters: tests assert steady-state executions
         # re-run NO discovery and build NO new jitted programs
@@ -2287,13 +2273,10 @@ class JaxExecutor:
                          c.ctype, c.dictionary)
             key_cols.append((name, c))
         self._grouping_ctx = ([n for n, _ in p.group_by], subset)
-        use_pallas = False
-        direct = None
-        if key_cols and self.groupby_mode in ("auto", "pallas"):
-            direct = self._direct_group_ids(key_cols, dt.alive)
+        direct = self._direct_group_ids(key_cols, dt.alive) \
+            if key_cols else None
         if direct is not None:
             gid, ngseg, out_alive, out_cols, order = direct
-            use_pallas = self.groupby_mode == "pallas"
         elif key_cols:
             keys = [_key_col(c, dt.alive) for _, c in key_cols]
             gid, order, newgrp = _group_ids(keys)
@@ -2337,7 +2320,7 @@ class JaxExecutor:
         for name, e in p.aggs:
             out_cols[name] = self._eval_agg(
                 dt, evl, self._resolve_subqueries(e), gid, ngseg, out_alive,
-                order, use_pallas)
+                order, dense=direct is not None)
         return DTable(out_cols, out_alive)
 
     def _direct_group_ids(self, key_cols, alive):
@@ -2366,7 +2349,7 @@ class JaxExecutor:
             if span <= 0:
                 return None
             domain *= span + 1
-            if domain > self.groupby_domain_cap or domain >= 2 ** 31 - 1:
+            if domain > _GROUPBY_DOMAIN_CAP or domain >= 2 ** 31 - 1:
                 return None
             parts.append((c, lo, span))
         cap = int(alive.shape[0])
@@ -2398,14 +2381,12 @@ class JaxExecutor:
             # the replay guard so the query rediscovers (and the eager
             # pass below warns) instead of silently dropping rows
             self._oks.append(~jnp.any(bad))
-        elif self._in_discovery or \
-                os.environ.get("NDSTPU_DEBUG_BOUNDS", "0") not in ("", "0"):
+        elif self._in_discovery:
             # the bool() forces a blocking device sync — pay it during
             # discovery (which covers demoted-to-eager subtrees too:
             # every query's FIRST execution passes through
             # _discover_plan, so bugs surface then), not on every
-            # steady-state demoted eager aggregate.  NDSTPU_DEBUG_BOUNDS
-            # restores the per-execution check.
+            # steady-state demoted eager aggregate.
             if bool(jnp.any(bad)):
                 import warnings
                 warnings.warn(
@@ -2455,10 +2436,10 @@ class JaxExecutor:
                                       code="NDS207")
 
     def _eval_agg(self, dt: DTable, evl: JEval, e: ex.Expr, gid, ngseg,
-                  out_alive, order, use_pallas: bool = False) -> DCol:
+                  out_alive, order, dense: bool = False) -> DCol:
         if isinstance(e, ex.AggExpr):
             return self._agg_column(dt, evl, e, gid, ngseg, out_alive,
-                                    order, use_pallas)
+                                    order, dense)
         if isinstance(e, ex.Func) and e.name == "grouping":
             # grouping(key) = 0 when the key participates in this grouping
             # set, 1 when rolled up (Spark semantics)
@@ -2482,14 +2463,14 @@ class JaxExecutor:
                     counter[0] += 1
                     sub_cols[name] = self._agg_column(
                         dt, evl, node, gid, ngseg, out_alive, order,
-                        use_pallas)
+                        dense)
                     return ex.ColumnRef(name)
                 if isinstance(node, ex.Func) and node.name == "grouping":
                     name = f"__agg{counter[0]}"
                     counter[0] += 1
                     sub_cols[name] = self._eval_agg(
                         dt, evl, node, gid, ngseg, out_alive, order,
-                        use_pallas)
+                        dense)
                     return ex.ColumnRef(name)
                 if isinstance(node, ex.BinOp):
                     return ex.BinOp(node.op, lower(node.left),
@@ -2554,12 +2535,21 @@ class JaxExecutor:
         return df64.segment_sum_compensated2(x1, x2, gid, ngseg, order,
                                              levels)
 
-    def _pallas_interpret(self) -> bool:
-        """Mosaic lowering only exists on real TPU backends; everywhere
-        else (CPU tests, host-pinned discovery) run the interpreter."""
+    def _pallas_kernel(self) -> Optional[Dict[str, bool]]:
+        """The one rule for a Pallas kernel (segsum, keycmp) in a
+        program: it is part of a TRACED REPLAY program and of nothing
+        else -- None in eager execution and discovery, which take the
+        plain jnp form of the same result (op by op on the host there:
+        the interpreter over a power-run-sized grid, or an unfused
+        [K, n] compare, is far slower than XLA's scatter); the choice
+        adds no entry to the size plan, so discovery on the plain form
+        and replay on the kernel stay record-consistent.  Else the
+        kernel's call options: Mosaic lowers it on a TPU, the
+        interpreter runs it where the default platform is the CPU
+        (tests, rehearsals)."""
         if self.mode != "replay":
-            return True
-        return default_platform() == "cpu"
+            return None
+        return {"interpret": default_platform() == "cpu"}
 
     # one-hot MXU segment sums stay exact while every |value| < 2^41
     # (ndstpu.ops.segsum bias bound) and rows fit the int32 accumulator
@@ -2583,7 +2573,9 @@ class JaxExecutor:
         return False
 
     def _agg_column(self, dt: DTable, evl: JEval, a: ex.AggExpr, gid, ngseg,
-                    out_alive, order, use_pallas: bool = False) -> DCol:
+                    out_alive, order, dense: bool = False) -> DCol:
+        """``dense``: ``gid`` came from _direct_group_ids (``ngseg`` is
+        the key domain, not the row capacity)."""
         func = a.func
         alive = dt.alive
         if a.distinct and func in ("count", "sum", "avg") and \
@@ -2600,23 +2592,18 @@ class JaxExecutor:
                         INT64)
         c = evl.eval(a.arg)
         valid = c.valid & alive
-        if use_pallas and func in ("sum", "avg") and \
-                self._pallas_sum_ok(c, ngseg) and \
-                (not self._pallas_interpret() or self._groupby_explicit):
-            # exact int64 sums + counts in one one-hot MXU kernel pass.
-            # Interpret-mode execution (eager/discovery, CPU platforms)
-            # keeps the scatter path unless pallas was requested
-            # explicitly: the Pallas INTERPRETER over a power-run-sized
-            # grid is drastically slower than XLA's scatter, and the
-            # path choice adds no size-plan sync points, so discovery-
-            # on-scatter + replay-on-kernel stays record-consistent.
+        kernel = self._pallas_kernel() if dense and func in (
+            "sum", "avg") and self._pallas_sum_ok(c, ngseg) else None
+        if kernel is not None:
+            # exact int64 sums + counts in one one-hot MXU kernel pass
+            # (v5e has no native int64 ALU: XLA's int64 scatter-add is
+            # emulated on the VPU; kernel against scatter not timed)
             from ndstpu.ops import segsum
             # ticks at trace time, like the exchange.* counters: proof
             # that a compiled program really contains the kernel
             obs.inc("engine.pallas.segsum_calls")
             sums, cnts = segsum.segment_sum_decimal(
-                c.data.astype(jnp.int64), gid, valid, ngseg,
-                interpret=self._pallas_interpret())
+                c.data.astype(jnp.int64), gid, valid, ngseg, **kernel)
             if func == "sum":
                 if c.ctype.kind == "decimal":
                     return DCol(sums, cnts > 0, decimal(38, c.ctype.scale))
@@ -3211,7 +3198,7 @@ class JaxExecutor:
         # `bound` slots, so a near-cap domain against tiny tables would
         # cost far more than the sort path over m+n rows
         if bound is not None and 0 < bound <= min(
-                self.join_lut_cap, max(8 * (m + n), 1 << 20)):
+                _JOIN_LUT_CAP, max(8 * (m + n), 1 << 20)):
             return int(bound)
         return None
 
@@ -3544,16 +3531,16 @@ class JaxExecutor:
     def _compare_rows(self, lkey: jnp.ndarray, bkeys: jnp.ndarray,
                       brows: jnp.ndarray, span: int) -> jnp.ndarray:
         """The same rows with no table and no gather: ``lkey`` against
-        every one of the unique ``bkeys``, in the keycmp kernel.  Eager
-        execution (discovery, op by op on the host, where a [K, n]
-        compare would be materialised) scatters the K keys into the
-        table instead: the choice adds no entry to the size plan."""
-        if self.mode != "replay":
+        every one of the unique ``bkeys``, in the keycmp kernel.  Where
+        no kernel is traced (_pallas_kernel: eager execution, where a
+        [K, n] compare would be materialised) the K keys are scattered
+        into the table instead."""
+        kernel = self._pallas_kernel()
+        if kernel is None:
             return self._table_rows(
                 lkey, jnp.where(bkeys >= 0, bkeys, span), brows, span)
         from ndstpu.ops import keycmp
-        return keycmp.match_rows(lkey, bkeys, brows,
-                                 interpret=self._pallas_interpret())
+        return keycmp.match_rows(lkey, bkeys, brows, **kernel)
 
     @staticmethod
     def _survivor_positions(mask: jnp.ndarray, cap: int) -> jnp.ndarray:

@@ -317,11 +317,9 @@ class Session:
     def _canonicalize(self, plan: lp.Plan, key: str):
         """Parameter-lift an optimized plan (analysis/canon.py) for
         shape-keyed compile caching.  None (→ text keying) on any
-        canonicalization failure or with NDSTPU_CANON=0 — the safety
-        valve keeps queries running when the analyzer is wrong."""
-        import os
-        if os.environ.get("NDSTPU_CANON", "1") in ("", "0"):
-            return None
+        canonicalization failure, counted in ``engine.canon.errors`` —
+        the safety valve keeps queries running when the analyzer is
+        wrong."""
         from ndstpu import obs
         try:
             from ndstpu.analysis import canon as _canon

@@ -165,8 +165,6 @@ class MicroBatchIngestor:
         dict versions ride snapshot versions exactly.  Pinned readers
         keep selecting the dict entry matching their pinned snapshot;
         only new loads see the grown value set."""
-        if not gdict.enabled():
-            return
         for table, cur in sorted(post.items()):
             if pre.get(table) == cur:
                 continue

@@ -521,9 +521,9 @@ def run_query_stream(args) -> None:
 
     # per-query watchdog (accel engines): a compile or execution that
     # never returns otherwise blocks the stream forever — abandon such
-    # a query in a daemon thread (root bench.py does the same).  The
-    # abandoned thread keeps only the OLD session, so the stream
-    # continues on a fresh one (records preloaded again).
+    # a query in a daemon thread.  The abandoned thread keeps only the
+    # OLD session, so the stream continues on a fresh one (records
+    # preloaded again).
     #
     # Device-sharing hazard: the abandoned thread still drives the old
     # session on the SAME TPU runtime the fresh session uses; a late

@@ -33,11 +33,11 @@ loaders, which glob by extension, exactly like ``_SUCCESS``):
   already keys every epoch-invalidated cache, so dict growth rides
   the existing invalidation for free.
 
-Kill switch: ``NDSTPU_GLOBAL_DICTS=0`` disables the layer everywhere
-(loaders fall back to per-call dictionaries, chunk sources reject
-string columns again, joins translate through merged dictionaries).
-``scripts/dict_audit.py`` sweeps sidecar sizes + corpus coverage into
-the ``DICT_AUDIT.*`` artifacts.
+A table with no sidecar (a warehouse transcoded before the layer, or
+hand-written parquet) takes the per-call paths: loaders build per-call
+dictionaries, chunk sources reject its string columns, joins translate
+through merged dictionaries.  ``scripts/dict_audit.py`` sweeps sidecar
+sizes + corpus coverage into the ``DICT_AUDIT.*`` artifacts.
 
 Counters (docs/OBSERVABILITY.md): ``engine.dict.lookups`` /
 ``engine.dict.misses`` per bind-time value lookup,
@@ -60,11 +60,6 @@ GDICT_FILE = "_GLOBAL_DICTS.json"
 
 #: sidecar schema version
 FORMAT = 1
-
-
-def enabled() -> bool:
-    """NDSTPU_GLOBAL_DICTS=0 kills the global-dictionary layer."""
-    return os.environ.get("NDSTPU_GLOBAL_DICTS", "1") not in ("", "0")
 
 
 def _obs_inc(name: str, value: float = 1) -> None:
@@ -177,8 +172,6 @@ def table_dicts(table_dir: str, table: Optional[str] = None,
     """Load the frozen dictionaries for one table, selecting per column
     the version matching ``pin_table_version`` (snapshot-pinned chunk
     sources) or the newest (live loads)."""
-    if not enabled():
-        return {}
     doc = _read_sidecar(table_dir)
     if doc is None:
         return {}
@@ -274,8 +267,6 @@ def grow_for_table(table_dir: str, table: Optional[str] = None,
     commit: only columns whose value set actually grew get a new
     version, stamped with the commit's lake version.  Idempotent, so a
     retried or resumed batch converges on the same sidecar."""
-    if not enabled():
-        return {}
     from ndstpu.io import lake
     tname = table or os.path.basename(os.path.normpath(table_dir))
     if not lake.is_lake(table_dir):

@@ -231,21 +231,16 @@ class StreamUnsupported(RuntimeError):
 
 
 def _string_stream_reject(table: str, col: str) -> StreamUnsupported:
-    """Why a string column cannot stream, naming the knob that changes
-    the answer: streaming strings requires the table's frozen global
+    """Why a string column cannot stream, naming what changes the
+    answer: streaming strings requires the table's frozen global
     dictionary (ndstpu/io/gdict.py) so every chunk emits codes in one
     shared code space."""
-    if not gdict.enabled():
-        why = ("global dictionaries are disabled "
-               "(NDSTPU_GLOBAL_DICTS=0)")
-    else:
-        why = (f"the table has no {gdict.GDICT_FILE} sidecar covering "
-               f"it — re-transcode the warehouse to build one; "
-               f"scripts/dict_audit.py (DICT_AUDIT.md) reports "
-               f"per-column coverage")
     return StreamUnsupported(
         f"string column {col} of {table}: per-chunk dictionaries do not "
-        f"share a code space, and {why}")
+        f"share a code space, and the table has no {gdict.GDICT_FILE} "
+        f"sidecar covering it — re-transcode the warehouse to build "
+        f"one (or run the table resident); scripts/dict_audit.py "
+        f"(DICT_AUDIT.md) reports per-column coverage")
 
 
 def _check_gdict_decode(t: columnar.Table, table: str) -> columnar.Table:
@@ -258,9 +253,8 @@ def _check_gdict_decode(t: columnar.Table, table: str) -> columnar.Table:
                 f"string column {n} of {table}: chunk holds values "
                 f"outside the frozen global dictionary (stale "
                 f"{gdict.GDICT_FILE} sidecar — re-transcode the table "
-                f"or check DICT_AUDIT.md coverage; "
-                f"NDSTPU_GLOBAL_DICTS=0 disables string streaming "
-                f"entirely)")
+                f"or check DICT_AUDIT.md coverage; the resident path "
+                f"loads it with per-load dictionaries)")
     return t
 
 
@@ -326,10 +320,10 @@ class ParquetChunkSource(ChunkSource):
     sidecar (ndstpu/io/gdict.py): every chunk decodes its strings
     against the frozen table-wide dictionary, so codes agree with the
     resident load and the traced spine's compile-time dictionary.
-    Without a sidecar (or with ``NDSTPU_GLOBAL_DICTS=0``) they are
-    rejected (``StreamUnsupported``): per-chunk dictionary encodings
-    would not share a code space.  Hive partition-key columns live in
-    directory names, not the files, and are likewise rejected.
+    Without a sidecar they are rejected (``StreamUnsupported``):
+    per-chunk dictionary encodings would not share a code space.  Hive
+    partition-key columns live in directory names, not the files, and
+    are likewise rejected.
     """
 
     def __init__(self, warehouse: str, table: str,

@@ -153,8 +153,6 @@ def _build_global_dicts(args, table: str, out_root: str, at) -> None:
     so snapshot-pinned readers can select the dict matching their
     pin."""
     from ndstpu.io import gdict
-    if not gdict.enabled():
-        return
     uniques = gdict.string_uniques_arrow(at)
     if not uniques:
         return

@@ -1,41 +1,28 @@
-"""Machine checks that committed docs and artifacts stay honest.
+"""Machine check that committed docs cite artifacts that exist.
 
-Two lints (CLI wrapper: scripts/doc_lint.py; wired into the test
+One lint (CLI wrapper: scripts/doc_lint.py; wired into the test
 suite via tests/test_doc_lint.py):
 
-1. **Citation lint** — scan ``docs/*.md`` (and README.md / a root
-   STATUS.md) for cited artifact paths (``docs/*.json``/``docs/*.csv``
-   and root ``BENCH_*.json`` / ``PLAN_LINT.json`` / ``PLAN_LINT.md`` /
-   ``CANON_AUDIT.json`` / ``CANON_AUDIT.md`` / ``MQO_AUDIT.json`` /
-   ``MQO_AUDIT.md`` / ``DICT_AUDIT.json`` / ``DICT_AUDIT.md`` /
-   ``COST_LINT.json`` / ``COST_LINT.md``)
-   and fail when a cited file is absent
-   from the tree.  A citation whose line carries an explicit
-   not-here-yet marker (``pending``, ``uncommitted``,
-   ``not committed``) is exempt — docs may *promise* an artifact, they
-   may not *cite* a ghost.  ``RUN_STATE.json`` citations are
-   recognized but exempt from the existence check: it is a per-run
-   resume journal (docs/ROBUSTNESS.md), never a committed file.
-
-2. **Config-mismatch lint** — a ``docs/*.json`` artifact may record
-   the engine defaults it was measured under in a top-level
-   ``engine_defaults`` map (e.g. ``{"NDSTPU_GROUPBY": "auto"}``).
-   When a recorded default no longer matches the code's current
-   default the artifact describes an engine that no longer exists;
-   lint fails unless the artifact is stamped ``"stale": true`` (with
-   ``describes_commit`` / ``stale_reason`` telling the reader what it
-   does describe).  Current defaults are parsed from the engine
-   *source* (jaxexec.py's ``GROUPBY_DEFAULT``), not imported —
-   importing the engine pulls jax, and lint must run anywhere.
+**Citation lint** — scan ``docs/*.md`` (and README.md / a root
+STATUS.md) for cited artifact paths (``docs/*.json``/``docs/*.csv``
+and root ``BENCH_*.json`` / ``PLAN_LINT.json`` / ``PLAN_LINT.md`` /
+``CANON_AUDIT.json`` / ``CANON_AUDIT.md`` / ``MQO_AUDIT.json`` /
+``MQO_AUDIT.md`` / ``DICT_AUDIT.json`` / ``DICT_AUDIT.md`` /
+``COST_LINT.json`` / ``COST_LINT.md``) and fail when a cited file is
+absent from the tree.  A citation whose line carries an explicit
+not-here-yet marker (``pending``, ``uncommitted``, ``not committed``)
+is exempt — docs may *promise* an artifact, they may not *cite* a
+ghost.  ``RUN_STATE.json`` citations are recognized but exempt from the
+existence check: it is a per-run resume journal (docs/ROBUSTNESS.md),
+never a committed file.
 """
 
 from __future__ import annotations
 
 import glob
-import json
 import os
 import re
-from typing import Dict, Iterable, List, Optional, Tuple
+from typing import Iterable, List, Optional, Tuple
 
 CITED_RE = re.compile(
     r"\bdocs/[A-Za-z0-9_.\-/]*\.(?:json|csv)\b"
@@ -59,10 +46,6 @@ EXEMPT_MARKERS = ("pending", "uncommitted", "not committed")
 # point at
 RUNTIME_ARTIFACTS = ("RUN_STATE.json", "INGEST_DIFF.json", "SLO.json",
                      "FLEET_HEALTH.json")
-
-_GROUPBY_DEFAULT_RE = re.compile(
-    r'^GROUPBY_DEFAULT\s*=\s*["\'](\w+)["\']', re.MULTILINE)
-
 
 def cited_artifacts(text: str) -> Iterable[Tuple[int, str, str]]:
     """(lineno, cited path, line) for every artifact citation."""
@@ -105,51 +88,6 @@ def lint_docs(root: str = ".",
     return findings
 
 
-def current_engine_defaults(root: str = ".") -> Dict[str, str]:
-    """Defaults artifacts may pin themselves to, parsed from source so
-    lint never needs to import jax."""
-    src_path = os.path.join(root, "ndstpu", "engine", "jaxexec.py")
-    out: Dict[str, str] = {}
-    try:
-        with open(src_path) as f:
-            src = f.read()
-    except OSError:
-        return out
-    m = _GROUPBY_DEFAULT_RE.search(src)
-    if m:
-        out["NDSTPU_GROUPBY"] = m.group(1)
-    return out
-
-
-def artifact_config_mismatches(
-        root: str = ".",
-        current: Optional[Dict[str, str]] = None) -> List[str]:
-    current = current if current is not None \
-        else current_engine_defaults(root)
-    findings: List[str] = []
-    for p in sorted(glob.glob(os.path.join(root, "docs", "*.json"))):
-        try:
-            with open(p) as f:
-                obj = json.load(f)
-        except (ValueError, OSError):
-            continue
-        if not isinstance(obj, dict):
-            continue
-        recorded = obj.get("engine_defaults")
-        if not isinstance(recorded, dict) or obj.get("stale"):
-            continue
-        rel = os.path.relpath(p, root)
-        for k, v in recorded.items():
-            cur = current.get(k)
-            if cur is not None and str(cur) != str(v):
-                findings.append(
-                    f"{rel}: measured under {k}={v} but the current "
-                    f"default is {k}={cur} - regenerate the artifact "
-                    f"or stamp it '\"stale\": true' with "
-                    f"describes_commit/stale_reason")
-    return findings
-
-
 def lint_repo(root: str = ".") -> List[str]:
-    """All lints; empty list means the committed tree is honest."""
-    return lint_docs(root) + artifact_config_mismatches(root)
+    """Empty list means the committed tree is honest."""
+    return lint_docs(root)

@@ -1069,9 +1069,8 @@ class DistributedPlanExecutor:
                         raise DistUnsupported(
                             "string join key without dictionary (no "
                             "frozen global dict either — see "
-                            "DICT_AUDIT.md coverage; "
-                            "NDSTPU_GLOBAL_DICTS=0 disables the "
-                            "global-dictionary path)",
+                            "DICT_AUDIT.md coverage; transcode the "
+                            "warehouse to build the sidecar)",
                             code="NDS307")
                     key_parts.append(c.data.astype(np.int64))
                     key_dicts.append(c.dictionary)
@@ -1846,12 +1845,11 @@ class DistributedPlanExecutor:
                     raise DistUnsupported(
                         f"string key against {c.ctype.kind} probe "
                         f"(no shared global dictionary — see "
-                        f"DICT_AUDIT.md; NDSTPU_GLOBAL_DICTS=0 "
-                        f"disables the global-dictionary path)",
+                        f"DICT_AUDIT.md; transcode the warehouse to "
+                        f"build the sidecar)",
                         code="NDS307")
                 np_dict = c.dictionary
-                from ndstpu.io import gdict as _gdict
-                if _gdict.enabled() and len(kd) == len(np_dict) and \
+                if len(kd) == len(np_dict) and \
                         np.array_equal(kd, np_dict):
                     # both sides carry the same frozen code space
                     # (warehouse-wide global dictionary): codes ARE the

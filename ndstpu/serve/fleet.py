@@ -34,9 +34,9 @@ run dir — a runtime artifact (never committed; artifact_lint exempts
 it like ``RUN_STATE.json``) that smoke tests and operators read for
 pids, readiness, restart counts, and the serve.fleet.* counters.
 
-``NDSTPU_FLEET=0`` is the kill switch: the supervisor degenerates to
-one replica, and the plain single-server ``ndstpu-serve`` path is
-untouched by this module entirely.
+``--replicas 1`` is a supervised single server, and the plain
+single-server ``ndstpu-serve`` path is untouched by this module
+entirely.
 
 **One process per chip.**  A chip belongs to one process at a time,
 so with an accelerator engine (and no ``JAX_PLATFORMS=cpu`` pin) the
@@ -68,7 +68,6 @@ from ndstpu.serve import protocol, transport
 
 FLEET_HEALTH_BASENAME = "FLEET_HEALTH.json"
 FLEET_HEALTH_ARTIFACT = "ndstpu-fleet-health-v1"
-FLEET_ENV = "NDSTPU_FLEET"
 
 
 @dataclasses.dataclass
@@ -150,10 +149,6 @@ class FleetSupervisor:
                  probe_fn: Optional[Callable] = None,
                  launcher: Optional[Callable] = None):
         self.config = config
-        if os.environ.get(FLEET_ENV, "") == "0":
-            print(f"[fleet] {FLEET_ENV}=0: degenerating to 1 replica")
-            config = dataclasses.replace(config, replicas=1)
-            self.config = config
         if config.replicas < 1:
             raise ValueError("fleet needs >= 1 replica")
         self._chip_bound = False

@@ -28,9 +28,8 @@ Usage::
 
 Without a warehouse argument a tiny SF-0.002 warehouse is generated
 and transcoded (the spmd_coverage.py pattern).  Exits nonzero on
-baseline regression.  NDSTPU_GLOBAL_DICTS=0 empties the inventory and
-turns every string-touching part uncovered — the audit reports what
-the kill switch costs.
+baseline regression.  A warehouse without sidecars has an empty
+inventory and every string-touching part uncovered.
 """
 
 import argparse
@@ -172,8 +171,7 @@ def check_baseline(statuses: dict, inv: dict, baseline: dict) -> list:
 def write_artifacts(inv: dict, statuses: dict, json_path, md_path):
     buckets = summarize(statuses)
     doc = {
-        "meta": {"tool": "scripts/dict_audit.py",
-                 "enabled": _enabled()},
+        "meta": {"tool": "scripts/dict_audit.py"},
         "summary": buckets,
         "inventory": inv,
         "parts": statuses,
@@ -182,8 +180,6 @@ def write_artifacts(inv: dict, statuses: dict, json_path, md_path):
         json.dumps(doc, indent=2, sort_keys=True) + "\n")
 
     lines = ["# Global-dictionary audit", ""]
-    lines.append(
-        f"- layer enabled: {_enabled()} (NDSTPU_GLOBAL_DICTS)")
     lines.append("- parts: " + ", ".join(
         f"{buckets[k]} {k}" for k in sorted(buckets)))
     lines += ["", "## Sidecar inventory", "",
@@ -200,11 +196,6 @@ def write_artifacts(inv: dict, statuses: dict, json_path, md_path):
         lines.append(f"| {name} | {st} |")
     lines.append("")
     pathlib.Path(md_path).write_text("\n".join(lines))
-
-
-def _enabled() -> bool:
-    from ndstpu.io import gdict
-    return gdict.enabled()
 
 
 def build_tiny_warehouse() -> str:

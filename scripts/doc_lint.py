@@ -2,10 +2,7 @@
 
 Fails (exit 1) when committed prose cites an artifact that is not in
 the tree (including the root ``PLAN_LINT.*`` / ``CANON_AUDIT.*`` /
-``MQO_AUDIT.*`` / ``DICT_AUDIT.*`` sweeps), or when a ``docs/*.json``
-artifact pins
-``engine_defaults``
-that no longer match the engine source and is not stamped stale.
+``MQO_AUDIT.*`` / ``DICT_AUDIT.*`` sweeps).
 
     python scripts/doc_lint.py [--root PATH]
 

@@ -14,9 +14,9 @@ string table as the sharded fact, proving off-hardware that:
   launches) bit-identical to the resident run: every chunk decodes
   against the same frozen sidecar dictionary, which is exactly the
   invariant that made string tables streamable at all;
-* **kill-switch parity** — a subprocess with ``NDSTPU_GLOBAL_DICTS=0``
-  (per-call dictionaries, translate-path joins) produces byte-identical
-  rows, and its chunk source rejects the string table
+* **no-sidecar parity** — a copy of the table without its
+  ``_GLOBAL_DICTS.json`` sidecar (per-call dictionaries) produces
+  byte-identical rows, and its chunk source rejects the string table
   (``StreamUnsupported``) as it did before the layer existed.
 
 Usage::
@@ -26,9 +26,9 @@ Usage::
 """
 from __future__ import annotations
 
-import json
 import os
 import pathlib
+import shutil
 import subprocess
 import sys
 import tempfile
@@ -71,34 +71,17 @@ def dist_rows(catalog, chunk_rows=None):
     return list(map(str, exe.execute_plan(plan).to_rows())), exe
 
 
-def subprocess_probe(wh: str) -> dict:
-    """Re-exec this script with the layer disabled: distributed rows
-    on the translate path + whether the chunk source rejects strings."""
-    env = dict(os.environ, PYTHONPATH=str(REPO),
-               NDSTPU_GLOBAL_DICTS="0")
-    out = subprocess.run(
-        [sys.executable, __file__, "--_probe", wh],
-        check=True, env=env, capture_output=True, text=True)
-    return json.loads(out.stdout.splitlines()[-1])
-
-
-def probe_mode(wh: str) -> int:
-    from ndstpu.io import loader
-    catalog = loader.load_catalog(wh)
-    rows, _ = dist_rows(catalog)
-    try:
-        loader.ParquetChunkSource(wh, "customer_address")
-        reject = None
-    except loader.StreamUnsupported as e:
-        reject = str(e)
-    print(json.dumps({"rows": rows, "stream_reject": reject}))
-    return 0
+def without_sidecar(wh: str, table: str) -> str:
+    """A warehouse holding ``table`` as transcode wrote it, less the
+    global-dictionary sidecar (one transcoded before the layer)."""
+    from ndstpu.io import gdict
+    bare = tempfile.mkdtemp(prefix="ndstpu_strsmoke_bare")
+    shutil.copytree(os.path.join(wh, table), os.path.join(bare, table),
+                    ignore=shutil.ignore_patterns(gdict.GDICT_FILE))
+    return bare
 
 
 def main() -> int:
-    if len(sys.argv) > 1 and sys.argv[1] == "--_probe":
-        return probe_mode(sys.argv[2])
-
     from ndstpu import obs
     from ndstpu.engine import physical
     from ndstpu.engine.session import Session
@@ -159,16 +142,22 @@ def main() -> int:
             "chunk-streamed string rows are not bit-identical to the "
             "resident oracle")
 
-    # kill switch: translate-path rows byte-identical, streaming rejected
-    probe = subprocess_probe(wh)
-    if probe["rows"] != oracle:
+    # no sidecar: per-call-dictionary rows byte-identical, streaming
+    # rejected
+    bare = without_sidecar(wh, "customer_address")
+    bare_rows, _ = dist_rows(
+        loader.load_catalog(bare, ["customer_address"]))
+    if bare_rows != oracle:
         failures.append(
-            "NDSTPU_GLOBAL_DICTS=0 translate-path rows differ from the "
+            "rows from the table without a sidecar differ from the "
             "global-dict rows")
-    if not probe["stream_reject"]:
+    try:
+        loader.ParquetChunkSource(bare, "customer_address")
         failures.append(
-            "NDSTPU_GLOBAL_DICTS=0 chunk source should reject string "
-            "columns (StreamUnsupported) but did not")
+            "a chunk source over a table without a sidecar should "
+            "reject string columns (StreamUnsupported) but did not")
+    except loader.StreamUnsupported:
+        pass
 
     if failures:
         print("\nstring stream smoke FAILED:")
@@ -177,7 +166,7 @@ def main() -> int:
         return 1
     print(f"\nstring stream smoke ok: {len(oracle)} rows bit-identical "
           f"across resident / {n_launches}-launch chunked stream / "
-          f"kill-switch translate path on a {N_DEV}-device mesh "
+          f"no-sidecar per-call dictionaries on a {N_DEV}-device mesh "
           f"(identity_joins={ident})")
     return 0
 

@@ -55,6 +55,23 @@ def tiny_sf():
     return 0.01
 
 
+@pytest.fixture()
+def without_sidecar(tmp_path):
+    """``without_sidecar(warehouse, table)``: a new warehouse holding
+    that table as transcode wrote it, less its global-dictionary
+    sidecar (a warehouse transcoded before the layer)."""
+    import shutil
+    from ndstpu.io import gdict
+
+    def strip(warehouse, table):
+        shutil.copytree(pathlib.Path(warehouse) / table, tmp_path / table,
+                        ignore=shutil.ignore_patterns(gdict.GDICT_FILE))
+        assert not gdict.has_sidecar(str(tmp_path / table))
+        return tmp_path
+
+    return strip
+
+
 @pytest.fixture(scope="session")
 def sf002_warehouse(tmp_path_factory):
     """ONE tiny plain-parquet warehouse (SF0.002, 2 chunks, the

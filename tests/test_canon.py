@@ -8,10 +8,10 @@ the value-dependent artifacts the optimizer leaves behind (generated
 ``__ssa`` column names, ``UnaryOp('neg')`` wrappers) must not leak in.
 
 Runtime half (tiny generated warehouse): canonical keying must be
-invisible to results (differential vs the text-keyed path under
-NDSTPU_CANON=0), must make re-renderings compile ZERO new programs, and
-must give a discover-process and a preload-process identical compile
-cache keys.
+invisible to results (differential vs the numpy engine; a raising
+canonicalizer falls back to text keying), must make re-renderings
+compile ZERO new programs, and must give a discover-process and a
+preload-process identical compile cache keys.
 """
 
 import math
@@ -205,20 +205,43 @@ def _rows(t):
     return sorted(out, key=repr)
 
 
-def test_canonical_results_match_text_keyed(catalog, monkeypatch):
+def test_canonical_results_match_text_keyed(catalog):
     """Property: for every sample rendering, the canonical (param-bound)
-    execution equals the text-keyed execution of the SAME sql."""
+    execution equals the numpy engine's answer to the SAME sql."""
     canon_sess = Session(catalog, backend="tpu")
-    monkeypatch.setenv("NDSTPU_CANON", "0")
-    text_sess = Session(catalog, backend="tpu")
+    cpu_sess = Session(catalog, backend="cpu")
     for name in SAMPLE:
         for seed in (SEED_A, SEED_B):
             for pname, sql in render(name, seed):
-                monkeypatch.setenv("NDSTPU_CANON", "1")
                 got = _rows(canon_sess.sql(sql))
-                monkeypatch.setenv("NDSTPU_CANON", "0")
-                want = _rows(text_sess.sql(sql))
+                want = _rows(cpu_sess.sql(sql))
                 assert got == want, f"{pname} seed={seed}"
+
+
+def test_raising_canonicalizer_falls_back_to_text_keying(catalog,
+                                                         monkeypatch):
+    """The safety valve: where canonicalization raises, the statement is
+    keyed by its normalized text (two renderings: two programs), counted
+    in ``engine.canon.errors``, and still answered correctly."""
+    from ndstpu.analysis import canon
+    from ndstpu.engine.sql import normalize_sql_key
+
+    def broken(plan, query=""):
+        raise RuntimeError("analyzer is wrong")
+
+    (_p, sql_a), = render("query96", SEED_A)
+    (_p, sql_b), = render("query96", SEED_B)
+    cpu_sess = Session(catalog, backend="cpu")
+    want = {sql: _rows(cpu_sess.sql(sql)) for sql in (sql_a, sql_b)}
+    monkeypatch.setattr(canon, "canonicalize", broken)
+    sess = Session(catalog, backend="tpu")
+    before = obs.counters_snapshot()
+    for sql in (sql_a, sql_b):
+        assert sess.canonical_key(sql) == normalize_sql_key(sql)
+        assert _rows(sess.sql(sql)) == want[sql]
+    moved = obs.counter_delta(before)
+    assert moved.get("engine.canon.errors", 0) == 2
+    assert moved.get("engine.cache.compiled.miss", 0) == 2
 
 
 def test_second_seed_compiles_zero_new_programs(catalog):

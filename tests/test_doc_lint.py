@@ -2,7 +2,6 @@
 scripts/doc_lint.py) — the committed tree must never cite a ghost
 artifact, and stale perf artifacts must say so."""
 
-import json
 import os
 import subprocess
 import sys
@@ -61,27 +60,6 @@ def test_cost_lint_root_citations_checked(tmp_path):
     (tmp_path / "COST_LINT.json").write_text("{}")
     (tmp_path / "COST_LINT.md").write_text("# cost\n")
     assert artifact_lint.lint_text(text, str(tmp_path)) == []
-
-
-def test_config_mismatch_flagged_unless_stale(tmp_path):
-    docs = tmp_path / "docs"
-    docs.mkdir()
-    current = {"NDSTPU_GROUPBY": "pallas"}
-    art = {"engine_defaults": {"NDSTPU_GROUPBY": "auto"}, "data": {}}
-    (docs / "A.json").write_text(json.dumps(art))
-    findings = artifact_lint.artifact_config_mismatches(
-        str(tmp_path), current=current)
-    assert len(findings) == 1 and "NDSTPU_GROUPBY" in findings[0]
-    # the stale stamp is the escape hatch: artifact admits its age
-    art["stale"] = True
-    (docs / "A.json").write_text(json.dumps(art))
-    assert artifact_lint.artifact_config_mismatches(
-        str(tmp_path), current=current) == []
-
-
-def test_current_defaults_parsed_from_source():
-    cur = artifact_lint.current_engine_defaults(REPO)
-    assert cur.get("NDSTPU_GROUPBY") in ("pallas", "auto", "sort")
 
 
 def test_committed_tree_is_clean():

@@ -502,13 +502,18 @@ def test_transcode_builds_gdict_sidecars(warehouse):
     assert d.nbytes == sum(len(str(v).encode()) for v in d.values)
 
 
-def test_gdict_kill_switch_disables_layer(warehouse, monkeypatch):
+def test_table_without_sidecar_loads_with_per_call_dicts(
+        warehouse, without_sidecar):
     from ndstpu.io import gdict
-    monkeypatch.setenv("NDSTPU_GLOBAL_DICTS", "0")
-    assert not gdict.enabled()
-    assert gdict.table_dicts(str(warehouse / "item"), "item") == {}
-    cat = loader.load_catalog(str(warehouse), ["item"])
-    assert cat.get("item").column("i_category").gdict is None
+    bare = without_sidecar(warehouse, "item")
+    assert gdict.table_dicts(str(bare / "item"), "item") == {}
+    col = loader.load_catalog(str(bare), ["item"]).get(
+        "item").column("i_category")
+    assert col.gdict is None
+    # the same strings, decoded through the per-call dictionary
+    want = loader.load_catalog(str(warehouse), ["item"]).get(
+        "item").column("i_category")
+    assert col.to_pylist() == want.to_pylist()
 
 
 def test_gdict_update_sidecar_append_only(tmp_path):
@@ -564,9 +569,13 @@ def test_parquet_chunk_source_streams_strings(warehouse):
 
 
 def test_parquet_chunk_source_rejects_strings_without_dicts(
-        warehouse, monkeypatch):
-    monkeypatch.setenv("NDSTPU_GLOBAL_DICTS", "0")
+        warehouse, without_sidecar):
+    from ndstpu.io import gdict
+    bare = without_sidecar(warehouse, "item")
     with pytest.raises(loader.StreamUnsupported) as ei:
-        loader.ParquetChunkSource(str(warehouse), "item",
+        loader.ParquetChunkSource(str(bare), "item",
                                   ["i_item_sk", "i_category"])
-    assert "NDSTPU_GLOBAL_DICTS" in str(ei.value)
+    assert gdict.GDICT_FILE in str(ei.value)
+    # its numeric columns stream as before
+    src = loader.ParquetChunkSource(str(bare), "item", ["i_item_sk"])
+    assert src.num_rows > 0

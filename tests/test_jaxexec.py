@@ -435,45 +435,92 @@ def test_compiled_invalidation_on_dml(catalog):
     catalog.register("item", item)
 
 
-# -- group-by strategies (sort / direct small-domain / pallas MXU) ----------
+# -- group-by paths (sort / direct small-domain with the segsum kernel) ------
 
-_GB_QUERIES = [
-    # int key with static bounds + decimal sum (pallas-eligible)
+_GB_QUERIES = {
+    # int key with static bounds + decimal sum (kernel-eligible)
+    "bounded_int_decimal_sum":
     "select ss_store_sk, sum(ss_ext_sales_price) as s, count(*) as n "
     "from store_sales group by ss_store_sk",
     # dictionary-coded string key + avg + min/max
+    "string_key":
     "select i_category, avg(i_current_price) as p, min(i_brand_id) as lo, "
     "max(i_brand_id) as hi from item group by i_category",
     # composite string x int domain; NULL keys from outer join misses
+    "composite_null_keys":
     "select i_category, ss_store_sk, sum(ss_quantity) as q, "
     "count(ss_item_sk) as n from store_sales "
     "left join item on ss_item_sk = i_item_sk "
     "group by i_category, ss_store_sk",
     # float aggregate: exercises the lazy-order compensated path
+    "float_aggregates":
     "select d_year, stddev_samp(ss_sales_price) as sd, "
     "avg(ss_net_profit) as m from store_sales "
     "join date_dim on ss_sold_date_sk = d_date_sk group by d_year",
-    # huge int domain (ticket numbers): must fall back to the sort path
-    "select ss_ticket_number, count(*) as n from store_sales "
-    "group by ss_ticket_number",
-    # rollup keeps working under every mode
+    # composite int domain over the cap (1922 tickets x 1833 items at
+    # this scale, 3.5 M slots): must fall back to the sort path
+    "ticket_item_sort":
+    "select ss_ticket_number, ss_item_sk, count(*) as n from store_sales "
+    "group by ss_ticket_number, ss_item_sk",
+    "rollup":
     "select i_category, i_class, count(*) as n from item "
     "group by rollup(i_category, i_class)",
-]
+}
 
 
-@pytest.mark.parametrize("mode", ["sort", "auto", "pallas"])
-def test_groupby_modes_differential(catalog, cpu_sess, monkeypatch, mode):
-    monkeypatch.setenv("NDSTPU_GROUPBY", mode)
+@pytest.mark.parametrize("name", list(_GB_QUERIES))
+def test_groupby_paths_differential(catalog, cpu_sess, monkeypatch, name):
+    """Each group-by shape against the numpy engine, in discovery and
+    in the compiled replay, on the path its key domain selects: the
+    linearized group ids for bounded keys, the sort where the domain is
+    over the cap."""
+    from ndstpu.engine import jaxexec
+    answered = []
+    direct = jaxexec.JaxExecutor._direct_group_ids
+
+    def spy(self, key_cols, alive):
+        out = direct(self, key_cols, alive)
+        answered.append(out is not None)
+        return out
+
+    monkeypatch.setattr(jaxexec.JaxExecutor, "_direct_group_ids", spy)
+    sql = _GB_QUERIES[name]
     sess = Session(catalog, backend="tpu")
-    for sql in _GB_QUERIES:
-        assert_tables_match(cpu_sess.sql(sql), sess.sql(sql))
+    want = cpu_sess.sql(sql)
+    for _run in ("discovery", "replay"):
+        assert_tables_match(want, sess.sql(sql))
+    assert answered
+    if name == "ticket_item_sort":
+        assert not any(answered)
+    else:
+        assert all(answered)
 
 
-def test_groupby_direct_path_engages(catalog, monkeypatch):
+def test_replay_contains_segsum_kernel(catalog, cpu_sess):
+    """A Pallas kernel is part of a traced replay program and of nothing
+    else (`_pallas_kernel`): discovery sums a kernel-eligible decimal
+    by scatter, the replay's program contains the interpreted kernel
+    (the counter ticks when a program is traced), and both equal the
+    numpy engine."""
+    from ndstpu import obs
+    sql = _GB_QUERIES["bounded_int_decimal_sum"]
+    want = cpu_sess.sql(sql)
+    obs.reset(enabled=True)
+    try:
+        sess = Session(catalog, backend="tpu")
+        assert_tables_match(want, sess.sql(sql))            # discovery
+        assert "engine.pallas.segsum_calls" not in obs.counters_snapshot()
+        assert_tables_match(want, sess.sql(sql))            # traced
+        assert obs.counters_snapshot()["engine.pallas.segsum_calls"] == 1
+        assert_tables_match(want, sess.sql(sql))            # not again
+        assert obs.counters_snapshot()["engine.pallas.segsum_calls"] == 1
+    finally:
+        obs.reset()
+
+
+def test_groupby_direct_path_engages(catalog):
     """The small-domain linearized-gid path must actually be taken for a
     bounded int key (not silently fall back to the sort path)."""
-    monkeypatch.setenv("NDSTPU_GROUPBY", "pallas")
     sess = Session(catalog, backend="tpu")
     exe = sess._jax_executor()
     from ndstpu.engine import jaxexec

@@ -43,10 +43,12 @@ def _check_probe(exe, pkey, bkey, bound, lut: bool):
     """Validate (lo, counts, order) against a brute-force reference:
     order[lo[i] .. lo[i]+counts[i]-1] must be exactly the build rows
     whose key equals probe key i (for valid keys)."""
-    exe.join_lut_cap = (1 << 25) if lut else 0
     pk = jnp.asarray(np.asarray(pkey, np.int64))
     bk = jnp.asarray(np.asarray(bkey, np.int64))
-    lo, counts, order = exe._probe_counts(pk, bk, bound)
+    with pytest.MonkeyPatch.context() as mp:
+        if not lut:     # no domain is under the cap: the combined sort
+            mp.setattr(jaxexec, "_JOIN_LUT_CAP", 0)
+        lo, counts, order = exe._probe_counts(pk, bk, bound)
     lo, counts, order = (np.asarray(lo), np.asarray(counts),
                          np.asarray(order))
     bkey = np.asarray(bkey)

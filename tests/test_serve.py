@@ -758,16 +758,19 @@ def test_fleet_probe_failures_restart_only_at_threshold(tmp_path):
     assert rep.restarts == 1 and len(launched) == 2
 
 
-def test_fleet_kill_switch_degenerates_to_one_replica(tmp_path,
-                                                      monkeypatch):
+def test_fleet_of_one_replica_is_one_endpoint(tmp_path):
+    """``--replicas 1`` is the supervised single server: one endpoint,
+    no comma spec for clients to fail over between; none is refused."""
     from ndstpu.serve import fleet as fleet_mod
-    monkeypatch.setenv(fleet_mod.FLEET_ENV, "0")
-    sup = fleet_mod.FleetSupervisor(
-        _fleet_cfg(tmp_path, replicas=3),
-        probe_fn=lambda rep: {"alive": True, "ready": True},
-        launcher=lambda rep: _FakeProc(pid=1))
+    fakes = dict(probe_fn=lambda rep: {"alive": True, "ready": True},
+                 launcher=lambda rep: _FakeProc(pid=1))
+    sup = fleet_mod.FleetSupervisor(_fleet_cfg(tmp_path, replicas=1),
+                                    **fakes)
     assert len(sup.replicas) == 1
     assert "," not in sup.endpoints_spec()
+    with pytest.raises(ValueError, match=">= 1 replica"):
+        fleet_mod.FleetSupervisor(_fleet_cfg(tmp_path, replicas=0),
+                                  **fakes)
 
 
 def test_fleet_default_endpoints_stable_and_short(tmp_path):

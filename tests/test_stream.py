@@ -194,15 +194,15 @@ def test_parquet_chunk_source_windows_match_resident(stream_root, catalog):
 
 
 def test_parquet_chunk_source_rejects_string_columns(stream_root,
-                                                     monkeypatch):
-    """With the global-dict sidecar present string columns stream; with
-    NDSTPU_GLOBAL_DICTS=0 the source refuses them as before."""
+                                                     without_sidecar):
+    """With the global-dict sidecar present string columns stream; from
+    a table without it the source refuses them as before."""
     src = loader.ParquetChunkSource(str(stream_root / "wh"), "item",
                                     columns=["i_item_sk", "i_category"])
     assert src.column_meta()["i_category"][2] is not None
-    monkeypatch.setenv("NDSTPU_GLOBAL_DICTS", "0")
+    bare = without_sidecar(stream_root / "wh", "item")
     with pytest.raises(loader.StreamUnsupported, match="string column"):
-        loader.ParquetChunkSource(str(stream_root / "wh"), "item",
+        loader.ParquetChunkSource(str(bare), "item",
                                   columns=["i_item_sk", "i_category"])
 
 
