@@ -114,8 +114,19 @@ _SEARCH_COMPACT_COST = 1.5
 _COMPARE_PAIR_COST = 5.6e-5
 # the kernel keeps keys and rows in scalar memory (1 MB a core)
 _COMPARE_MAX_KEYS = 32768
+# where a chain of lookup joins compacts (_defer_cheaper): behind a
+# compaction every column is a lazy view, and a key column read there
+# gathers its data (one element a row) and its validity, a pred gather
+# that costs this many int32 ones.  TPU v5 lite, query7 at SF1 (ledger,
+# PR 30; builder's trace, PR 29): store_sales' key gathered at 2 Mi rows
+# from its 4 Mi base takes 18.0 ms for the data and 38.8 for the
+# validity, behind a compaction scatter of 19.3 ms (21.1 at 4 Mi rows
+# whatever survives, _SEARCH_COMPACT_COST) -- 76 ms so that a compare
+# with 512 alive keys runs over 2 Mi rows instead of 4 Mi: 0.45 ms
+# instead of 1.1.
+_PRED_GATHER_COST = 2
 # "compare" joins are counted under "lookup" too (they are lookups)
-_JOIN_PATHS = ("lookup", "expand", "sort", "compare")
+_JOIN_PATHS = ("lookup", "expand", "sort", "compare", "deferred")
 # group-by by linearized key (_direct_group_ids): the most slots of a
 # composite key domain; a larger one takes the sort path.  1 << 16 left
 # q2's pivoted (d_week_seq x d_day_name) composite key (~83k slots) --
@@ -329,10 +340,17 @@ def _gather_cols(cols: Dict[str, DCol], idx: jnp.ndarray,
 
 @dataclasses.dataclass
 class DTable:
-    """Device table: named columns + alive mask, all of one capacity."""
+    """Device table: named columns + alive mask, all of one capacity.
+
+    ``pending`` is set on what an inner lookup join returns when it left
+    its survivors where the probe had them: the survivors' size class
+    (static, an upper bound on ``sum(alive)``), to which whatever needs
+    dense rows compacts them (JaxExecutor._settle).  Only
+    ``execute(settled=False)`` hands such a table out."""
 
     columns: Dict[str, DCol]
     alive: jnp.ndarray
+    pending: Optional[int] = None
 
     @property
     def capacity(self) -> int:
@@ -346,7 +364,8 @@ class DTable:
         return self.columns[name]
 
     def select(self, names: Sequence[str]) -> "DTable":
-        return DTable({n: self.columns[n] for n in names}, self.alive)
+        return DTable({n: self.columns[n] for n in names}, self.alive,
+                      self.pending)
 
     def gather(self, idx: jnp.ndarray, alive: jnp.ndarray) -> "DTable":
         return DTable(_gather_cols(self.columns, idx), alive)
@@ -1839,24 +1858,33 @@ class JaxExecutor:
     _MEMO_NODES = (lp.Join, lp.Aggregate, lp.SetOp, lp.Window,
                    lp.Distinct, lp.Sort)
 
-    def execute(self, p: lp.Plan) -> DTable:
+    def execute(self, p: lp.Plan, settled: bool = True) -> DTable:
+        """The node's table, with dense rows: a lookup join's pending
+        compaction (DTable.pending) is settled here unless the caller
+        can take the rows where they lie (``settled=False``: the probe
+        side of _exec_join).  The memo keeps the settled form once one
+        was asked for, so a node used twice compacts once."""
+        key = None
         if isinstance(p, self._MEMO_NODES):
             try:
                 key = _plan_fp(p)
             except TypeError:
                 # un-fingerprintable leaf (no content-based repr):
                 # skip memoization rather than fail the query
-                return self._execute_node(p)
+                pass
+        out = None
+        if key is not None:
             cache = getattr(self, "_tree_cache", None)
             if cache is None:
                 cache = self._tree_cache = {}
-            hit = cache.get(key)
-            if hit is not None:
-                return hit
+            out = cache.get(key)
+        if out is None:
             out = self._execute_node(p)
+        if settled and out.pending is not None:
+            out = self._settle(out)
+        if key is not None:
             cache[key] = out
-            return out
-        return self._execute_node(p)
+        return out
 
     def _execute_node(self, p: lp.Plan) -> DTable:
         name = "_exec_" + type(p).__name__.lower()
@@ -2086,6 +2114,13 @@ class JaxExecutor:
         """Scatter alive rows to the front (order-preserving); one
         sync point for the new capacity."""
         return self._compact_to(dt, *self._capacity_for(jnp.sum(dt.alive)))
+
+    def _settle(self, dt: DTable) -> DTable:
+        """The compaction a lookup join left pending, now: the alive
+        rows at the front of the survivors' size class.  The count is
+        taken here, so a row-wise mask since the join cannot have made
+        it stale; the class was recorded and guarded by the join."""
+        return self._compact_to(dt, dt.pending, jnp.sum(dt.alive))
 
     def _compact_to(self, dt: DTable, cap: int, n_alive) -> DTable:
         """The ``n_alive`` alive rows of ``dt`` at the front of ``cap``."""
@@ -3085,22 +3120,20 @@ class JaxExecutor:
         merged = _merged_dict([lc, rc])
         return (merged, max(len(merged), 1))
 
-    def _join_keys(self, lt: DTable, rt: DTable,
-                   keys: List[Tuple[ex.Expr, ex.Expr]]):
-        """Composite join keys on both sides (mixed-radix).
-
-        Key pairs whose value domain is statically known (int-like with
-        bounds, dictionary-coded strings) are encoded DIRECTLY from
-        values — no joint dense-rank, which costs a full sort over the
-        combined capacities per key.  Only unbounded pairs (raw float64,
-        computed columns without bounds) pay the rank-pairing sort.
-        When the final composite bound fits int32 the whole key build
-        runs in int32 (native on v5e; int64 is emulated as s32 pairs)."""
+    def _join_key_cols(self, lt: DTable, rt: DTable,
+                       keys: List[Tuple[ex.Expr, ex.Expr]]):
+        """(left, right) key columns, pair by pair."""
         levl, revl = JEval(lt), JEval(rt)
-        lcols = [levl.eval(self._resolve_subqueries(le)) for le, _ in keys]
-        rcols = [revl.eval(self._resolve_subqueries(re_)) for _, re_ in keys]
-        capl, capr = lt.capacity, rt.capacity
-        rank_radix = capl + capr + 3
+        return ([levl.eval(self._resolve_subqueries(le)) for le, _ in keys],
+                [revl.eval(self._resolve_subqueries(re_)) for _, re_ in keys])
+
+    def _join_key_specs(self, lcols: List[DCol], rcols: List[DCol],
+                        rank_radix: int):
+        """Host side of _join_keys: each pair's direct encoding (None:
+        the rank-pairing sort, over ``rank_radix`` ranks), whether the
+        radixes pass int64 on the way and re-densify, and the composite
+        key's exclusive bound.  With every pair direct and no
+        re-densifying the three depend on neither side's capacity."""
         specs = []
         for lc, rc in zip(lcols, rcols):
             spec = self._direct_join_spec(lc, rc)
@@ -3109,7 +3142,7 @@ class JaxExecutor:
                 if sspec is not None:
                     spec = ("str",) + sspec
             specs.append(spec)
-        # simulate the radix accumulation host-side to pick the key dtype
+        # simulate the radix accumulation to pick the key dtype
         bound = 1
         redensified = False
         for spec in specs:
@@ -3123,6 +3156,24 @@ class JaxExecutor:
                 redensified = True
                 bound = rank_radix
             bound *= radix
+        return specs, redensified, bound
+
+    def _join_keys(self, lt: DTable, rt: DTable,
+                   keys: List[Tuple[ex.Expr, ex.Expr]]):
+        """Composite join keys on both sides (mixed-radix).
+
+        Key pairs whose value domain is statically known (int-like with
+        bounds, dictionary-coded strings) are encoded DIRECTLY from
+        values — no joint dense-rank, which costs a full sort over the
+        combined capacities per key.  Only unbounded pairs (raw float64,
+        computed columns without bounds) pay the rank-pairing sort.
+        When the final composite bound fits int32 the whole key build
+        runs in int32 (native on v5e; int64 is emulated as s32 pairs)."""
+        lcols, rcols = self._join_key_cols(lt, rt, keys)
+        capl, capr = lt.capacity, rt.capacity
+        rank_radix = capl + capr + 3
+        specs, redensified, bound = self._join_key_specs(
+            lcols, rcols, rank_radix)
         use32 = (not redensified) and bound < 2 ** 31
         kdt = jnp.int32 if use32 else jnp.int64
         lkey = jnp.zeros(capl, kdt)
@@ -3300,7 +3351,14 @@ class JaxExecutor:
 
     def _exec_join(self, p: lp.Join) -> DTable:
         kind = p.kind
-        lt = self.execute(p.left)
+        # the probe side of an inner / left equi-join on plain columns
+        # may come with its survivors' compaction pending: _equi_join
+        # settles it, or looks up over the rows where they lie and
+        # leaves the compaction to this join's consumer (_probe_rows)
+        takes_pending = kind in ("inner", "left") and p.extra is None \
+            and bool(p.keys) and all(isinstance(e, ex.ColumnRef)
+                                     for pair in p.keys for e in pair)
+        lt = self.execute(p.left, settled=not takes_pending)
         rt = self.execute(p.right)
         extra = self._resolve_subqueries(p.extra) \
             if p.extra is not None else None
@@ -3378,6 +3436,9 @@ class JaxExecutor:
 
     def _equi_join(self, lt: DTable, rt: DTable, keys, kind,
                    extra, mark: Optional[str] = None) -> DTable:
+        sized = None
+        if lt.pending is not None:
+            lt, sized = self._probe_rows(lt, rt, keys)
         lkey, rkey, lvalid, rvalid, bound = self._join_keys(lt, rt, keys)
 
         if kind == "nullaware_anti":
@@ -3393,14 +3454,17 @@ class JaxExecutor:
         lkey = jnp.where(lvalid & lt.alive, lkey, -1)
         rkey = jnp.where(rvalid & rt.alive, rkey, -2)
 
-        span = self._lut_span(bound, rt.capacity, lt.capacity)
+        # (the tables are sized for the probe's rows, not for the
+        # capacity a pending compaction leaves them in)
+        span = self._lut_span(bound, rt.capacity,
+                              lt.pending or lt.capacity)
         cnt_t = None
         if span is not None and kind in ("inner", "left"):
             # a build side whose alive keys are unique (a dimension on
             # its surrogate key) gives each probe row 0 or 1 match: one
             # lookup, no expansion.  Observed, recorded and guarded like
             # every other data-dependent choice of the size plan.
-            k_cap, n_b = self._capacity_for(
+            k_cap, n_b = sized or self._capacity_for(
                 jnp.sum(rkey >= 0, dtype=jnp.int32))
             if self._compare_cheaper(lt.capacity, rt.capacity, k_cap):
                 # few alive build keys (a filtered dimension): compare
@@ -3423,6 +3487,11 @@ class JaxExecutor:
             if unique:
                 self._join_paths["lookup"] += 1
                 return self._lookup_join(lt, rt, lkey, ri, kind, extra)
+            if lt.pending is not None:
+                # the expansion wants dense rows after all
+                lt = self._settle(lt)
+                lkey, _, lvalid, _, _ = self._join_keys(lt, rt, keys)
+                lkey = jnp.where(lvalid & lt.alive, lkey, -1)
         self._join_paths["sort" if span is None else "expand"] += 1
 
         need_order = kind in ("inner", "left") or extra is not None
@@ -3470,11 +3539,17 @@ class JaxExecutor:
                      extra) -> DTable:
         """Inner / left join against a build side with unique alive keys,
         given each probe row's build row ``ri`` (-1: none): lazy build
-        columns and (inner) a compaction sized by the survivors.  Output
-        rows keep the probe's order."""
+        columns and (inner) the survivors' size class, recorded here and
+        left PENDING on the result: the compaction to it is the
+        consumer's (execute() settles it; a compare lookup above may
+        pass it on, _probe_rows).  A left join hands on what its probe
+        came with.  Output rows keep the probe's order."""
         matched = (ri >= 0) & (lkey >= 0) & lt.alive
         ri = jnp.maximum(ri, 0)
         rcols = _gather_cols(rt.columns, ri, matched)
+        if lt.pending is not None:
+            # (no ``extra`` here: _exec_join)
+            self._join_paths["deferred"] += 1
         if kind == "left":
             if extra is not None:
                 # at most one candidate a probe row: the residual
@@ -3482,32 +3557,98 @@ class JaxExecutor:
                 joined = DTable({**lt.columns, **rcols}, matched)
                 matched = matched & JEval(joined).predicate(extra)
                 rcols = _gather_cols(rt.columns, ri, matched)
-            return DTable({**lt.columns, **rcols}, lt.alive)
+            return DTable({**lt.columns, **rcols}, lt.alive, lt.pending)
         out = DTable({**lt.columns, **rcols}, matched)
         cap, n_out = self._capacity_for(jnp.sum(matched))
-        if cap != lt.capacity:
-            # (else the survivors fill the probe's size class: nothing
-            # moves)
+        # (survivors that fill the probe's size class: nothing moves)
+        pending = cap if cap != lt.capacity else None
+        if extra is None:
+            return DTable(out.columns, matched, pending)
+        if pending is not None:
             out = self._compact_to(out, cap, n_out)
-        if extra is not None:
-            # on the compacted rows, as the expansion path does: the
-            # predicate's right columns are gathered at ``cap``
-            out = DTable(out.columns,
-                         out.alive & JEval(out).predicate(extra))
-        return out
+        # on the compacted rows, as the expansion path does: the
+        # predicate's right columns are gathered at ``cap``
+        return DTable(out.columns, out.alive & JEval(out).predicate(extra))
 
     @staticmethod
-    def _compare_cheaper(n: int, m: int, k_cap: int) -> bool:
-        """Is comparing ``n`` probe keys with ``k_cap`` alive build keys
-        cheaper than the direct-addressed lookup (a gather of ``n``
-        elements behind two scatters of the ``m`` build rows)?  In
-        gathered elements; _COMPARE_PAIR_COST has the readings."""
+    def _lookup_costs(n: int, m: int, k_cap: int) -> Tuple[float, float]:
+        """(compare, gather): what finding the build row of ``n`` probe
+        keys costs by comparing each with ``k_cap`` alive build keys
+        (compacted out of ``m`` build rows first), and by the
+        direct-addressed lookup (a gather of ``n`` elements behind two
+        scatters of the ``m`` build rows).  In gathered elements;
+        _COMPARE_PAIR_COST has the readings."""
         if k_cap > _COMPARE_MAX_KEYS:
-            return False
+            return math.inf, n + 2 * m
         steps = max(m - 1, 1).bit_length()
-        compare = n * k_cap * _COMPARE_PAIR_COST + \
-            min(k_cap * steps * _SEARCH_COMPACT_COST, m)
-        return compare < n + 2 * m
+        return (n * k_cap * _COMPARE_PAIR_COST +
+                min(k_cap * steps * _SEARCH_COMPACT_COST, m), n + 2 * m)
+
+    @classmethod
+    def _compare_cheaper(cls, n: int, m: int, k_cap: int) -> bool:
+        """Is the compare the cheaper of _lookup_costs?"""
+        compare, gather = cls._lookup_costs(n, m, k_cap)
+        return compare < gather
+
+    @classmethod
+    def _defer_cheaper(cls, n: int, cap: int, m: int, k_cap: int,
+                       lazy_now: int = 0,
+                       lazy_settled: int = 1 + _PRED_GATHER_COST) -> bool:
+        """A probe side of capacity ``n`` whose survivors' compaction to
+        ``cap`` is pending: is the compare lookup over all ``n`` rows
+        cheaper than compacting first?  In the unit of _lookup_costs.
+        Unsettled, the compare and whatever the key columns gather a
+        row as they are (``lazy_now``).  Settled, the compaction
+        (_survivor_positions), the key columns behind it
+        (``lazy_settled`` a row: every column is a lazy view there) and
+        the cheaper lookup at ``cap``.  Only the compare path needs no
+        dense rows, so a join it does not take at ``n`` settles."""
+        compare_n, gather_n = cls._lookup_costs(n, m, k_cap)
+        if compare_n >= gather_n:
+            return False
+        steps = max(n - 1, 1).bit_length()
+        settled = min(cap * steps * _SEARCH_COMPACT_COST, n) + \
+            cap * lazy_settled + min(cls._lookup_costs(cap, m, k_cap))
+        return compare_n + n * lazy_now < settled
+
+    @staticmethod
+    def _key_gather_cost(cols: Sequence[DCol], settled: bool) -> int:
+        """Elements gathered a row to read these key columns: nothing
+        for a materialised column, its data and (_PRED_GATHER_COST) its
+        validity for a lazy one, as every column is once ``settled``."""
+        cost = 0
+        for c in cols:
+            if settled or c._data is None:
+                cost += 1
+            carries = c.src_valid if c.view is not None else c._valid
+            if carries is not None and (settled or c._valid is None):
+                cost += _PRED_GATHER_COST
+        return cost
+
+    def _probe_rows(self, lt: DTable, rt: DTable, keys):
+        """``lt`` for a lookup join, given with its survivors'
+        compaction pending (_exec_join: inner / left, plain key columns,
+        no ``extra``): as it is where _defer_cheaper, else settled.
+        Decided from the build side alone, before any probe key is
+        read; with it ``(k_cap, n_b)`` if the alive build keys were
+        sized here (the join's first record, as in _equi_join)."""
+        lcols, rcols = self._join_key_cols(lt, rt, keys)
+        specs, redensified, bound = self._join_key_specs(
+            lcols, rcols, lt.pending + rt.capacity + 3)
+        if redensified or any(spec is None for spec in specs) or \
+                self._lut_span(bound, rt.capacity, lt.pending) is None:
+            # rank pairing or the sort path: dense rows
+            return self._settle(lt), None
+        live = rt.alive
+        for c in rcols:
+            live = live & c.valid
+        sized = self._capacity_for(jnp.sum(live, dtype=jnp.int32))
+        if self._defer_cheaper(
+                lt.capacity, lt.pending, rt.capacity, sized[0],
+                self._key_gather_cost(lcols, False),
+                self._key_gather_cost(lcols, True)):
+            return lt, sized
+        return self._settle(lt), sized
 
     def _alive_build_keys(self, rkey: jnp.ndarray, k_cap: int, n_b):
         """(keys, rows): the alive build keys (``rkey >= 0``) and their
@@ -3654,7 +3795,7 @@ class _CompiledPlan:
     source_sql: Optional[str] = None
     # equi-join operators of the traced program by path (_JOIN_PATHS
     # order), set when fn is traced; None before
-    join_paths: Optional[Tuple[int, int, int, int]] = None
+    join_paths: Optional[Tuple[int, ...]] = None
 
 
 def _scan_columns(p: lp.Plan) -> Dict[str, Optional[List[str]]]:

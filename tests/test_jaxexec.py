@@ -944,11 +944,12 @@ def test_lookup_join(monkeypatch, kind, shape, nulls, extra, method):
     cp = sess.compiled_plan(sql)
     assert cp is not None and cp.compilable
     # a duplicated build key expands; unique alive keys look up
-    # (lookup, expand, sort, compare: a compare is a lookup too)
+    # (lookup, expand, sort, compare: a compare is a lookup too; and
+    # deferred: none here, no lookup probes another's survivors)
     n_joins = 2 if shape == "union" else 1
     assert cp.join_paths == (
-        (0, 1, 0, 0) if nulls == "dup" else
-        (n_joins, 0, 0, n_joins if method == "compare" else 0))
+        (0, 1, 0, 0, 0) if nulls == "dup" else
+        (n_joins, 0, 0, n_joins if method == "compare" else 0, 0))
     if shape == "union":
         assert ("cap", 256) in cp.record and want.num_rows > 5000
     if nulls == "edge" and method == "compare":
@@ -1007,7 +1008,7 @@ def test_lookup_join_int64_composite_key(monkeypatch, method):
         assert_tables_match(want, sess.sql(sql), ordered=True)
     assert all(k[0].dtype == jnp.int64 and k[4] < 2 ** 20 for k in seen)
     assert sess.compiled_plan(sql).join_paths == \
-        ((1, 0, 0, 1) if method == "compare" else (1, 0, 0, 0))
+        ((1, 0, 0, 1, 0) if method == "compare" else (1, 0, 0, 0, 0))
 
 
 @pytest.mark.parametrize("method", ["search", "scatter"])
@@ -1075,7 +1076,7 @@ def test_lookup_join_guard_rediscovers(monkeypatch, method):
     from ndstpu import obs
     from ndstpu.engine import jaxexec
     monkeypatch.setattr(jaxexec, "_COMPARE_PAIR_COST", _LJ_METHODS[method])
-    looked_up = (1, 0, 0, 1 if method == "compare" else 0)
+    looked_up = (1, 0, 0, 1 if method == "compare" else 0, 0)
     catalog = _guard_catalog()
     cpu = Session(catalog, backend="cpu")
     sess = Session(catalog, backend="tpu")
@@ -1091,7 +1092,7 @@ def test_lookup_join_guard_rediscovers(monkeypatch, method):
     assert any("rediscover" in str(w.message) for w in caught)
     assert obs.counters_snapshot()["engine.discoveries"] == before + 1
     assert_tables_match(cpu.sql(dup), sess.sql(dup))     # replay
-    assert sess.compiled_plan(dup).join_paths == (0, 1, 0, 0)
+    assert sess.compiled_plan(dup).join_paths == (0, 1, 0, 0, 0)
     # and back: unique again under the first draw
     assert_tables_match(cpu.sql(uniq), sess.sql(uniq))
     assert_tables_match(cpu.sql(uniq), sess.sql(uniq))
@@ -1103,7 +1104,7 @@ def test_lookup_join_guard_rediscovers(monkeypatch, method):
         **dim.columns, "d_val": _i32([i % 3 for i in range(dim.num_rows)])}))
     for _run in ("discovery", "replay"):
         assert_tables_match(cpu.sql(uniq), sess.sql(uniq))
-    assert sess.compiled_plan(uniq).join_paths == (0, 1, 0, 0)
+    assert sess.compiled_plan(uniq).join_paths == (0, 1, 0, 0, 0)
 
 
 _EDGE_SQL = ("select f_id, d_k, d_val from fact join "
@@ -1126,7 +1127,7 @@ def test_compare_join_capacity_guard_rediscovers():
     for _run in ("discovery", "replay"):
         assert_tables_match(cpu.sql(fits), sess.sql(fits))
     cp = sess.compiled_plan(fits)
-    assert cp.join_paths == (1, 0, 0, 1) and ("cap", 256) in cp.record
+    assert cp.join_paths == (1, 0, 0, 1, 0) and ("cap", 256) in cp.record
     before = obs.counters_snapshot().get("engine.discoveries", 0)
     with warnings.catch_warnings(record=True) as caught:
         warnings.simplefilter("always")
@@ -1135,7 +1136,7 @@ def test_compare_join_capacity_guard_rediscovers():
     assert_tables_match(cpu.sql(outgrows), sess.sql(outgrows))   # replay
     assert obs.counters_snapshot()["engine.discoveries"] == before + 1
     cp = sess.compiled_plan(outgrows)
-    assert cp.join_paths == (1, 0, 0, 1) and ("cap", 512) in cp.record
+    assert cp.join_paths == (1, 0, 0, 1, 0) and ("cap", 512) in cp.record
     # the smaller draw fits the larger class: no rediscovery back
     assert_tables_match(cpu.sql(fits), sess.sql(fits))
     assert obs.counters_snapshot()["engine.discoveries"] == before + 1
