@@ -411,6 +411,8 @@ enum TableId {
   T_S_CATALOG_ORDER_LINEITEM, T_S_WEB_ORDER, T_S_WEB_ORDER_LINEITEM,
   T_S_STORE_RETURNS, T_S_CATALOG_RETURNS, T_S_WEB_RETURNS, T_S_INVENTORY,
   T_DELETE = 60, T_INVENTORY_DELETE,
+  // draws per ORDER, not per row (web_order_number)
+  T_WEB_ORDER_LINES = 80,
 };
 
 static uint64_t g_seed = 19620718;  // default base seed
@@ -440,11 +442,27 @@ struct SaleCore {
   bool null_date, null_customer, null_channel, null_promo;
 };
 
-// items per ticket (avg ~3) — ticket id = row / spread
+// items per ticket (avg ~3) — ticket id = row / spread (store and
+// catalog; web orders have the source's structure, below)
 static const int TICKET_SPREAD = 3;
 
-static SaleCore gen_sale(uint64_t table_id, int64_t row, int64_t n_channel,
-                         int64_t order_spread) {
+// ws_order_number of web_sales row `row`: orders of 8 to 16 lines,
+// uniform (dsdgen's w_web_sales.c draws 8..16 line items an order).
+// Orders come in pairs that share 24 rows: the first of pair p takes
+// a drawn 8..16 of them and the second the rest, so each length is
+// uniform on 8..16 and any row finds its order in O(1), whatever chunk
+// or returns table asks.  The draw is the pair's own
+// (Rng(seed, T_WEB_ORDER_LINES, pair)), not the row's counter stream:
+// every other column of a row is drawn as before.
+static const int64_t WEB_ORDER_PAIR_ROWS = 24;
+
+static int64_t web_order_number(int64_t row) {
+  int64_t pair = row / WEB_ORDER_PAIR_ROWS;
+  int64_t first = Rng(g_seed, T_WEB_ORDER_LINES, pair).range(8, 16);
+  return 2 * pair + (row % WEB_ORDER_PAIR_ROWS >= first ? 2 : 1);
+}
+
+static SaleCore gen_sale(uint64_t table_id, int64_t row, int64_t n_channel) {
   Rng r(g_seed, table_id, row);
   SaleCore s;
   s.null_date = r.chance(0.02);
@@ -472,7 +490,8 @@ static SaleCore gen_sale(uint64_t table_id, int64_t row, int64_t n_channel,
   s.channel_sk = r.range(1, n_channel);
   s.null_promo = r.chance(0.5);
   s.promo_sk = r.range(1, g_sz.promotion);
-  s.ticket = row / order_spread + 1;
+  s.ticket = table_id == T_WEB_SALES ? web_order_number(row)
+                                     : row / TICKET_SPREAD + 1;
   s.quantity = r.range(1, 100);
   s.wholesale = r.cents(100, 10000);                     // 1.00 .. 100.00
   s.list = s.wholesale + r.cents(0, s.wholesale);        // markup <= 100%
@@ -926,6 +945,10 @@ static void gen_customer(Writer& w, int64_t b, int64_t e) {
 static void gen_web_site(Writer& w, int64_t b, int64_t e) {
   static const char* kSiteNames[] = {"site_0", "site_1", "site_2", "site_3",
       "site_4", "site_5"};
+  // dsdgen's web_company_name: the "syllables" word of a company id
+  // 1..6 -- the domain query94 / query95 draw their literal 'pri' from
+  static const char* kCompanyNames[] = {"ought", "able", "pri", "ese",
+      "anti", "cally"};
   for (int64_t i = b; i < e; i++) {
     int64_t sk = i + 1;
     Rng r(g_seed, T_WEB_SITE, i);
@@ -943,7 +966,7 @@ static void gen_web_site(Writer& w, int64_t b, int64_t e) {
     w.fstr(sentence(r, 8));
     w.fstr(std::string(pick(r, kFirstNames)) + " " + pick(r, kLastNames));
     w.fint(r.range(1, 2));
-    w.fstr("Company " + std::to_string(r.range(1, 6)));
+    w.fstr(kCompanyNames[r.range(1, 6) - 1]);
     char num[16];
     snprintf(num, sizeof num, "%" PRId64, r.range(1, 999));
     w.fstr(num);
@@ -1085,7 +1108,7 @@ static void gen_dbgen_version(Writer& w, int64_t b, int64_t e) {
 
 static void gen_store_sales(Writer& w, int64_t b, int64_t e) {
   for (int64_t i = b; i < e; i++) {
-    SaleCore s = gen_sale(T_STORE_SALES, i, g_sz.store, TICKET_SPREAD);
+    SaleCore s = gen_sale(T_STORE_SALES, i, g_sz.store);
     if (s.null_date) w.fnull(); else w.fint(s.date_sk);
     w.fint(s.time_sk);
     w.fint(s.item_sk);
@@ -1115,7 +1138,7 @@ static void gen_store_sales(Writer& w, int64_t b, int64_t e) {
 
 static void gen_catalog_sales(Writer& w, int64_t b, int64_t e) {
   for (int64_t i = b; i < e; i++) {
-    SaleCore s = gen_sale(T_CATALOG_SALES, i, g_sz.call_center, TICKET_SPREAD);
+    SaleCore s = gen_sale(T_CATALOG_SALES, i, g_sz.call_center);
     Rng r2(g_seed, T_CATALOG_SALES + 100, i);  // extra columns stream
     int64_t ship_date = s.date_sk + r2.range(2, 120);
     if (s.null_date) w.fnull(); else w.fint(s.date_sk);
@@ -1162,7 +1185,7 @@ static void gen_catalog_sales(Writer& w, int64_t b, int64_t e) {
 
 static void gen_web_sales(Writer& w, int64_t b, int64_t e) {
   for (int64_t i = b; i < e; i++) {
-    SaleCore s = gen_sale(T_WEB_SALES, i, g_sz.web_site, TICKET_SPREAD);
+    SaleCore s = gen_sale(T_WEB_SALES, i, g_sz.web_site);
     Rng r2(g_seed, T_WEB_SALES + 100, i);
     int64_t ship_date = s.date_sk + r2.range(2, 120);
     if (s.null_date) w.fnull(); else w.fint(s.date_sk);
@@ -1209,7 +1232,7 @@ static void gen_web_sales(Writer& w, int64_t b, int64_t e) {
 static void gen_store_returns(Writer& w, int64_t b, int64_t e) {
   for (int64_t j = b; j < e; j++) {
     int64_t i = return_parent_row(j, g_sz.store_sales, g_sz.store_returns);
-    SaleCore s = gen_sale(T_STORE_SALES, i, g_sz.store, TICKET_SPREAD);
+    SaleCore s = gen_sale(T_STORE_SALES, i, g_sz.store);
     RetCore t = gen_return(T_STORE_RETURNS, j, s);
     w.fint(t.ret_date_sk);
     w.fint(t.ret_time_sk);
@@ -1238,7 +1261,7 @@ static void gen_store_returns(Writer& w, int64_t b, int64_t e) {
 static void gen_catalog_returns(Writer& w, int64_t b, int64_t e) {
   for (int64_t j = b; j < e; j++) {
     int64_t i = return_parent_row(j, g_sz.catalog_sales, g_sz.catalog_returns);
-    SaleCore s = gen_sale(T_CATALOG_SALES, i, g_sz.call_center, TICKET_SPREAD);
+    SaleCore s = gen_sale(T_CATALOG_SALES, i, g_sz.call_center);
     Rng r2(g_seed, T_CATALOG_SALES + 100, i);
     RetCore t = gen_return(T_CATALOG_RETURNS, j, s);
     w.fint(t.ret_date_sk);
@@ -1275,7 +1298,7 @@ static void gen_catalog_returns(Writer& w, int64_t b, int64_t e) {
 static void gen_web_returns(Writer& w, int64_t b, int64_t e) {
   for (int64_t j = b; j < e; j++) {
     int64_t i = return_parent_row(j, g_sz.web_sales, g_sz.web_returns);
-    SaleCore s = gen_sale(T_WEB_SALES, i, g_sz.web_site, TICKET_SPREAD);
+    SaleCore s = gen_sale(T_WEB_SALES, i, g_sz.web_site);
     Rng r2(g_seed, T_WEB_SALES + 100, i);
     RetCore t = gen_return(T_WEB_RETURNS, j, s);
     w.fint(t.ret_date_sk);

@@ -111,12 +111,28 @@ def compile_cache_dir() -> str:
 
 
 def configure_compile_cache() -> str:
-    """Point JAX's persistent compile cache at the resolved directory.
-    Sets nothing when ``JAX_COMPILATION_CACHE_DIR`` is set."""
+    """Point JAX's persistent compile cache at the resolved directory
+    (no directory is set when ``JAX_COMPILATION_CACHE_DIR`` is), and
+    keep Python frames out of every program this process lowers
+    (``jax_traceback_in_locations_limit`` 0), so that a program's cache
+    key is its own.
+
+    Why the frames: a Pallas kernel is serialized into its program with
+    the frames of whoever traced that kernel shape FIRST in the process,
+    so the key of every kernel-bearing program depended on which
+    statement ran first: a warm power set-up recompiled two to six of
+    seven programs (160-385 s of XLA) by the text the pass started at
+    (builder's chip runs, PR 32).  What it costs: an op of a lowered
+    program, an HLO dump or a profiler trace no longer names the Python
+    file and line that made it, and an XLA or Mosaic error no longer
+    points at one; operator scopes (``jax.named_scope``), kernel names
+    and every Python traceback of an exception stay
+    (docs/OBSERVABILITY.md)."""
+    import jax
     if not os.environ.get(CACHE_ENV):
-        import jax
         jax.config.update("jax_compilation_cache_dir",
                           str(FIXED_CACHE_DIR))
+    jax.config.update("jax_traceback_in_locations_limit", 0)
     return compile_cache_dir()
 
 

@@ -127,6 +127,13 @@ _COMPARE_MAX_KEYS = 32768
 _PRED_GATHER_COST = 2
 # "compare" joins are counted under "lookup" too (they are lookups)
 _JOIN_PATHS = ("lookup", "expand", "sort", "compare", "deferred")
+# operators of a traced program by KIND, beside the joins' paths: semi
+# covers anti and null-aware anti; residual counts those semi / anti /
+# mark joins that expand their key matches to test a residual predicate
+# (_residual_hits); setop is INTERSECT / EXCEPT; agg_sort a keyed
+# aggregate without a linearised key (_direct_group_ids gave None)
+_OP_KINDS = ("join_semi", "join_mark", "join_residual", "join_full",
+             "setop", "agg_sort")
 # group-by by linearized key (_direct_group_ids): the most slots of a
 # composite key domain; a larger one takes the sort path.  1 << 16 left
 # q2's pivoted (d_week_seq x d_day_name) composite key (~83k slots) --
@@ -1795,6 +1802,8 @@ class JaxExecutor:
         # equi-join operators by the path each took, since the replay
         # program being traced began (-> _CompiledPlan.join_paths)
         self._join_paths: Dict[str, int] = dict.fromkeys(_JOIN_PATHS, 0)
+        # ... and its operators by kind (-> _CompiledPlan.op_kinds)
+        self._op_kinds: Dict[str, int] = dict.fromkeys(_OP_KINDS, 0)
         # eager bounds diagnostic: plain (non-compiling) executors keep
         # it always on — they have no discovery phase to front-load the
         # check into; CompilingExecutor narrows it to discovery
@@ -2313,6 +2322,7 @@ class JaxExecutor:
         if direct is not None:
             gid, ngseg, out_alive, out_cols, order = direct
         elif key_cols:
+            self._op_kinds["agg_sort"] += 1
             keys = [_key_col(c, dt.alive) for _, c in key_cols]
             gid, order, newgrp = _group_ids(keys)
             ngseg = cap
@@ -3030,6 +3040,7 @@ class JaxExecutor:
             return both if p.all else self._distinct_of(both)
         # intersect / except, distinct semantics (Spark): keep the first
         # left occurrence of each qualifying row-value group
+        self._op_kinds["setop"] += 1
         cap = both.capacity
         nl = lt.capacity
         keys = [_key_col(c, both.alive) for c in both.columns.values()]
@@ -3398,12 +3409,17 @@ class JaxExecutor:
         return out
 
     def _full_join(self, lt: DTable, rt: DTable, keys, extra) -> DTable:
+        self._op_kinds["join_full"] += 1
         left_part = self._equi_join(lt, rt, keys, "left", extra)
         # right rows with no key match (residual predicate excluded, as in
         # the reference interpreter's full-join path)
         lkey, rkey, lvalid, rvalid, bound = self._join_keys(lt, rt, keys)
         lkey = jnp.where(lvalid & lt.alive, lkey, -1)
         rkey = jnp.where(rvalid & rt.alive, rkey, -2)
+        # the second probe, the other way round: counted by its path
+        # like any other
+        span = self._lut_span(bound, lt.capacity, rt.capacity)
+        self._join_paths["sort" if span is None else "expand"] += 1
         _, rcounts, _ = self._probe_counts(rkey, lkey, bound,
                                            need_order=False)
         runmatched = rt.alive & ~(rcounts > 0)
@@ -3425,6 +3441,7 @@ class JaxExecutor:
                        extra) -> jnp.ndarray:
         """Per-left-row mask: does any key match survive the residual
         predicate?  (shared by semi / anti / mark joins)"""
+        self._op_kinds["join_residual"] += 1
         out_cap, total = self._capacity_for(
             jnp.sum(counts, dtype=jnp.int64))
         inner = self._expand(lt, rt, order, lo, counts, total, out_cap)
@@ -3493,6 +3510,9 @@ class JaxExecutor:
                 lkey, _, lvalid, _, _ = self._join_keys(lt, rt, keys)
                 lkey = jnp.where(lvalid & lt.alive, lkey, -1)
         self._join_paths["sort" if span is None else "expand"] += 1
+        if kind in ("semi", "anti", "mark"):
+            self._op_kinds["join_mark" if kind == "mark"
+                           else "join_semi"] += 1
 
         need_order = kind in ("inner", "left") or extra is not None
         lo, counts, order = self._probe_counts(lkey, rkey, bound,
@@ -3796,6 +3816,8 @@ class _CompiledPlan:
     # equi-join operators of the traced program by path (_JOIN_PATHS
     # order), set when fn is traced; None before
     join_paths: Optional[Tuple[int, ...]] = None
+    # ... and its operators by kind (_OP_KINDS order), set with it
+    op_kinds: Optional[Tuple[int, ...]] = None
 
 
 def _scan_columns(p: lp.Plan) -> Dict[str, Optional[List[str]]]:
@@ -4155,6 +4177,9 @@ class CompilingExecutor(JaxExecutor):
         # when its program was traced
         joins = {"join_" + k: sum(p.join_paths[i] for p in ran)
                  for i, k in enumerate(_JOIN_PATHS)}
+        # ... and their operators by kind
+        joins.update({k: sum(p.op_kinds[i] for p in ran)
+                      for i, k in enumerate(_OP_KINDS)})
         for k, v in joins.items():
             obs.inc("engine.replay." + k, v)
         if sp is not obs.NULL_SPAN:
@@ -4563,7 +4588,41 @@ class CompilingExecutor(JaxExecutor):
             cp.source_sql = sql
             self._compiled[f"{key_prefix}|{ckey}"] = cp
             n += 1
+        if n:
+            self._make_resident()
         return n
+
+    def _make_resident(self) -> None:
+        """Fetch the replay inputs of every loaded record now, table by
+        table in one fixed order: the largest table first (bytes of the
+        columns the records scan), ties by name (_accel_args: resident
+        on a chip, plain host arguments where the platform is the CPU).
+        Uploaded at first use they lie where the programs and
+        temporaries of the statements before them left room, and a
+        program's gathers then run 2-8 % faster or slower by the
+        statement a process happened to start with (query95 617-675 ms
+        a replay over seven starting texts: builder's chip runs, PR 32).
+        Sorted by name alone the power cell's pass read 1.3 % over the
+        parent's median in six same-seed pairs, largest first 0.4 %
+        (my chip runs, PR 33: PERF.md section 6); one order for every
+        configuration.  A discovery cannot do the same: the columns a
+        statement scans are known once it has run, so a process that
+        discovers still uploads at first use."""
+        need: Dict[str, set] = {}
+        for cp in (*self._compiled.values(), *self._seg_compiled.values()):
+            for table, cols in (cp.table_cols or {}).items():
+                need.setdefault(table, set()).update(
+                    cols if cols is not None
+                    else self._table_device(table).column_names)
+        def nbytes(table: str) -> int:
+            cols = self._table_device(table).columns
+            return sum(cols[c].data.nbytes + cols[c].valid.nbytes
+                       for c in need[table])
+
+        # the largest table first, ties by name (a table's columns go
+        # in one transfer, which JAX lays out by name)
+        for table in sorted(need, key=lambda t: (-nbytes(t), t)):
+            self._accel_args(table, sorted(need[table]))
 
     # -- replay argument assembly --------------------------------------------
 
@@ -4638,6 +4697,7 @@ class CompilingExecutor(JaxExecutor):
                                for i, n in enumerate(cp.plan.walk())}
             self._trace_tables = {}
             self._join_paths = dict.fromkeys(_JOIN_PATHS, 0)
+            self._op_kinds = dict.fromkeys(_OP_KINDS, 0)
             for name, entry in tables.items():
                 if name == "\x00params":
                     continue   # parameter subtree, not a table
@@ -4677,6 +4737,7 @@ class CompilingExecutor(JaxExecutor):
                     ok = ok & o
                 cp.join_paths = tuple(self._join_paths[k]
                                       for k in _JOIN_PATHS)
+                cp.op_kinds = tuple(self._op_kinds[k] for k in _OP_KINDS)
             finally:
                 self.mode = "eager"
                 self._trace_tables = None

@@ -46,19 +46,64 @@ def test_accel_engine_accepts_cpu_backend_with_pin():
 
 def test_compile_cache_resolver(monkeypatch, tmp_path):
     """JAX_COMPILATION_CACHE_DIR set: the program sets no directory in
-    code.  Unset: one fixed path inside the checkout."""
+    code.  Unset: one fixed path inside the checkout.  Either way no
+    Python frames go into what is lowered, and a session on a chip
+    engine is what asks for both."""
     import jax
     calls = []
     monkeypatch.setattr(jax.config, "update",
                         lambda k, v: calls.append((k, v)))
+    frames = ("jax_traceback_in_locations_limit", 0)
     monkeypatch.setenv(device.CACHE_ENV, str(tmp_path))
     assert device.configure_compile_cache() == str(tmp_path)
-    assert calls == []
+    assert calls == [frames]
     monkeypatch.delenv(device.CACHE_ENV)
     fixed = str(REPO / ".bench_cache" / "xla_cache_tpu")
+    del calls[:]
     assert device.configure_compile_cache() == fixed
-    assert calls == [("jax_compilation_cache_dir", fixed)]
+    assert calls == [("jax_compilation_cache_dir", fixed), frames]
     assert device.compile_cache_dir() == fixed
+    del calls[:]
+    monkeypatch.setattr(device, "wants_chip", lambda engine: True)
+    monkeypatch.setattr(device, "require_accelerator", lambda engine: None)
+    Session(tiny_catalog(), backend="tpu")
+    assert calls == [("jax_compilation_cache_dir", fixed), frames]
+
+
+def _lowered_keycmp_for_tpu(depth: int) -> str:
+    """The TPU text of a program holding the keycmp kernel, traced
+    afresh ``depth`` Python calls down."""
+    if depth:
+        return _lowered_keycmp_for_tpu(depth - 1)
+    import jax
+    import jax.numpy as jnp
+    from ndstpu.ops import keycmp
+    jax.clear_caches()
+    x = jax.ShapeDtypeStruct((4096,), jnp.int32)
+    k = jax.ShapeDtypeStruct((256,), jnp.int32)
+    return jax.jit(lambda x, k, r: keycmp.match_rows(x, k, r)).trace(
+        x, k, k).lower(lowering_platforms=("tpu",)).as_text()
+
+
+def test_a_kernel_program_lowers_the_same_whoever_traced_it():
+    """A Pallas kernel is serialized into its program with the Python
+    frames of whoever traced it, so the compile-cache key of a
+    kernel-bearing program followed the statement a process started
+    at.  With the limit configure_compile_cache sets, the text lowered
+    for the TPU is the same from any call depth; with JAX's default it
+    is not (the fault is still there to guard against)."""
+    import jax
+    was = jax.config.jax_traceback_in_locations_limit
+    try:
+        jax.config.update("jax_traceback_in_locations_limit", 10)
+        assert _lowered_keycmp_for_tpu(0) != _lowered_keycmp_for_tpu(5)
+        jax.config.update("jax_traceback_in_locations_limit", 0)
+        shallow = _lowered_keycmp_for_tpu(0)
+        assert "tpu_custom_call" in shallow and "keycmp" in shallow
+        assert shallow == _lowered_keycmp_for_tpu(5)
+    finally:
+        jax.config.update("jax_traceback_in_locations_limit", was)
+        jax.clear_caches()
 
 
 def test_build_jit_failure_on_accelerator_surfaces_in_summary(

@@ -162,6 +162,42 @@ def test_preloaded_record_drift_self_heals(catalog, tmp_path):
     assert exe2.n_discoveries > 0, "drifted record did not self-heal"
 
 
+def test_preload_makes_the_records_columns_resident_in_one_order(
+        catalog, tmp_path, monkeypatch):
+    """Loading records fetches the replay inputs of every column they
+    scan before any statement runs, table by table, the largest first
+    (bytes of the scanned columns), ties by name, a table's columns by
+    name (uploaded at first use, a chip's layout followed the order of
+    the statements).  One rule for every platform: what _accel_args
+    returns (resident buffers on a chip, host arguments on the CPU) is
+    its own."""
+    s1 = _fresh_tpu_session(catalog)
+    s1.sql(_SEG_SQL).to_rows()
+    path = str(tmp_path / "plans.pkl")
+    assert s1.save_compiled(path) >= 1
+    s2 = _fresh_tpu_session(catalog)
+    exe2 = s2._jax_executor()
+    calls = []
+    monkeypatch.setattr(exe2, "_accel_args",
+                        lambda t, cols=None: calls.append((t, cols)))
+    assert s2.preload_compiled(path) >= 1
+    assert all(cols == sorted(set(cols)) for _t, cols in calls)
+    scanned = {}
+    for cp in (*exe2._compiled.values(), *exe2._seg_compiled.values()):
+        for t, cols in cp.table_cols.items():
+            scanned.setdefault(t, set()).update(cols)
+    assert {t: set(cols) for t, cols in calls} == scanned
+
+    def nbytes(t):
+        cols = exe2._table_device(t).columns
+        return sum(cols[c].data.nbytes + cols[c].valid.nbytes
+                   for c in scanned[t])
+
+    tables = [t for t, _cols in calls]
+    assert len(tables) >= 2
+    assert tables == sorted(scanned, key=lambda t: (-nbytes(t), t))
+
+
 def test_eager_demotion_warns(catalog, monkeypatch):
     """A query demoted to eager execution after repeated replay
     failures must surface a warning (the task-failure listener
