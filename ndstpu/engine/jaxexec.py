@@ -62,7 +62,8 @@ from ndstpu import obs  # noqa: E402
 # shared with the static analyzer and scripts/spmd_coverage.py — keep
 # capability checks here pointing at it so the two can't drift
 from ndstpu.analysis import lowering as lowreg  # noqa: E402
-from ndstpu.engine import columnar, expr as ex, physical, plan as lp  # noqa: E402
+from ndstpu.engine import (  # noqa: E402
+    columnar, expr as ex, optimizer, physical, plan as lp)
 from ndstpu.engine.columnar import (  # noqa: E402
     BOOL,
     DATE,
@@ -131,9 +132,11 @@ _JOIN_PATHS = ("lookup", "expand", "sort", "compare", "deferred")
 # covers anti and null-aware anti; residual counts those semi / anti /
 # mark joins that expand their key matches to test a residual predicate
 # (_residual_hits); setop is INTERSECT / EXCEPT; agg_sort a keyed
-# aggregate without a linearised key (_direct_group_ids gave None)
+# aggregate without a linearised key (_direct_group_ids gave None);
+# exists_extremes the join of two per-key min / max aggregates that
+# optimizer.exists_by_extremes put in place of an inner join's pairs
 _OP_KINDS = ("join_semi", "join_mark", "join_residual", "join_full",
-             "setop", "agg_sort")
+             "setop", "agg_sort", "exists_extremes")
 # group-by by linearized key (_direct_group_ids): the most slots of a
 # composite key domain; a larger one takes the sort path.  1 << 16 left
 # q2's pivoted (d_week_seq x d_day_name) composite key (~83k slots) --
@@ -3373,6 +3376,9 @@ class JaxExecutor:
         rt = self.execute(p.right)
         extra = self._resolve_subqueries(p.extra) \
             if p.extra is not None else None
+        if isinstance(p.left, lp.Aggregate) and any(
+                n.startswith(optimizer.EXTREMES) for n, _ in p.left.aggs):
+            self._op_kinds["exists_extremes"] += 1
         if kind == "cross" or not p.keys:
             if kind not in ("cross", "inner"):
                 raise Unsupported(f"non-equi {kind} join", code="NDS210")

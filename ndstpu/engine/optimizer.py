@@ -657,6 +657,178 @@ def _null_filter_to_anti(p: lp.Plan, total):
     return out, True
 
 
+# name prefix of the per-key extremes exists_by_extremes computes; the
+# executor counts each join of two such aggregates it runs
+# (engine.replay.exists_extremes)
+EXTREMES = "__extremes"
+# value types whose order the comparison and min / max agree on exactly
+_EXTREME_KINDS = ("int32", "int64", "decimal", "date")
+# b.x op a.x  <=>  a.x _FLIP[op] b.x
+_FLIP = {"<>": "<>", "<": ">", "<=": ">=", ">": "<", ">=": "<="}
+
+
+def exists_by_extremes(p: lp.Plan, catalog=None) -> lp.Plan:
+    """``Project[k](Filter(a.x op b.x, A JOIN B ON a.k = b.k))`` read
+    only for which keys exist -> the same over per-key min / max.
+
+    The build side of a semi, anti, null-aware anti or mark join is
+    read only for the SET of its key values: neither how often a key
+    occurs nor any other column matters.  That fact passes down through
+    renaming Projects and SubqueryAliases, and into a side of an inner
+    equi-join that is read above the join only through its join key
+    (q95's ``web_returns JOIN ws_wh``).  Where it reaches an inner
+    equi-join on plain columns whose one residual conjunct compares a
+    plain column of each side, and nothing above reads the pair but its
+    keys, the pairs are never needed: over rows with a non-NULL key and
+    value, some pair of a key has a.x <> b.x iff min_A(x) < max_B(x) or
+    max_A(x) > min_B(x) (< and <= need the first, > and >= the second).
+    So each side becomes ``Aggregate[k; min(x), max(x)]`` over its rows
+    with k and x not NULL, the two aggregates are joined on k, filtered
+    on their extremes and projected to the keys read.  A key whose every
+    value is NULL on a side has no pair and no aggregate row; a NULL key
+    matches nothing in either form, so a NOT IN consumer sees no new
+    NULL.  q95's self-join of web_sales (719 384 rows -> about 9 M pairs
+    at SF1) becomes two aggregates into about 60 000 keys.
+
+    Integer, decimal and date values only (a string's or a float's
+    min / max need not agree with its comparison); a residual that
+    reads the probe row (a semi join's ``extra``, q94) carries no fact
+    and is left as it is.  Column types come from ``catalog``: without
+    one nothing is rewritten."""
+    if catalog is None:
+        return p
+    return _exists_walk(p, None, catalog)[0]
+
+
+def _exists_walk(p: lp.Plan, need: Optional[Set[str]], catalog):
+    """``need``: the output columns of ``p`` read above it, when only
+    the set of their value tuples matters there (None: no such fact).
+    Returns (plan, changed)."""
+    if need is not None:
+        out = _extremes_pair(p, need, catalog)
+        if out is not None:
+            return out, True
+    changed = False
+    for attr, sub in _exists_children(p, need):
+        nv, c = _exists_walk(getattr(p, attr), sub, catalog)
+        if c:
+            setattr(p, attr, nv)
+            changed = True
+    if changed and need is not None and isinstance(p, lp.Project):
+        # the pair's value columns are gone below: keep what is read
+        p.exprs = [(n, e) for n, e in p.exprs if n in need]
+    return p, changed
+
+
+def _exists_children(p: lp.Plan, need: Optional[Set[str]]):
+    """(attribute, need) of each plan child of ``p``: the fact passed
+    down to it, or None."""
+    subs = {f.name: None for f in dataclasses.fields(p)
+            if isinstance(getattr(p, f.name), lp.Plan)}
+    if isinstance(p, lp.Join) and p.keys and p.extra is None and \
+            p.kind in ("semi", "anti", "nullaware_anti", "mark"):
+        subs["right"] = set().union(*(_refs(r) for _l, r in p.keys))
+    elif need is None:
+        pass
+    elif isinstance(p, lp.SubqueryAlias):
+        subs["child"] = need
+    elif isinstance(p, lp.Project):
+        exprs = dict(p.exprs)
+        if need <= exprs.keys() and \
+                all(isinstance(exprs[n], ex.ColumnRef) for n in need):
+            subs["child"] = {exprs[n].name for n in need}
+    elif isinstance(p, lp.Join) and p.kind == "inner" and p.keys:
+        try:
+            outs = {"left": set(_output_names(p.left)),
+                    "right": set(_output_names(p.right))}
+        except RuntimeError:
+            return subs.items()
+        if outs["left"] & outs["right"]:
+            return subs.items()
+        read = need | (_refs(p.extra) if p.extra is not None else set())
+        for i, side in enumerate(("left", "right")):
+            keyrefs = set().union(*(_refs(pair[i]) for pair in p.keys))
+            if read & outs[side] <= keyrefs:
+                subs[side] = keyrefs
+    return subs.items()
+
+
+def _extremes_pair(p: lp.Plan, need: Set[str], catalog):
+    """The per-key extremes form of ``p`` if it is an inner equi-join
+    with one cross-side comparison read only through its keys, else
+    None."""
+    if isinstance(p, lp.Filter) and isinstance(p.child, lp.Join) and \
+            p.child.extra is None:
+        j, cond = p.child, p.condition
+    elif isinstance(p, lp.Join):
+        j, cond = p, p.extra
+    else:
+        return None
+    if j.kind != "inner" or not j.keys or not (
+            isinstance(cond, ex.BinOp) and cond.op in _FLIP and
+            isinstance(cond.left, ex.ColumnRef) and
+            isinstance(cond.right, ex.ColumnRef)) or not all(
+            isinstance(e, ex.ColumnRef) for pair in j.keys for e in pair):
+        return None
+    try:
+        louts, routs = set(_output_names(j.left)), set(_output_names(j.right))
+    except RuntimeError:
+        return None
+    lk = list(dict.fromkeys(l.name for l, _r in j.keys))
+    rk = list(dict.fromkeys(r.name for _l, r in j.keys))
+    if louts & routs or not need <= {*lk, *rk}:
+        return None
+    a, op, b = cond.left.name, cond.op, cond.right.name
+    if a in routs and b in louts:
+        a, op, b = b, _FLIP[op], a
+    if not (a in louts and b in routs and
+            _extreme_kind(j.left, a, catalog) and
+            _extreme_kind(j.right, b, catalog)):
+        return None
+
+    def extremes(side: lp.Plan, keys: List[str], x: str):
+        lo, hi = f"{EXTREMES}.min.{x}", f"{EXTREMES}.max.{x}"
+        rows = _push_conjuncts(side, [
+            ex.UnaryOp("isnotnull", ex.ColumnRef(n))
+            for n in dict.fromkeys([*keys, x])])
+        agg = lp.Aggregate(rows, [(k, ex.ColumnRef(k)) for k in keys],
+                           [(lo, ex.AggExpr("min", ex.ColumnRef(x))),
+                            (hi, ex.AggExpr("max", ex.ColumnRef(x)))])
+        return agg, ex.ColumnRef(lo), ex.ColumnRef(hi)
+
+    left, amin, amax = extremes(j.left, lk, a)
+    right, bmin, bmax = extremes(j.right, rk, b)
+    if op in ("<", "<="):
+        test = ex.BinOp(op, amin, bmax)
+    elif op in (">", ">="):
+        test = ex.BinOp(op, amax, bmin)
+    else:
+        test = ex.BinOp("or", ex.BinOp("<", amin, bmax),
+                        ex.BinOp(">", amax, bmin))
+    pairs = lp.Filter(lp.Join(left, right, "inner", list(j.keys)), test)
+    return lp.Project(pairs, [(n, ex.ColumnRef(n))
+                              for n in dict.fromkeys([*lk, *rk])
+                              if n in need])
+
+
+def _extreme_kind(p: lp.Plan, name: str, catalog) -> bool:
+    """Is output column ``name`` of ``p`` a stored base-table column,
+    through renames, of a type whose min / max orders as it compares?"""
+    while not isinstance(p, lp.Scan):
+        if isinstance(p, lp.Project):
+            e = dict(p.exprs).get(name)
+            if not isinstance(e, ex.ColumnRef):
+                return False
+            name = e.name
+        elif not isinstance(p, (lp.Filter, lp.SubqueryAlias)):
+            return False
+        p = p.child
+    if p.table not in catalog:
+        return False
+    col = catalog.get(p.table).columns.get(name)
+    return col is not None and col.ctype.kind in _EXTREME_KINDS
+
+
 def pivot_case_aggregates(p: lp.Plan) -> lp.Plan:
     """Rewrite N-way masked-sum pivots into ONE composite-key
     aggregation plus a tiny re-aggregation.
@@ -961,6 +1133,7 @@ def optimize(p: lp.Plan, catalog=None) -> lp.Plan:
     p = pivot_case_aggregates(p)
     p = fuse_sibling_scalar_aggregates(p)
     p = null_filter_to_anti(p)
+    p = exists_by_extremes(p, catalog)
     p = prune(p, None)
     _optimize_embedded(p, catalog)
     return p
