@@ -134,9 +134,15 @@ _JOIN_PATHS = ("lookup", "expand", "sort", "compare", "deferred")
 # (_residual_hits); setop is INTERSECT / EXCEPT; agg_sort a keyed
 # aggregate without a linearised key (_direct_group_ids gave None);
 # exists_extremes the join of two per-key min / max aggregates that
-# optimizer.exists_by_extremes put in place of an inner join's pairs
+# optimizer.exists_by_extremes put in place of an inner join's pairs;
+# window_rank a rank / dense_rank / row_number window, window_running an
+# aggregate window with ORDER BY (_running_window), window_whole one over
+# a whole partition; agg_wide a keyed aggregate on a linearised key whose
+# domain exceeds _PALLAS_SEGS_MAX, with a sum / avg column (those take
+# XLA's int64 scatter, not the segsum kernel)
 _OP_KINDS = ("join_semi", "join_mark", "join_residual", "join_full",
-             "setop", "agg_sort", "exists_extremes")
+             "setop", "agg_sort", "exists_extremes", "window_rank",
+             "window_running", "window_whole", "agg_wide")
 # group-by by linearized key (_direct_group_ids): the most slots of a
 # composite key domain; a larger one takes the sort path.  1 << 16 left
 # q2's pivoted (d_week_seq x d_day_name) composite key (~83k slots) --
@@ -2324,6 +2330,11 @@ class JaxExecutor:
             if key_cols else None
         if direct is not None:
             gid, ngseg, out_alive, out_cols, order = direct
+            if ngseg > self._PALLAS_SEGS_MAX and any(
+                    isinstance(n, ex.AggExpr) and n.func in ("sum", "avg")
+                    and not n.distinct
+                    for _, e in p.aggs for n in e.walk()):
+                self._op_kinds["agg_wide"] += 1
         elif key_cols:
             self._op_kinds["agg_sort"] += 1
             keys = [_key_col(c, dt.alive) for _, c in key_cols]
@@ -2842,6 +2853,7 @@ class JaxExecutor:
             c = evl.eval(self._resolve_subqueries(e))
             okeys.append(self._order_key(evl, c, asc, None))
         if w.func in ("row_number", "rank", "dense_rank"):
+            self._op_kinds["window_rank"] += 1
             order = _lexsort_order([pid] + okeys)
             idx = jax.lax.iota(jnp.int32, cap)
             pid_s = pid[order]
@@ -2875,7 +2887,9 @@ class JaxExecutor:
         # a running UNBOUNDED PRECEDING..CURRENT ROW frame (Spark default
         # RANGE — peers share the run value; explicit ROWS = per-row)
         if w.order_by:
+            self._op_kinds["window_running"] += 1
             return self._running_window(dt, evl, w, pid, okeys)
+        self._op_kinds["window_whole"] += 1
         gid = pid
         if w.func == "count" and (w.arg is None or
                                   isinstance(w.arg, ex.Star)):
