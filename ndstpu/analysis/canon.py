@@ -10,7 +10,12 @@ serves every stream permutation and every RNGSEED.
 
 ``canonicalize(optimized_plan)`` walks the plan bottom-up and replaces
 each literal with a typed parameter slot (:class:`ndstpu.engine.expr.Param`
-/ :class:`~ndstpu.engine.expr.InParam`), emitting:
+/ :class:`~ndstpu.engine.expr.InParam`), one slot per SOURCE literal: the
+planner binds a CTE's body once and shares its expression objects among
+the copies it instantiates (``plan.copy_plan``), so every site that reaches
+one literal object reads one slot, and the copies fingerprint equal (the
+executor then runs them once).  Two literals of equal value stay two slots.
+Emitting:
 
 * a **canonical fingerprint** — sha256 of the structural tree with slot
   markers in place of values (process-stable, keys the compile caches),
@@ -132,7 +137,7 @@ def projection_defs(plan: lp.Plan) -> Dict[str, ex.Expr]:
 
 @dataclasses.dataclass(frozen=True)
 class Slot:
-    """One lifted literal occurrence."""
+    """One lifted source literal, and every plan site that reads it."""
 
     slot: int
     value: object                      # original python value (tuple for IN)
@@ -210,21 +215,44 @@ class _Canon:
         self.diags: List[Diagnostic] = []
         self.force_shape = 0      # >0 inside pre-resolved subquery plans
         self.limit_slots: Dict[int, int] = {}   # id(Limit node) -> slot
+        # (id(source literal), kind, ctype, code, tag, in_list, negated)
+        # -> (the literal, slot); the literal is held so that its id
+        # cannot be reused by another object while this walk runs
+        self._sourced: Dict[tuple, tuple] = {}
 
     # -- slot bookkeeping ----------------------------------------------------
 
     def _slot(self, kind: str, value, ctype: DType, path: str, *,
               code: Optional[str] = None, reason: str = "",
               column=None, orig_ctype=None, in_list=False,
-              negated=False, tag: str = "") -> int:
-        # One slot per literal OCCURRENCE, assigned in walk order.  Never
-        # dedup by value: two distinct template parameters can render to
-        # the same literal in one stream and different literals in the
-        # next, and a value-sensitive slot assignment would give those
-        # renderings different structures — the exact instability this
-        # pass exists to remove.  Optimizer-duplicated literals simply
-        # occupy several slots bound to the same value.
+              negated=False, tag: str = "", source=None) -> int:
+        # One slot per SOURCE literal, assigned in walk order.  ``source``
+        # is the expression object the literal was read from; a site
+        # that reads an object an earlier site read the same way (kind,
+        # type, code, tag, IN-list form) joins that slot: a CTE's uses
+        # share their body's expressions, and the optimizer may copy a
+        # predicate.  Which objects are shared is fixed by the template,
+        # not by its values, so every rendering gets the same slots.
+        # Never dedup by value: two distinct template parameters can
+        # render to the same literal in one stream and different
+        # literals in the next, and a value-sensitive slot assignment
+        # would give those renderings different structures — the exact
+        # instability this pass exists to remove.  A LIMIT count has no
+        # source object and takes a slot per occurrence.
+        key = None
+        if source is not None:
+            key = (id(source), kind, ctype, code, tag, in_list, negated)
+            hit = self._sourced.get(key)
+            if hit is not None:
+                idx = hit[1]
+                self.slots[idx]["paths"].append(path)
+                if kind == "shape" and code is not None:
+                    self._diag(code, f"slot S{idx} value {value!r}: "
+                                     f"{reason}", path)
+                return idx
         idx = len(self.slots)
+        if key is not None:
+            self._sourced[key] = (source, idx)
         self.slots.append(dict(
             slot=idx, value=value, ctype=ctype, kind=kind, code=code,
             reason=reason, column=column, paths=[path],
@@ -326,9 +354,12 @@ class _Canon:
     # -- expression rewriting ------------------------------------------------
 
     def _lift(self, e: ex.Literal, path: str, *, shape_code=None,
-              reason="", column=None, tag="") -> ex.Expr:
-        """Lift one literal into a slot.  None literals and non-scalar
-        values stay structural (a NULL needs no runtime value)."""
+              reason="", column=None, tag="", source=None) -> ex.Expr:
+        """Lift one literal into a slot; ``source`` is the expression it
+        was read from when that is not ``e`` itself (a folded negation).
+        None literals and non-scalar values stay structural (a NULL
+        needs no runtime value)."""
+        source = e if source is None else source
         v = e.value
         if v is None or not isinstance(v, (bool, int, float, str)):
             return e
@@ -346,10 +377,11 @@ class _Canon:
         if shape_code is not None:
             idx = self._slot("shape", v, ct, path, code=shape_code,
                              reason=reason, column=column,
-                             orig_ctype=e.ctype, tag=tag)
+                             orig_ctype=e.ctype, tag=tag, source=source)
             return ex.Param(idx, ct, shape=True)
         idx = self._slot("bind", v, ct, path, reason=reason or "bindable",
-                         column=column, orig_ctype=e.ctype, tag=tag)
+                         column=column, orig_ctype=e.ctype, tag=tag,
+                         source=source)
         return ex.Param(idx, ct)
 
     def _expr(self, e: ex.Expr, path: str) -> ex.Expr:
@@ -370,7 +402,8 @@ class _Canon:
                 if days is not None:
                     idx = self._slot("bind", days, DATE, path,
                                      reason="date literal (cast folded)",
-                                     orig_ctype=None, tag="date")
+                                     orig_ctype=None, tag="date",
+                                     source=e.operand)
                     return ex.Param(idx, DATE)
             if isinstance(e.operand, ex.Literal) and \
                     isinstance(e.operand.value, str) and \
@@ -387,7 +420,7 @@ class _Canon:
         if isinstance(e, ex.UnaryOp):
             folded = _fold_neg(e)
             if folded is not e:
-                return self._expr(folded, path)
+                return self._lift(folded, path, source=e)
             return ex.UnaryOp(e.op, self._expr(e.operand, path))
         if isinstance(e, ex.Case):
             whens = []
@@ -462,7 +495,7 @@ class _Canon:
                         "bind", lit.value, STRING, path,
                         reason=f"string compare ({op})",
                         column=self._source_column(other),
-                        orig_ctype=lit.ctype, tag="str")
+                        orig_ctype=lit.ctype, tag="str", source=lit)
                     pnode = ex.Param(idx, STRING)
                     oc = self._expr(other, path)
                     return ex.BinOp(op, oc, pnode) if swapped \
@@ -484,7 +517,7 @@ class _Canon:
                             reason="date string compare (implicit "
                                    "string->date coercion)",
                             column=self._source_column(other),
-                            orig_ctype=None, tag="date")
+                            orig_ctype=None, tag="date", source=lit)
                         pnode = ex.Param(idx, DATE)
                         oc = self._expr(other, path)
                         return ex.BinOp(op, oc, pnode) if swapped \
@@ -515,10 +548,11 @@ class _Canon:
 
     def _cmp_side(self, side: ex.Expr, other: ex.Expr,
                   path: str) -> ex.Expr:
-        side = _fold_neg(side)
-        if isinstance(side, ex.Literal):
-            return self._lift(side, path,
-                              column=self._source_column(other))
+        folded = _fold_neg(side)
+        if isinstance(folded, ex.Literal):
+            return self._lift(folded, path,
+                              column=self._source_column(other),
+                              source=side)
         if isinstance(side, ex.Cast) and side.target.kind == "date" \
                 and isinstance(side.operand, ex.Literal) \
                 and isinstance(side.operand.value, str) \
@@ -533,7 +567,8 @@ class _Canon:
                 idx = self._slot("bind", days, DATE, path,
                                  reason="date literal (cast folded)",
                                  column=self._source_column(other),
-                                 orig_ctype=None, tag="date")
+                                 orig_ctype=None, tag="date",
+                                 source=side.operand)
                 return ex.Param(idx, DATE)
         return self._expr(side, path)
 
@@ -580,7 +615,7 @@ class _Canon:
             idx = self._slot("bind", vals, STRING, path,
                              reason="string IN-list (dictionary membership)",
                              column=col, in_list=True, negated=e.negated,
-                             tag="in")
+                             tag="in", source=e)
             return ex.InParam(operand, idx, len(vals), e.negated)
         if ot is not None and (ot.is_numeric or ot.kind == "date"):
             coerced, had_null = ex.coerce_in_values(ot, vals)
@@ -588,7 +623,7 @@ class _Canon:
                 idx = self._slot("bind", vals, ot, path,
                                  reason=f"IN-list over {ot} operand",
                                  column=col, in_list=True,
-                                 negated=e.negated, tag="in")
+                                 negated=e.negated, tag="in", source=e)
                 return ex.InParam(operand, idx, len(vals), e.negated)
             self._diag("NDS403", f"IN-list values {vals!r} do not coerce "
                                  f"cleanly to {ot}; kept literal", path)

@@ -139,10 +139,13 @@ _JOIN_PATHS = ("lookup", "expand", "sort", "compare", "deferred")
 # aggregate window with ORDER BY (_running_window), window_whole one over
 # a whole partition; agg_wide a keyed aggregate on a linearised key whose
 # domain exceeds _PALLAS_SEGS_MAX, with a sum / avg column (those take
-# XLA's int64 scatter, not the segsum kernel)
+# XLA's int64 scatter, not the segsum kernel); memo_shared a maximal
+# subtree the program took from an identical earlier one instead of
+# running it (a memo hit in execute: a second use of a CTE, or a second
+# read of a segment _cut_segments folded two occurrences into)
 _OP_KINDS = ("join_semi", "join_mark", "join_residual", "join_full",
              "setop", "agg_sort", "exists_extremes", "window_rank",
-             "window_running", "window_whole", "agg_wide")
+             "window_running", "window_whole", "agg_wide", "memo_shared")
 # group-by by linearized key (_direct_group_ids): the most slots of a
 # composite key domain; a larger one takes the sort path.  1 << 16 left
 # q2's pivoted (d_week_seq x d_day_name) composite key (~83k slots) --
@@ -1873,8 +1876,10 @@ class JaxExecutor:
     # pruned columns) fingerprint equal and execute ONCE per query.
     # Deterministic given the plan tree, so discover and replay hit the
     # memo at the same points and the size-plan record stays aligned.
+    # A DeviceResult stands for its segment's program: a second read of
+    # one is a folded occurrence, counted with the other hits.
     _MEMO_NODES = (lp.Join, lp.Aggregate, lp.SetOp, lp.Window,
-                   lp.Distinct, lp.Sort)
+                   lp.Distinct, lp.Sort, lp.DeviceResult)
 
     def execute(self, p: lp.Plan, settled: bool = True) -> DTable:
         """The node's table, with dense rows: a lookup join's pending
@@ -1898,6 +1903,8 @@ class JaxExecutor:
             out = cache.get(key)
         if out is None:
             out = self._execute_node(p)
+        else:
+            self._op_kinds["memo_shared"] += 1
         if settled and out.pending is not None:
             out = self._settle(out)
         if key is not None:

@@ -186,6 +186,34 @@ def test_small_tables_agree_with_the_numpy_engine(small, case):
     assert tuple(tallies[k] for k in NEW_KINDS) == new_kinds, tallies
 
 
+_SUMS = "select g, sum(x) sx from t where o > 1 group by g"
+# case: (sql, memo_shared of its programs).  One literal of a CTE used
+# twice is one parameter slot, so the two uses fingerprint equal and run
+# once; the same subquery written out twice holds two literals of one
+# value, two slots, and runs twice.
+MEMO = {
+    "cte_used_twice": (
+        f"with s as ({_SUMS}) select s1.g, s1.sx, s2.sx sx2 "
+        "from s s1, s s2 where s1.g = s2.g", 1),
+    "subquery_written_twice": (
+        f"select s1.g, s1.sx, s2.sx sx2 from ({_SUMS}) s1, ({_SUMS}) s2 "
+        "where s1.g = s2.g", 0),
+}
+
+
+@pytest.mark.parametrize("case", list(MEMO))
+def test_a_cte_used_twice_runs_once(small, case):
+    tpu, numpy_sess = small
+    sql, shared = MEMO[case]
+    want = numpy_sess.sql(sql).to_rows()
+    assert want, case
+    for phase in ("discovery", "replay"):
+        refused, counts = _refused(tpu.sql(sql).to_rows(), "idd", want)
+        assert not refused, (case, phase, counts)
+    tallies = dict(zip(_OP_KINDS, _tallies(tpu, sql)[1]))
+    assert tallies["memo_shared"] == shared, tallies
+
+
 @pytest.mark.parametrize("table,slots", [("w_narrow", 30000),
                                          ("w_wide", 40000)])
 def test_dense_decimal_sums_are_exact(small, table, slots):
@@ -220,17 +248,18 @@ TABLES = ["store_sales", "catalog_sales", "web_sales", "date_dim", "item",
           "time_dim", "household_demographics", "reason"]
 # part: (lookup, expand, sort, compare, deferred),
 #       (semi, mark, residual, full, setop, agg_sort, exists_extremes,
-#        window_rank, window_running, window_whole, agg_wide)
+#        window_rank, window_running, window_whole, agg_wide, memo_shared)
 # summed over the part's programs, on this data set.  A CTE used twice
-# or three times runs once per use: canonical keying gives each use's
-# literals parameter slots of their own (query2's day names, query47's
-# years), so the uses' subtrees differ and neither the segment cut nor
-# the memo shares them -- query2's pivot sum counts 2, query47's
-# six-key aggregate and its two windows 3 each.
+# or three times runs once: its uses share their literals' parameter
+# slots (one slot per source literal), so they fingerprint equal, the
+# segment cut folds them into one program and the parent reads it once
+# a use -- query2's pivot sum counts 1 and its second use memo_shared
+# 1; query47's six-key aggregate, its two windows and the lookups under
+# them count once, its two further uses memo_shared 2.
 TALLIES = {
-    "query2": ((2, 3, 0, 0, 0), (0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 2)),
-    "query47": ((9, 0, 2, 9, 0), (0, 0, 0, 0, 0, 3, 0, 3, 0, 3, 0)),
-    "query51": ((2, 0, 2, 2, 0), (0, 0, 0, 1, 0, 2, 0, 0, 4, 0, 0)),
+    "query2": ((1, 3, 0, 0, 0), (0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 1, 1)),
+    "query47": ((3, 0, 2, 3, 0), (0, 0, 0, 0, 0, 1, 0, 1, 0, 1, 0, 2)),
+    "query51": ((2, 0, 2, 2, 0), (0, 0, 0, 1, 0, 2, 0, 0, 4, 0, 0, 0)),
 }
 PARTS = list(TALLIES)
 # power-sf1.opclass7's parts: (window_rank, window_running, window_whole,

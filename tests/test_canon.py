@@ -179,6 +179,126 @@ def test_canonical_key_session_helper(ssess):
     assert ssess.canonical_key(junk) == normalize_sql_key(junk)
 
 
+# -- one slot per source literal ----------------------------------------------
+
+# the power cells' fixed seeds (benchmark/workloads/*.json)
+CELL_SEED = {"opclass7": "3000000041", "joinclass6": "3000000043",
+             "aggwin3": "3000000047"}
+# part: (uses of its CTE, slots) at the aggwin3 cell's seed; per
+# occurrence the parts had 25 and 28 slots, and 2 and 3 segments
+CTE_USES = {"query2": (2, 18), "query47": (3, 14)}
+
+
+@pytest.mark.parametrize("name", list(CTE_USES))
+def test_cte_uses_share_slots_and_one_segment(ssess, tables, name):
+    """A CTE used more than once is planned once and copied per use, the
+    copies sharing its expression objects: each literal of the body is
+    one slot, the uses' aggregates and windows fingerprint equal, and
+    the segment cut folds them into one program the uses read."""
+    from ndstpu.engine import plan as lp
+    from ndstpu.engine.jaxexec import _cut_segments, _plan_fp
+    uses, n_slots = CTE_USES[name]
+    (pname, sql), = render(name, CELL_SEED["aggwin3"])
+    res = canon_of(ssess, tables, sql, pname)
+    assert len(res.slots) == n_slots
+    assert any(len(s.paths) == uses for s in res.slots)
+    for kind in (lp.Aggregate, lp.Window):
+        fps = [_plan_fp(n) for n in res.exec_plan.walk()
+               if isinstance(n, kind)]
+        assert len(fps) == uses * len(set(fps)), kind
+    parent, segs = _cut_segments(res.exec_plan)
+    reads = [n.key for n in parent.walk() if isinstance(n, lp.DeviceResult)]
+    assert len(segs) == 1 and reads == list(segs) * uses
+
+
+def test_equal_literals_of_two_sources_keep_two_slots(ssess, tables):
+    """Identity, never value: two literals written apart keep two slots
+    where they render one value, and share the structure of a rendering
+    whose values differ; one literal of a CTE used twice is one slot."""
+    def apart(a, b):
+        return ("select count(*) as n from store_sales, date_dim, item "
+                "where ss_sold_date_sk = d_date_sk and ss_item_sk = "
+                f"i_item_sk and d_moy = {a} and i_manufact_id = {b}")
+
+    def twice(a, b):
+        return ("select count(*) as n from "
+                f"(select d_date_sk from date_dim where d_moy = {a}) x, "
+                f"(select d_date_sk from date_dim where d_moy = {b}) y "
+                "where x.d_date_sk = y.d_date_sk")
+    for sql in (apart, twice):
+        same = canon_of(ssess, tables, sql(11, 11))
+        differ = canon_of(ssess, tables, sql(11, 12))
+        assert sorted(s.value for s in same.slots) == [11, 11]
+        assert all(len(s.paths) == 1 for s in same.slots)
+        assert same.fingerprint == differ.fingerprint
+        assert same.cache_key == differ.cache_key
+    cte = canon_of(ssess, tables,
+                   "with v as (select d_date_sk from date_dim "
+                   "where d_moy = 11) select count(*) as n from v x, v y "
+                   "where x.d_date_sk = y.d_date_sk")
+    assert [(s.value, len(s.paths)) for s in cte.slots] == [(11, 2)]
+
+
+# parts whose renderings at the two seeds below give two cache keys (a
+# shape-affecting slot draws apart); every other part gives one.  Keying
+# slots by source literal split none of them further.
+TWO_KEYS = {"query14_part2", "query24_part1", "query24_part2", "query35",
+            "query4", "query44", "query54", "query58", "query6",
+            "query66", "query91"}
+
+
+def test_corpus_keys_split_no_further_across_seeds(ssess, tables):
+    keys = {}
+    for seed in (CELL_SEED["aggwin3"], SEED_A):
+        for name, sql in streamgen.render_power_corpus(rngseed=seed,
+                                                       stream=0):
+            keys.setdefault(name, set()).add(
+                canon_of(ssess, tables, sql, name).cache_key)
+    assert len(keys) == 103
+    wider = {n: len(k) for n, k in keys.items()
+             if len(k) > (2 if n in TWO_KEYS else 1)}
+    assert not wider, wider
+
+
+# (cache key, slots) of each part the other cells run, at the cell's
+# seed: no CTE of theirs is used twice, so their programs are the ones
+# compiled before slots were keyed by source literal.  The served cell
+# draws its texts from the run's seed; its parts are opclass7's.
+OTHER_CELLS = {
+    ("opclass7", "query3"): ("c:76794393083688b2:6eefa5acd663", 3),
+    ("opclass7", "query7"): ("c:41570cd560308da0:d23da4e9d8b5", 7),
+    ("opclass7", "query96"): ("c:eb3e23189de543b7:29783a1b0aac", 5),
+    ("opclass7", "query12"): ("c:fbec7d464233b5a9:8d7aec4045aa", 6),
+    ("opclass7", "query86"): ("c:e81e819c7fe0ec8f:6cb770322914", 6),
+    ("opclass7", "query25"): ("c:82f9985545ce0488:50461cbdeb3e", 9),
+    ("opclass7", "query9"): ("c:7193e17a8dff2cae:3362743655b5", 36),
+    ("joinclass6", "query69"): ("c:a546ee12dbb5bd53:af85c6dd3485", 14),
+    ("joinclass6", "query10"): ("c:20bff49d080a53c9:af85c6dd3485", 14),
+    ("joinclass6", "query94"): ("c:233cc05b41bb32b8:fb238a8e9f80", 6),
+    ("joinclass6", "query97"): ("c:871dade758feb703:42524ae942dd", 13),
+    ("joinclass6", "query38"): ("c:eedeb84ddee6a61f:fbfc74ee649f", 10),
+    ("joinclass6", "query95"): ("c:a1203195d9568428:fb238a8e9f80", 6),
+    ("aggwin3", "query51"): ("c:837ce022ed38711d:d23da4e9d8b5", 7),
+}
+SERVED = ["query96", "query3", "query86", "query9"]
+
+
+@pytest.mark.parametrize("cell,name", list(OTHER_CELLS))
+def test_other_cells_keep_their_cache_keys(ssess, tables, cell, name):
+    (pname, sql), = render(name, CELL_SEED[cell])
+    res = canon_of(ssess, tables, sql, pname)
+    assert (res.cache_key, len(res.slots)) == OTHER_CELLS[cell, name]
+
+
+@pytest.mark.parametrize("name", SERVED)
+def test_served_parts_keep_their_cache_keys(ssess, tables, name):
+    for seed in (SEED_A, SEED_B):
+        (pname, sql), = render(name, seed)
+        res = canon_of(ssess, tables, sql, pname)
+        assert (res.cache_key, len(res.slots)) == \
+            OTHER_CELLS["opclass7", name]
+
+
 # -- runtime: differential + cache-counter properties -------------------------
 
 
