@@ -58,6 +58,7 @@ jax.config.update("jax_enable_x64", True)
 import jax.numpy as jnp  # noqa: E402
 
 from ndstpu import obs  # noqa: E402
+from ndstpu.obs import host as obs_host  # noqa: E402
 # the declarative supported-op registry is the single source of truth
 # shared with the static analyzer and scripts/spmd_coverage.py — keep
 # capability checks here pointing at it so the two can't drift
@@ -4133,7 +4134,10 @@ class CompilingExecutor(JaxExecutor):
         harness's per-query cost split is self-labeling.  The span
         carries where its host time went (``host_prep_s``,
         ``dispatch_s``, ``device_wait_s``, ``assemble_s``); a profiler
-        trace has the same four as live ``ndstpu:replay.*`` marks."""
+        trace has the same four as live ``ndstpu:replay.*`` marks.
+        Where the GC hook runs (``obs.host``) it also carries the
+        process's GC pause seconds in the replay (``gc_s``) and of them
+        inside ``device_wait_s`` (``wait_gc_s``)."""
         with obs.span("replay", cat="plan-node", bucket=bucket,
                       n_programs=1 + len(cp.seg_fps or ())) as sp:
             result = self._replay_query_timed(cp, sp, binding)
@@ -4143,6 +4147,7 @@ class CompilingExecutor(JaxExecutor):
                             binding: Optional[ex.ParamBinding] = None,
                             ) -> Optional[Table]:
         t_start = time.perf_counter()
+        gc_start = obs_host.gc_seconds()
         seg_args = {}
         seg_oks = []
         ran = [cp]      # the programs this replay runs on the device
@@ -4180,15 +4185,17 @@ class CompilingExecutor(JaxExecutor):
         with obs.annotation("replay.dispatch"):
             (out, alive), ok = cp.fn(args)
         t_called = time.perf_counter()
+        gc_called = obs_host.gc_seconds()
         # the device runs from here (one replay in flight: the host
-        # waits in device_get for the programs and the D2H copy)
+        # waits in device_get for the programs and the D2H copy; a GC
+        # pause on any thread in that time is inside the wait too)
         with obs.annotation("replay.device_wait"):
             (out, alive_np), okv, seg_okv = jax.device_get(
                 ((out, alive), ok, seg_oks))
         t_fetched = time.perf_counter()
+        gc_fetched = obs_host.gc_seconds()
         fetched = int(alive_np.nbytes) + sum(
             d.nbytes + v.nbytes for d, v in out.values())
-        obs.inc("engine.fetched_bytes", fetched)
         result = None
         if bool(okv) and all(bool(o) for o in seg_okv):
             for fp in (cp.seg_fps or ()):
@@ -4199,7 +4206,7 @@ class CompilingExecutor(JaxExecutor):
             with obs.annotation("replay.assemble"):
                 result = self._assemble_host(cp, out, alive_np)
         t_end = time.perf_counter()
-        obs.inc("engine.replay.device_wait_s", t_fetched - t_called)
+        gc_end = obs_host.gc_seconds()
         # join operators of the programs just run, by the path each took
         # when its program was traced
         joins = {"join_" + k: sum(p.join_paths[i] for p in ran)
@@ -4215,6 +4222,9 @@ class CompilingExecutor(JaxExecutor):
                    device_wait_s=round(t_fetched - t_called, 6),
                    assemble_s=round(t_end - t_fetched, 6),
                    fetched_bytes=fetched, **joins)
+            if obs_host.installed():
+                sp.set(gc_s=round(gc_end - gc_start, 6),
+                       wait_gc_s=round(gc_fetched - gc_called, 6))
         return result
 
     @staticmethod

@@ -116,6 +116,11 @@ class Session:
 
         from ndstpu.engine import device
         from ndstpu.engine.latch import KeyedLatch
+        from ndstpu.obs import host
+        # GC pauses and host stalls are recorded once per process, in
+        # the power run and the serve daemon alike (none where tracing
+        # is off)
+        host.start()
         # the accelerator engines run on whatever jax.devices()[0] is:
         # refuse a quiet CPU fallback here, before any query, and keep
         # the chip's compiled programs in the one resolved cache dir

@@ -53,6 +53,8 @@ import threading
 import time
 from typing import Dict, List, Optional
 
+from ndstpu.obs import host
+
 ANNOTATION_PREFIX = "ndstpu:"
 DEFAULT_MAX_EVENTS = 100_000
 
@@ -232,11 +234,21 @@ class Tracer:
             return
         with self.annotation(name, wall_us=int(wall_s * 1e6)):
             pass
+        self.log_event(name, cat, t0_epoch, wall_s, threading.get_ident(),
+                       **attrs)
+
+    def log_event(self, name: str, cat: str, t0_epoch: float,
+                  wall_s: float, tid: int, **attrs) -> None:
+        """``record`` without the profiler mark, for a region measured
+        on thread ``tid`` whose mark, if any, was live (the GC hook's
+        ``ndstpu:gc``)."""
+        if not self.enabled:
+            return
         self._append_event({
             "name": name, "cat": cat, "ph": "X",
             "ts_epoch_s": round(t0_epoch, 6),
             "wall_s": round(wall_s, 6),
-            "pid": self.pid, "tid": threading.get_ident(),
+            "pid": self.pid, "tid": tid,
             "seq": self._next_seq(), "depth": 0,
             "args": attrs,
         })
@@ -292,8 +304,13 @@ class Tracer:
             self.gauges[name] = value
 
     def counters_snapshot(self) -> Dict[str, float]:
+        """The counters, with the host runtime's (``host.*``, kept
+        outside the lock by ``obs.host``) where this tracer is on."""
         with self._lock:
-            return dict(self.counters)
+            out = dict(self.counters)
+        if self.enabled:
+            out.update(host.counters())
+        return out
 
     def gauges_snapshot(self) -> Dict[str, float]:
         with self._lock:
